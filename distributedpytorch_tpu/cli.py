@@ -245,7 +245,7 @@ def parse_profile_steps(text):
 def _channel_shaped(exc: BaseException) -> bool:
     """Does this exception look like a dead/flapping runtime channel —
     i.e. a PEER failure, not this rank's own bug? One definition with
-    the retry taxonomy (utils/faults.is_transient): the OSError family
+    the retry classification (utils/faults.is_transient): the OSError family
     plus grpc/socket-marked RuntimeErrors, which is exactly how a gloo
     peer's death presents on every survivor."""
     from distributedpytorch_tpu.utils.faults import is_transient
@@ -253,32 +253,21 @@ def _channel_shaped(exc: BaseException) -> bool:
     return is_transient(exc)
 
 
-def _enable_compilation_cache():
-    """Persistent XLA compilation cache: first-run UNet compiles cost
-    20-40 s on TPU; subsequent launches reload them from disk. Best-effort
-    (older jax versions or unsupported backends simply skip it)."""
-    try:
-        import jax
-
-        cache_dir = os.environ.get(
-            "DPT_COMPILATION_CACHE",
-            os.path.join(os.path.expanduser("~"), ".cache", "dpt_xla_cache"),
-        )
-        if cache_dir:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:  # pragma: no cover
-        pass
-
-
 def main():
     args = get_args()
-    _enable_compilation_cache()
+    from distributedpytorch_tpu.utils.backend import (
+        enable_compilation_cache,
+        require_accelerator,
+    )
+
+    enable_compilation_cache()
 
     # Multi-process init must precede any other jax call (reference
     # train.py:58's init_process_group slot).
     from distributedpytorch_tpu.dist import initialize_from_env, shutdown
 
     runtime = initialize_from_env()
+    require_accelerator("train")
 
     from distributedpytorch_tpu.config import TrainConfig
     from distributedpytorch_tpu.train import Trainer

@@ -53,8 +53,7 @@ class TrainConfig:
     image_size: Tuple[int, int] = (960, 640)
     num_workers: int = 0  # host-side prefetch threads (0 = synchronous)
     # Device-placement prefetch depth: host→device transfer of batch i+1..i+k
-    # overlaps the device's compute of batch i (transfers are comparable to
-    # the step time on tunneled/remote runtimes). Applies to K-stacked
+    # overlaps the device's compute of batch i. Applies to K-stacked
     # dispatch payloads too (the whole stack/place pipeline runs on the
     # worker, see utils/prefetch.pipelined_placement). 0 = place
     # synchronously (the bitwise-identical baseline the equivalence tests
@@ -194,7 +193,7 @@ class TrainConfig:
     # dumps the step-timeline tracer's per-phase spans and requests a
     # checkpoint-and-stop via the collective stop agreement. 0 = off.
     # The FIRST executed epoch is untimed (it compiles every executable
-    # shape — minutes over a tunneled runtime — which would false-fire
+    # shape — a minute or more of compiles — which would false-fire
     # any steady-state-sized timeout); coverage starts at epoch 2.
     step_timeout_s: float = 0.0
     # Checkpoint retention: keep the newest N files per checkpoint path
@@ -234,10 +233,10 @@ class TrainConfig:
     #             (ops/fused_loss.py), one-pass eval stats
     #             (ops/pallas_kernels.py), the fused DoubleConv
     #             BN+ReLU epilogue (milesial), and the serve tier's
-    #             sigmoid/threshold mask kernel — each individually
-    #             revoked by a per-chip Mosaic probe priors file
-    #             (kernel_priors / DPT_KERNEL_PRIORS) that marks it
-    #             rejected, falling back bit-identically to XLA.
+    #             sigmoid/threshold mask kernel. A kernel Mosaic
+    #             refuses fails the run, unless a priors file the
+    #             operator hands over (kernel_priors /
+    #             DPT_KERNEL_PRIORS) marks it rejected.
     kernels: str = "xla"
     # Per-chip Mosaic probe priors file (tools/probe_kernels.py →
     # ops/kernels.load_priors): kernels the chip's compiler rejected
@@ -252,8 +251,8 @@ class TrainConfig:
     # -- dispatch amortization ----------------------------------------------
     # K optimizer steps per XLA dispatch (lax.scan over K stacked batches).
     # Semantically identical to K single steps on the same data; amortizes
-    # per-dispatch runtime latency, which dominates step time on remote /
-    # tunneled TPU runtimes. 1 = one dispatch per step (reference-shaped).
+    # per-dispatch runtime latency (its share of the step on the attached
+    # chip: not measured). 1 = one dispatch per step (reference-shaped).
     steps_per_dispatch: int = 1
 
     # -- gradient accumulation ----------------------------------------------

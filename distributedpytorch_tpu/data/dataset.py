@@ -35,10 +35,7 @@ from typing import Dict, Hashable, Optional, Sequence, Tuple
 import numpy as np
 from PIL import Image
 
-try:
-    from distributedpytorch_tpu.data import native
-except ImportError:  # pragma: no cover - a broken/absent native layer must
-    native = None  # never make the package unimportable (VERDICT.md round 2)
+from distributedpytorch_tpu.data import native
 
 logger = logging.getLogger(__name__)
 
@@ -224,7 +221,6 @@ class BasicDataset:
 
         if (
             self.use_native
-            and native is not None
             and native.supports(img_path)
             and native.supports(mask_path)
         ):

@@ -199,12 +199,9 @@ class DataLoader:
         supported formats."""
         ds = self.dataset
         if getattr(ds, "use_native", False) and hasattr(ds, "resolve_paths"):
-            try:
-                from distributedpytorch_tpu.data import native
-            except ImportError:  # missing native layer → per-item PIL path
-                native = None
+            from distributedpytorch_tpu.data import native
 
-            if native is not None and native.get_lib() is not None:
+            if native.get_lib() is not None:
                 paths = [ds.resolve_paths(int(i)) for i in idx_list]
                 if all(
                     native.supports(p) and native.supports(m) for p, m in paths

@@ -7,11 +7,13 @@ BICUBIC/NEAREST resize, /255 normalize, and NHWC assembly all happen in
 C++ threads (native/dpt_data.cpp dpt_load_batch) with no Python in the
 per-image loop.
 
-Everything degrades gracefully: if the shared library is absent and cannot
-be built (no toolchain, no libjpeg/libpng), `get_lib()` returns None and
-callers (data/dataset.py, data/loader.py) fall back to the PIL path. A
-missing or broken native layer must never make the package unimportable —
-that failure mode cost round 2 everything (VERDICT.md round 2).
+The library is built from the committed ``native/dpt_data.cpp`` +
+``Makefile``: `get_lib()` runs ``make`` every time (a no-op when the
+object is newer than its source), so whatever ``libdpt_data.so`` lies on
+disk is never trusted over the source. Where a toolchain is present, a
+failed build or load is an error. Only a machine with no ``make`` or no
+C++ compiler uses the PIL path, and says so at WARNING;
+:func:`decode_path` names the path that is live.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
+import shutil
 import subprocess
 import threading
 from os.path import splitext
@@ -44,25 +47,27 @@ def supports(path: str) -> bool:
     return splitext(str(path))[1].lower() in _SUPPORTED_EXTS
 
 
-def _build() -> bool:
-    """Build libdpt_data.so on demand via the Makefile. Best-effort."""
-    makefile = os.path.join(_NATIVE_DIR, "Makefile")
-    if not os.path.exists(makefile):
-        return False
-    try:
-        proc = subprocess.run(
-            ["make", "-C", os.path.abspath(_NATIVE_DIR), "libdpt_data.so"],
-            capture_output=True,
-            text=True,
-            timeout=120,
+def _toolchain() -> bool:
+    """make and a C++ compiler are both on PATH (CXX honoured like the
+    Makefile honours it)."""
+    cxx = os.environ.get("CXX", "g++").split()[0]
+    return bool(shutil.which("make") and shutil.which(cxx))
+
+
+def build(force: bool = False) -> None:
+    """Bring ``libdpt_data.so`` up to date with its committed source via
+    the Makefile; ``force`` rebuilds unconditionally (``make -B`` — what
+    the chip smoke does, so a stale object copied along with the tree is
+    never what runs). Raises ``RuntimeError`` when the build fails."""
+    cmd = ["make", "-C", os.path.abspath(_NATIVE_DIR), "libdpt_data.so"]
+    if force:
+        cmd.insert(1, "-B")
+    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
+    if proc.returncode != 0 or not os.path.exists(_LIB_PATH):
+        raise RuntimeError(
+            f"native data loader build failed (rc={proc.returncode}):\n"
+            f"{proc.stderr[-2000:]}"
         )
-    except (OSError, subprocess.TimeoutExpired) as exc:  # no make / hang
-        logger.info("native build unavailable: %s", exc)
-        return False
-    if proc.returncode != 0:
-        logger.info("native build failed:\n%s", proc.stderr[-2000:])
-        return False
-    return os.path.exists(_LIB_PATH)
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -91,30 +96,32 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """dlopen the native library, building it first if needed.
-
-    Returns None (and remembers the failure) when the library can't be
-    produced — callers then use the pure-Python PIL path.
-    """
+    """The native library, built from source first. None only where no
+    toolchain exists (callers then use the PIL path); with a toolchain,
+    a failed build or load raises."""
     global _lib, _lib_attempted
     if _lib is not None or _lib_attempted:
         return _lib
     with _lock:
         if _lib is not None or _lib_attempted:
             return _lib
-        _lib_attempted = True
-        if not os.path.exists(_LIB_PATH) and not _build():
-            logger.info("native data loader unavailable; using PIL path")
-            return None
-        try:
-            _lib = _bind(ctypes.CDLL(_LIB_PATH))
-            logger.info(
-                "native data loader: %s", _lib.dpt_version().decode()
+        if not _toolchain():
+            _lib_attempted = True
+            logger.warning(
+                "no make/C++ toolchain on PATH: native data loader not "
+                "built; decoding with PIL"
             )
-        except OSError as exc:
-            logger.info("native library failed to load: %s", exc)
-            _lib = None
+            return None
+        build()
+        _lib = _bind(ctypes.CDLL(_LIB_PATH))
+        _lib_attempted = True
+        logger.info("native data loader: %s", _lib.dpt_version().decode())
         return _lib
+
+
+def decode_path() -> str:
+    """Which decode path is live: ``"native"`` or ``"PIL"``."""
+    return "native" if get_lib() is not None else "PIL"
 
 
 def load_item(

@@ -62,13 +62,7 @@ def _enable_cpu_collectives() -> None:
     jaxlib; it just has to be selected BEFORE the backend initializes.
     Called only on the multi-process paths: single-process runs never
     need it, and on TPU backends the flag is simply unread."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jax without the flag: leave as-is
-        logger.warning(
-            "could not enable gloo CPU collectives; multi-process CPU "
-            "computations may be unavailable", exc_info=True,
-        )
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _warm_host_collectives() -> None:
@@ -122,8 +116,8 @@ def initialize_from_env(force: bool = False) -> RuntimeInfo:
 
     # Real multi-host TPU pods: argless initialize() autodetects the pod's
     # own coordinator from the TPU runtime/cloud metadata. Opt-in (env
-    # flag) because on single-host and tunneled setups the detection probes
-    # would stall startup.
+    # flag) because on a single host with no metadata server the
+    # detection probes would stall startup.
     if os.environ.get("DPT_JAX_AUTO_INIT") == "1":
         _enable_cpu_collectives()
         jax.distributed.initialize(**_init_timeout_kwargs())

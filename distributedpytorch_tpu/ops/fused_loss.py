@@ -37,9 +37,8 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from distributedpytorch_tpu.utils.compat import shard_map
 
 from distributedpytorch_tpu.ops.losses import (
     _LOG_SAFE_MIN,

@@ -17,8 +17,9 @@ policy     what engages
 ``xla``    nothing — every output is BIT-IDENTICAL to the historical
            paths (the correctness reference every kernel is pinned
            against)
-``pallas`` every engagement site below, each individually revocable by
-           the per-chip Mosaic probe priors (``apply_priors``)
+``pallas`` every engagement site below; a kernel Mosaic refuses fails
+           the run, unless the operator hands over a priors file that
+           marks it rejected (``apply_priors``)
 =========  =================================================================
 
 Engagement sites (the full table lives in docs/PERFORMANCE.md
@@ -50,9 +51,12 @@ Engagement sites (the full table lives in docs/PERFORMANCE.md
 (``PROBES`` — the ``wgrad_pallas_probe`` pattern generalized): lower +
 compile at a representative shape, record accepted-or-rejected with the
 Mosaic reason, ZERO execution. ``tools/probe_kernels.py`` runs the
-registry on a chip window and writes a per-chip priors file;
-``apply_priors`` turns rejected kernels off in the resolved policy
-(bit-identical fallback), and ``analysis/planner.py --kernel-priors``
+registry on the chip and writes a per-chip priors file (exit code
+non-zero on any refusal). The file is an EXPLICIT input
+(``--kernel-priors`` / ``$DPT_KERNEL_PRIORS``): only then does
+``apply_priors`` turn the kernels it marks rejected off in the resolved
+policy; with no priors given nothing is probed and a refused kernel
+fails the run at compile time. ``analysis/planner.py --kernel-priors``
 consumes the same file as a search axis — ``plan`` rejects
 Mosaic-rejected kernel points with zero device time and ranks kernel-on
 vs kernel-off configs.
@@ -76,20 +80,14 @@ from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from distributedpytorch_tpu.ops.precision import (
     LOSS_DTYPE,
     NORM_DTYPE,
     WGRAD_DTYPE,
 )
-
-try:  # TPU-specific memory spaces; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from distributedpytorch_tpu.utils.backend import pallas_interpret
 
 logger = logging.getLogger(__name__)
 
@@ -98,13 +96,6 @@ LANES = 128  # TPU vector lane width (pallas_kernels.py contract)
 #: 256 KB at C=128 and 2 MB at the deepest milesial width (C=1024) —
 #: comfortably VMEM-resident with in+out+params live.
 BLOCK_ROWS = 512
-
-
-def _auto_interpret() -> bool:
-    """Real Mosaic lowering on TPU; the Pallas interpreter elsewhere
-    (CPU test meshes, GPU). One place decides — callers pass
-    interpret=None."""
-    return jax.devices()[0].platform != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -367,13 +358,13 @@ def _rows_of(x: jax.Array) -> Tuple[jax.Array, int]:
 
 
 def _spec(block, index_map, interpret):
-    if interpret or _VMEM is None:
+    if interpret:
         return pl.BlockSpec(block, index_map)
-    return pl.BlockSpec(block, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
 
 def _sequential_grid_params(interpret):
-    if interpret or pltpu is None:
+    if interpret:
         return {}
     # sequential grid: the accumulator output block is carried across
     # steps (the wgrad_pallas.py pattern)
@@ -404,7 +395,7 @@ def fused_bn_act(
     (pallas_call has no GSPMD partition rule — see
     ``conv_epilogue_engaged``)."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = pallas_interpret()
     return _fused_bn_act_p(
         x, mean, var, scale, bias, float(epsilon), bool(interpret)
     )
@@ -513,14 +504,17 @@ def sigmoid_threshold_mask(
     ``threshold`` is trace-time static (the serve tier compiles one
     executable per bucket at a fixed operating point)."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = pallas_interpret()
     thr = float(threshold)
 
     def kernel(x_ref, o_ref):
         v = x_ref[:].astype(LOSS_DTYPE)
         if from_logits:
             v = jax.nn.sigmoid(v)
-        o_ref[:] = jnp.where(v >= thr, jnp.uint8(255), jnp.uint8(0))
+        # select in int32, then narrow: Mosaic on v5e refuses the
+        # i1 -> uint8 select directly (it cannot move the (512, 128)
+        # predicate into the packed 8-bit tiling)
+        o_ref[:] = jnp.where(v >= thr, 255, 0).astype(jnp.uint8)
 
     flat = x.reshape(-1)
     n = flat.shape[0]
@@ -594,9 +588,10 @@ def _probe_wgrad_9tap():
 
 #: The probe registry: kernel name → a compile-only callable (AOT
 #: ``lower().compile()``, ZERO execution — the wgrad_pallas_probe
-#: pattern per kernel). On TPU the auto-interpret gate resolves to real
-#: Mosaic lowering, so an exception IS the chip's accept/reject verdict;
-#: elsewhere the interpreter path compiles, proving the machinery.
+#: pattern per kernel). On a TPU utils/backend.pallas_interpret resolves
+#: to real Mosaic lowering, so an exception IS the chip's accept/reject
+#: verdict; on an operator-named CPU the interpreter path compiles,
+#: proving the machinery.
 PROBES: Dict[str, Callable[[], None]] = {
     "eval_stats": _probe_eval_stats,
     "fused_loss": _probe_fused_loss,
@@ -611,9 +606,9 @@ def run_probes(
     emit: Optional[Callable[[dict], None]] = None,
 ) -> dict:
     """Run the (selected) probe registry; returns the priors payload
-    (what ``save_priors`` writes). Never raises on a probe failure —
-    a Mosaic rejection is a RESULT (recorded with its reason), not an
-    error."""
+    (what ``save_priors`` writes). A Mosaic rejection is this
+    function's RESULT (recorded with its reason): the caller decides
+    what it costs — tools/probe_kernels.py exits non-zero on one."""
     selected = list(names) if names else sorted(PROBES)
     unknown = [n for n in selected if n not in PROBES]
     if unknown:

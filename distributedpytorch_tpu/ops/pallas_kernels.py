@@ -30,21 +30,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # The stats accumulators spell the LOSS_DTYPE contract (ops/precision.py):
 # loss/Dice statistics accumulate f32 under every --dtype policy — the
 # dptlint ``dtype-policy`` rule reaches kernel bodies, and these named
 # constants are its sanctioned spelling (this module is no longer exempt).
 from distributedpytorch_tpu.ops.precision import LOSS_DTYPE
-
-try:  # TPU-specific memory spaces; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _SMEM = pltpu.SMEM
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _SMEM = _VMEM = None
+from distributedpytorch_tpu.utils.backend import pallas_interpret
 
 _LOG_CLAMP = -100.0  # torch BCELoss log clamp (ops/losses.py)
 
@@ -76,19 +69,13 @@ def _stats_kernel(p_ref, t_ref, out_ref):
     out_ref[0, 5] += jnp.sum(pb) + jnp.sum(tb)  # hard-dice union
 
 
-def _auto_interpret() -> bool:
-    """Real Mosaic lowering on TPU; the Pallas interpreter elsewhere (CPU
-    test meshes, GPU). One place decides — callers pass interpret=None."""
-    return jax.devices()[0].platform != "tpu"
-
-
 def _stats_call(p2, t2, n, num_blocks, interpret):
     # no jit here: n/num_blocks/grid must stay static, and callers (the
     # jitted eval step; tests) already run this under their own trace
-    if not interpret and _SMEM is not None:
-        in_space, out_space = _VMEM, _SMEM
-    else:  # interpreter has no TPU memory spaces
+    if interpret:  # the interpreter has no TPU memory spaces
         in_space = out_space = None
+    else:
+        in_space, out_space = pltpu.VMEM, pltpu.SMEM
 
     def spec(block, index_map, space):
         if space is None:
@@ -131,13 +118,14 @@ def eval_stats_pallas(
     p + tb = 0 — so no masking is needed in the kernel; the true element
     count is patched in outside.
 
-    `interpret=None` auto-selects: Mosaic on TPU, interpreter elsewhere.
+    `interpret=None` follows utils/backend.pallas_interpret: Mosaic on a
+    TPU, the interpreter on an operator-named CPU, an error otherwise.
     The inputs must be unsharded (single device or replicated): pallas_call
     has no GSPMD partitioning rule, so callers on sharded meshes must not
     route sharded arrays here (see make_eval_step's gating).
     """
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = pallas_interpret()
     p = outputs.astype(LOSS_DTYPE).reshape(-1)
     t = targets.astype(LOSS_DTYPE).reshape(-1)
     n = p.size

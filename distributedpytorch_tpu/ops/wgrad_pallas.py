@@ -48,25 +48,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # The tap accumulator spells the WGRAD_DTYPE contract (ops/precision.py):
 # weight-gradient accumulation is f32 under every --dtype policy — the
 # dptlint ``dtype-policy`` rule reaches kernel bodies, and the named
 # constant is its sanctioned spelling (this module is no longer exempt).
 from distributedpytorch_tpu.ops.precision import WGRAD_DTYPE
-
-try:  # TPU-specific memory space; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
-
-def _auto_interpret() -> bool:
-    """Real Mosaic lowering on TPU; the Pallas interpreter elsewhere."""
-    return jax.devices()[0].platform != "tpu"
+from distributedpytorch_tpu.utils.backend import pallas_interpret
 
 
 def _wgrad_kernel(x0, x1, x2, d0, d1, d2, out_ref):
@@ -97,7 +86,7 @@ def wgrad_9tap_pallas(
     """Weight gradient of a SAME stride-1 3×3 NHWC conv: returns
     dW (3, 3, Cin, Cout) in float32 (callers cast to the kernel dtype)."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = pallas_interpret()
     b, h, w, cin = x.shape
     cout = dy.shape[-1]
     xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))  # (B, H+2, W+2, Cin)
@@ -107,7 +96,7 @@ def wgrad_9tap_pallas(
         for kx in range(3)
     ]
 
-    in_space = _VMEM if (not interpret and _VMEM is not None) else None
+    in_space = None if interpret else pltpu.VMEM
 
     def spec(block, index_map):
         if in_space is None:
@@ -125,7 +114,7 @@ def wgrad_9tap_pallas(
     out_spec = spec((3, 3, cin, cout), lambda bi, yi: (0, 0, 0, 0))
 
     kwargs = {}
-    if not interpret and pltpu is not None:
+    if not interpret:
         # sequential grid: the output block accumulates across steps
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")

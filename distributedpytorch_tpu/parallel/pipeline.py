@@ -117,9 +117,8 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from distributedpytorch_tpu.utils.compat import shard_map
 
 from distributedpytorch_tpu.ops.losses import bce_dice_stats, loss_from_stats
 # The stated f32 contracts (ops/precision.py, docs/PERFORMANCE.md

@@ -69,13 +69,18 @@ def _validate_pipeline_schedule(config: TrainConfig) -> None:
 
 def _state_donation(config: Optional[TrainConfig] = None) -> tuple:
     """``donate_argnums`` for the jitted train steps: donating the state
-    halves HBM pressure on accelerators (in-place Adam update), but the
-    jax 0.4.37 CPU client intermittently ABORTS (native SIGABRT/SIGSEGV,
-    no Python traceback) when donated executables from sequentially-built
-    trainers run in one process — reproduced at ~40-50% on the restart
-    tests (two Trainers per process) and ~10% on a plain resume, 0/15
-    with donation off, seed code either way. CPU donation saves nothing
-    (buffers are host RAM regardless), so donate only off-CPU.
+    halves HBM pressure on accelerators (in-place Adam update). Not on
+    the CPU backend: buffers there are host RAM, so donation saves
+    nothing, and callers that hold one initial state and feed it to
+    several step functions (the strategy-equivalence tests, the static
+    analyzer) would find it deleted after the first.
+
+    History: the gate was added for a jax 0.4.37 CPU-client abort
+    (native SIGABRT when donated executables of sequentially-built
+    trainers ran in one process, ~40-50% of restart-test runs). Re-tested
+    in PR 21 on jax 0.9.0 with donation forced on:
+    ``test_fit_with_restarts_resumes_after_crash`` 20/20 clean — the
+    abort is gone, and the reason above is the one that remains.
 
     ``nonfinite_policy='skip'`` also disables donation everywhere: the
     trainer holds the PREVIOUS state across each step so a non-finite

@@ -632,6 +632,13 @@ def main(argv=None) -> int:
 
     args = get_args(argv)
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    from distributedpytorch_tpu.utils.backend import (
+        enable_compilation_cache,
+        require_accelerator,
+    )
+
+    enable_compilation_cache()
+    require_accelerator("serve")
     heartbeat = None
     if args.heartbeat_dir:
         # beat FIRST — the engine's AOT compiles take long enough that a

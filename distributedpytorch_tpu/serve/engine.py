@@ -204,7 +204,7 @@ class ServeEngine:
             key = meta = exe = None
             if self.aot_store is not None:
                 key, meta = self._entry_key(b, device)
-                exe = self.aot_store.load(key, meta)
+                exe = self.aot_store.load(key, meta, device)
             if exe is None:
                 exe = self._compile_bucket(jitted, vars_dev, x_sds)
                 if self.aot_store is not None:

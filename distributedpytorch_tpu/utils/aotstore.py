@@ -315,12 +315,19 @@ class AOTStore:
         os.replace(tmp, path)
 
     # -- load ----------------------------------------------------------------
-    def load(self, key: str, meta: dict):
-        """The executable for ``key``, or None. No file = ``miss``; a
-        file that is torn, schema-broken, runtime-skewed, or whose
-        recorded identity disagrees with ``meta`` = ``skew`` — refused
-        with a logged note, never loaded, never a crash. A hit bumps
-        the entry's mtime (the ``gc`` LRU clock)."""
+    def load(self, key: str, meta: dict, device):
+        """The executable for ``key`` loaded onto ``device``, or None.
+        No file = ``miss``; a file that is torn, schema-broken,
+        runtime-skewed, or whose recorded identity disagrees with
+        ``meta`` = ``skew`` — refused with a logged note, never loaded,
+        never a crash. A hit bumps the entry's mtime (the ``gc`` LRU
+        clock).
+
+        ``device`` is the replica's device: a one-device executable
+        deserialized without ``execution_devices`` is loaded across
+        every device of the backend and then refuses its one-shard
+        arguments — on any host with more than one device, and for
+        every replica that is not device 0."""
         path = self._path(key)
         if not os.path.exists(path):
             self.stats["miss"] += 1
@@ -336,7 +343,10 @@ class AOTStore:
 
                 blob, in_tree, out_tree = pickle.loads(payload)
                 with no_xla_compilation_cache():
-                    compiled = deserialize_and_load(blob, in_tree, out_tree)
+                    compiled = deserialize_and_load(
+                        blob, in_tree, out_tree,
+                        execution_devices=[device],
+                    )
             else:
                 raise AOTEntryError(reason)
         except Exception as exc:  # noqa: BLE001 — every failure mode of

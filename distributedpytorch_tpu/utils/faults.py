@@ -12,7 +12,7 @@ is an untested path. This module provides:
     ``site:epoch:step[:count]`` strings (``*`` wildcards), armed via
     ``Config.inject_faults`` / CLI ``--inject-fault``, and fire
     deterministically at their (epoch, step) coordinates;
-  * the transient-error taxonomy the retry machinery keys on
+  * the transient-error classes the retry machinery keys on
     (:data:`TRANSIENT_ERRORS`, :func:`call_with_retries` — bounded
     exponential backoff shared by the decode and placement retry paths);
   * :class:`StepWatchdog` — the host-side dispatch watchdog the trainer

@@ -77,7 +77,7 @@ class LossRecords:
         self.images_seen = 0
         # Steady-state throughput reference point: set when the FIRST train
         # step has been recorded, so XLA compile + warmup of step 1 are
-        # excluded from images_per_second (VERDICT.md round 2 item 10).
+        # excluded from images_per_second.
         self._steady_t0: Optional[float] = None
         self._steady_images0 = 0
 

@@ -4,8 +4,8 @@ Two variants of the same submit-ahead/pop/yield shape, differing in who
 runs the work and what happens when the consumer walks away:
 
 * :func:`bounded_prefetch` — a single daemon worker thread. For work that
-  may block indefinitely on an external runtime (host→device placement on
-  a remote/tunneled TPU): a daemon thread can never block interpreter
+  may block indefinitely on an external runtime (host→device
+  placement): a daemon thread can never block interpreter
   exit, and closing the generator (or breaking out of a ``for``) stops the
   worker within its put-poll interval instead of leaving it wedged on a
   full queue pinning device buffers.

@@ -110,6 +110,68 @@ class TestEntryKey:
         assert meta0["input_shape"] == [2, 32, 48, 3]
 
 
+class TestLoadPinnedToItsDevice:
+    """A store entry is a ONE-device executable. Loaded without
+    ``execution_devices`` the installed jax spreads it over every device
+    of the backend and it then refuses its one-shard arguments ("Expected
+    args to execute_sharded_on_local_devices to have 8 shards") — on any
+    host with more than one device, and for every replica that is not
+    device 0. ``AOTStore.load`` pins the load to the replica's device."""
+
+    def _engine(self, pieces, store_dir, replicas):
+        from distributedpytorch_tpu.serve.engine import ServeEngine
+
+        model, params, model_state = pieces
+        return ServeEngine(
+            model, params, model_state, input_hw=SIZE_HW,
+            bucket_sizes=BUCKETS, replicas=replicas, host_cache_mb=0,
+            aot_cache=str(store_dir), engine_fingerprint=FP,
+        )
+
+    def test_warm_start_of_a_replica_that_is_not_device_zero(
+        self, pieces, tmp_path, devices
+    ):
+        """Three replicas cold, then warm: every replica — devices 1 and
+        2 included — loads its buckets with zero compiles, runs them on
+        ITS device, and answers bit-identically."""
+        cold = self._engine(pieces, tmp_path / "store", replicas=3)
+        assert cold.aot_compiles == 3 * len(BUCKETS)
+        hot = self._engine(pieces, tmp_path / "store", replicas=3)
+        assert hot.aot_compiles == 0
+        assert hot.aot_cache_stats["hit"] == 3 * len(BUCKETS)
+        rng = np.random.default_rng(11)
+        for index in (1, 2):
+            replica = hot.replicas[index]
+            assert replica.device == devices[index]
+            for n in BUCKETS:
+                batch = rng.random((n, *SIZE_HW, 3)).astype(np.float32)
+                out = hot.run(replica, hot.place(replica, batch))
+                assert out.devices() == {devices[index]}
+                np.testing.assert_array_equal(
+                    np.asarray(out), cold.infer(batch, replica_index=index)
+                )
+
+    def test_load_runs_on_the_device_it_is_given(
+        self, pieces, tmp_path, devices
+    ):
+        """The store API itself: an entry compiled for device 5 and
+        loaded with ``device=devices[5]`` executes there; the same bytes
+        are never usable through a device-0 default."""
+        import jax
+
+        engine = self._engine(pieces, tmp_path / "one", replicas=1)
+        store = engine.aot_store
+        dev = devices[5]
+        replica = engine._build_replica(5, dev, engine.replicas[0].variables)
+        key, meta = engine._entry_key(BUCKETS[0], dev)
+        exe = store.load(key, meta, dev)
+        assert exe is not None
+        x = jax.device_put(
+            np.zeros((BUCKETS[0], *SIZE_HW, 3), np.float32), replica.sharding
+        )
+        assert exe(replica.variables, x).devices() == {dev}
+
+
 class TestColdThenWarm:
     def test_cold_build_persists_every_bucket(self, warm):
         root, engine = warm

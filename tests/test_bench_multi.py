@@ -83,8 +83,7 @@ class TestMainLoop:
     def _fake_bench(self, results):
         """A stand-in for the bench module: run() pops from `results`
         (dict → return, Exception → raise)."""
-        mod = types.SimpleNamespace(BATCH=4, H=640, W=960, ARCH="unet",
-                                    _START=0.0)
+        mod = types.SimpleNamespace(BATCH=4, H=640, W=960, ARCH="unet")
 
         def run():
             r = results.pop(0)
@@ -95,26 +94,12 @@ class TestMainLoop:
         mod.run = run
         return mod
 
-    def _patch(self, monkeypatch, tmp_path, probe_ok, fake_mod, configs,
-               probes=None):
-        """probe_ok sets a constant probe result; probes (a list) makes
-        successive _probe_once calls pop from it instead (the liveness
-        re-probe after a retryable exception)."""
+    def _patch(self, monkeypatch, tmp_path, fake_mod, configs):
         monkeypatch.setattr(bench_multi, "CONFIGS", configs)
         monkeypatch.setattr(
             bench_multi, "_CONFIG_ENV_KEYS",
             sorted({k for _, env, _ in configs for k in env}))
-
-        def probe(t):
-            if probes is not None:
-                return probes.pop(0)
-            return ({"ok": True, "platform": "tpu"} if probe_ok
-                    else {"ok": False, "error": "probe timeout"})
-
         # main() imports bench lazily; plant the fake in sys.modules
-        fake_mod._probe_once = probe
-        fake_mod.acquire_client_lock = lambda *a, **k: True
-        fake_mod.release_client_lock = lambda: None
         monkeypatch.setitem(sys.modules, "bench", fake_mod)
 
     def test_all_configs_measured(self, tmp_path, monkeypatch):
@@ -122,7 +107,7 @@ class TestMainLoop:
         configs = [("a", {"BENCH_S2D_LEVELS": "0"}, 60.0),
                    ("b", {"BENCH_BATCH": "8"}, 60.0)]
         mod = self._fake_bench([{"value": 1.0}, {"value": 2.0}])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         rc = bench_multi.main(["--out", out])
         assert rc == 0
         state = bench_multi.load_state(out)
@@ -140,91 +125,24 @@ class TestMainLoop:
         ])
         configs = [("a", {}, 60.0), ("b", {}, 60.0), ("c", {}, 60.0)]
         mod = self._fake_bench([{"value": 3.0}])  # only c should run
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         rc = bench_multi.main(["--out", out])
         assert rc == 0
         assert bench_multi.load_state(out) == {
             "a": "ok", "b": "poison", "c": "ok"}
 
-    def test_runtime_death_stops_sequence_innocent(
+    def test_runtime_error_is_that_configs_error(
             self, tmp_path, monkeypatch):
-        """A RuntimeError mid-sequence whose liveness probe AND every
-        backed-off re-probe fail marks that config innocent (retryable
-        next window) and stops — later configs stay unattempted, so the
-        program exits nonzero and the watcher re-fires."""
-        out = str(tmp_path / "m.jsonl")
-        configs = [("a", {}, 60.0), ("b", {}, 60.0), ("c", {}, 60.0)]
-        mod = self._fake_bench(
-            [{"value": 1.0}, RuntimeError("UNAVAILABLE: relay gone")])
-        dead = {"ok": False, "error": "probe timeout"}
-        self._patch(monkeypatch, tmp_path, True, mod, configs, probes=[
-            {"ok": True, "platform": "tpu"},   # session start
-            dead,                              # after the raise
-            # the exponential-backoff re-probes, all dead
-            dead, dead, dead, dead,
-        ])
-        sleeps = []
-        monkeypatch.setattr(bench_multi.time, "sleep", sleeps.append)
-        rc = bench_multi.main(["--out", out])
-        assert rc == 4
-        # backoff actually backed off: 5, 10, 20 between re-probes
-        assert sleeps == [5.0, 10.0, 20.0]
-        state = bench_multi.load_state(out)
-        assert state == {"a": "ok", "b": "innocent"}
-        assert "c" not in state
-
-    def test_flapping_runtime_recovers_and_continues(
-            self, tmp_path, monkeypatch):
-        """THE r05 window-burner: a runtime that answers dead right after
-        a config failure but comes back during the backed-off re-probes.
-        The failed config is innocent (retried next invocation) and the
-        SEQUENCE CONTINUES — the window is not returned."""
-        out = str(tmp_path / "m.jsonl")
-        configs = [("a", {}, 60.0), ("b", {}, 60.0), ("c", {}, 60.0)]
-        mod = self._fake_bench(
-            [{"value": 1.0}, RuntimeError("UNAVAILABLE: relay gone"),
-             {"value": 3.0}])
-        dead = {"ok": False, "error": "probe timeout"}
-        alive = {"ok": True, "platform": "tpu"}
-        self._patch(monkeypatch, tmp_path, True, mod, configs, probes=[
-            alive,        # session start
-            dead,         # after the raise
-            dead, alive,  # backoff re-probes: flap ends
-        ])
-        monkeypatch.setattr(bench_multi.time, "sleep", lambda s: None)
-        rc = bench_multi.main(["--out", out])
-        state = bench_multi.load_state(out)
-        assert state == {"a": "ok", "b": "innocent", "c": "ok"}
-        assert rc == 1  # b remains unmeasured → refire
-
-    def test_channel_blip_with_live_runtime_is_innocent(
-            self, tmp_path, monkeypatch):
-        """A channel-shaped error (UNAVAILABLE/connection/...) while the
-        probe still answers: the in-process client blipped — the config
-        must stay retryable (innocent), NOT be poisoned as permanent."""
-        out = str(tmp_path / "m.jsonl")
-        configs = [("a", {}, 60.0), ("b", {}, 60.0)]
-        mod = self._fake_bench(
-            [RuntimeError("UNAVAILABLE: socket closed mid-dispatch"),
-             {"value": 2.0}])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
-        rc = bench_multi.main(["--out", out])
-        assert bench_multi.load_state(out) == {
-            "a": "innocent", "b": "ok"}
-        assert rc == 1  # a remains unmeasured → refire
-
-    def test_runtime_error_with_live_runtime_is_permanent(
-            self, tmp_path, monkeypatch):
-        """JAX raises deterministic config failures as XlaRuntimeError (a
-        RuntimeError subclass); if the liveness re-probe still answers,
-        the config is marked permanent and the sequence CONTINUES — a
-        broken config must not starve the ones ordered after it."""
+        """An exception in a config — XlaRuntimeError included — is that
+        config's error: no probe child is asked for a second opinion
+        (this process holds the chip), the config is marked permanent
+        and the sequence CONTINUES."""
         out = str(tmp_path / "m.jsonl")
         configs = [("a", {}, 60.0), ("b", {}, 60.0)]
         mod = self._fake_bench(
             [RuntimeError("INVALID_ARGUMENT: bad lowering"),
              {"value": 2.0}])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         rc = bench_multi.main(["--out", out])
         assert rc == 0
         assert bench_multi.load_state(out) == {
@@ -234,27 +152,18 @@ class TestMainLoop:
         out = str(tmp_path / "m.jsonl")
         configs = [("a", {}, 60.0), ("b", {}, 60.0)]
         mod = self._fake_bench([ValueError("bad"), {"value": 2.0}])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         rc = bench_multi.main(["--out", out])
         assert rc == 0  # both terminally resolved (permanent + ok)
         assert bench_multi.load_state(out) == {
             "a": "permanent", "b": "ok"}
-
-    def test_dead_runtime_at_start(self, tmp_path, monkeypatch):
-        out = str(tmp_path / "m.jsonl")
-        configs = [("a", {}, 60.0)]
-        mod = self._fake_bench([])
-        self._patch(monkeypatch, tmp_path, False, mod, configs)
-        rc = bench_multi.main(["--out", out])
-        assert rc == 2
-        assert "a" not in bench_multi.load_state(out)
 
     def test_nothing_todo(self, tmp_path, monkeypatch):
         out = str(tmp_path / "m.jsonl")
         _write(out, [{"config": "a", "value": 1.0}])
         configs = [("a", {}, 60.0)]
         mod = self._fake_bench([])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         assert bench_multi.main(["--out", out]) == 0
 
     def test_compile_only_probe_config(self):
@@ -277,8 +186,7 @@ class TestMainLoop:
         they are frozen from env at bench import and would otherwise
         mislabel every non-default config's metric series."""
         captured = {}
-        mod = types.SimpleNamespace(BATCH=4, H=640, W=960, ARCH="unet",
-                                    _START=0.0)
+        mod = types.SimpleNamespace(BATCH=4, H=640, W=960, ARCH="unet")
 
         def run():
             captured.update(BATCH=mod.BATCH, ARCH=mod.ARCH,
@@ -291,7 +199,6 @@ class TestMainLoop:
             mod, "x", {"BENCH_BATCH": "8", "BENCH_ARCH": "milesial",
                        "BENCH_WGRAD_TAPS": "1"}, 60.0)
         assert captured == {"BATCH": 8, "ARCH": "milesial", "taps": "1"}
-        assert mod._START > 0.0
         for k in ("BENCH_WGRAD_TAPS", "BENCH_ARCH", "BENCH_BATCH"):
             os.environ.pop(k, None)
 
@@ -344,7 +251,7 @@ class TestStaticPreflight:
         configs = [("sweep", {"BENCH_PIPELINE_SWEEP": "1"}, 300.0),
                    ("a", {}, 60.0)]
         mod = TestMainLoop._fake_bench(None, [{"value": 1.0}])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
         finding = ("[ppermute-deadlock] MP/1f1b train step: "
                    "tick-program deadlock: flipped edge")
         calls = []
@@ -381,7 +288,7 @@ class TestStaticPreflight:
         out = str(tmp_path / "m.jsonl")
         configs = [("sweep", {"BENCH_PIPELINE_SWEEP": "1"}, 300.0)]
         mod = TestMainLoop._fake_bench(None, [])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
         monkeypatch.setattr(
             bench_multi, "_run_analyze", lambda *a: (0, []))
         import tools.bench_pipeline as bp
@@ -396,7 +303,7 @@ class TestStaticPreflight:
         out = str(tmp_path / "m.jsonl")
         configs = [("sweep", {"BENCH_PIPELINE_SWEEP": "1"}, 300.0)]
         mod = TestMainLoop._fake_bench(None, [])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
         monkeypatch.setattr(
             bench_multi, "_run_analyze",
             lambda *a: (2, ["analyzer did not run: TimeoutExpired"]))
@@ -413,7 +320,7 @@ class TestStaticPreflight:
         out = str(tmp_path / "m.jsonl")
         configs = [("a", {"BENCH_BATCH": "8"}, 60.0)]
         mod = TestMainLoop._fake_bench(None, [{"value": 1.0}])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
 
         def never(*a):
             raise AssertionError("preflight ran for a collective-free "
@@ -446,7 +353,7 @@ class TestServeBenchConfig:
         out = str(tmp_path / "m.jsonl")
         configs = [("serve_bench", {"BENCH_SERVE": "1"}, 600.0)]
         mod = TestMainLoop._fake_bench(None, [])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
 
         def never(*a):
             raise AssertionError("preflight ran for the collective-free "
@@ -469,8 +376,8 @@ class TestServeBenchConfig:
 
 class TestFlightArtifacts:
     """ISSUE 7: every leg's result row names its flight-recorder
-    artifact path, and a poisoned/dead-probe leg dumps the ring buffer
-    at mark time — a dead chip-window leg ships its own post-mortem."""
+    artifact path, and a poisoned or failed leg dumps the ring buffer
+    at mark time — a dead leg ships its own post-mortem."""
 
     _fake_bench = TestMainLoop._fake_bench
     _patch = TestMainLoop._patch
@@ -479,33 +386,13 @@ class TestFlightArtifacts:
         out = str(tmp_path / "m.jsonl")
         configs = [("a", {}, 60.0)]
         mod = self._fake_bench([{"value": 1.0}])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         assert bench_multi.main(["--out", out]) == 0
         rows = [d for d in _lines(out) if d.get("config") == "a"
                 and "error" not in d and d.get("event") is None]
         assert rows and rows[0]["flight_recorder"] == (
             bench_multi.flight_artifact_path(out, "a")
         )
-
-    def test_injected_probe_death_dumps_parseable_artifact(
-            self, tmp_path, monkeypatch):
-        """Dead probe at session start (rc=2) ⇒ the ring is dumped and
-        the session_end line references an artifact that parses."""
-        from distributedpytorch_tpu.obs import flight
-
-        flight.record("span", phase="dispatch", step=3)
-        out = str(tmp_path / "m.jsonl")
-        configs = [("a", {}, 60.0)]
-        mod = self._fake_bench([])
-        self._patch(monkeypatch, tmp_path, False, mod, configs)
-        assert bench_multi.main(["--out", out]) == 2
-        end = [d for d in _lines(out) if d.get("event") == "session_end"][-1]
-        artifact = end["flight_recorder"]
-        assert artifact == bench_multi.flight_artifact_path(out, "session")
-        d = json.load(open(artifact))
-        assert d["reason"] == "dead_probe_at_start"
-        assert d["extra"]["probe"]["ok"] is False
-        assert any(e.get("phase") == "dispatch" for e in d["events"])
 
     def test_config_error_dumps_and_references_artifact(
             self, tmp_path, monkeypatch):
@@ -515,7 +402,7 @@ class TestFlightArtifacts:
         out = str(tmp_path / "m.jsonl")
         configs = [("a", {}, 60.0)]
         mod = self._fake_bench([ValueError("deterministically broken")])
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         assert bench_multi.main(["--out", out]) == 0
         row = [d for d in _lines(out)
                if d.get("config") == "a" and "error" in d][0]
@@ -558,7 +445,7 @@ class TestSupervisorRestarts:
         out = str(tmp_path / "m.jsonl")
         configs = [("a", {"BENCH_S2D_LEVELS": "0"}, 60.0)]
         mod = TestMainLoop._fake_bench(None, [{"value": 1.0}])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
         assert bench_multi.main(["--out", out]) == 0
         lines = [json.loads(x) for x in open(out) if x.strip()]
         start = [d for d in lines if d.get("event") == "session_start"]
@@ -596,7 +483,7 @@ class TestDtypeSweepConfig:
         out = str(tmp_path / "m.jsonl")
         configs = [("dtype_sweep", {"BENCH_DTYPE_SWEEP": "1"}, 900.0)]
         mod = TestMainLoop._fake_bench(None, [])
-        TestMainLoop._patch(None, monkeypatch, tmp_path, True, mod, configs)
+        TestMainLoop._patch(None, monkeypatch, tmp_path, mod, configs)
 
         def never(*a):
             raise AssertionError("preflight ran for the collective-free "
@@ -658,8 +545,7 @@ class TestPlanOrdering:
     def _ordered_bench(self, order):
         """A fake bench whose run() records which config's levers were
         active — the execution order probe."""
-        mod = types.SimpleNamespace(BATCH=4, H=640, W=960, ARCH="unet",
-                                    _START=0.0)
+        mod = types.SimpleNamespace(BATCH=4, H=640, W=960, ARCH="unet")
 
         def run():
             order.append((mod.BATCH, os.environ.get("BENCH_S2D_LEVELS")))
@@ -672,7 +558,7 @@ class TestPlanOrdering:
         out = str(tmp_path / "m.jsonl")
         order = []
         mod = self._ordered_bench(order)
-        self._patch(monkeypatch, tmp_path, True, mod, self.CONFIGS)
+        self._patch(monkeypatch, tmp_path, mod, self.CONFIGS)
         rc = bench_multi.main(
             ["--out", out, "--plan", self._plan_file(tmp_path)])
         assert rc == 0
@@ -698,7 +584,7 @@ class TestPlanOrdering:
         out = str(tmp_path / "m.jsonl")
         order = []
         mod = self._ordered_bench(order)
-        self._patch(monkeypatch, tmp_path, True, mod, configs)
+        self._patch(monkeypatch, tmp_path, mod, configs)
         rc = bench_multi.main(
             ["--out", out, "--plan", self._plan_file(tmp_path)])
         assert rc == 0
@@ -715,7 +601,7 @@ class TestPlanOrdering:
         out = str(tmp_path / "m.jsonl")
         order = []
         mod = self._ordered_bench(order)
-        self._patch(monkeypatch, tmp_path, True, mod, self.CONFIGS)
+        self._patch(monkeypatch, tmp_path, mod, self.CONFIGS)
         rc = bench_multi.main(
             ["--out", out, "--plan", str(tmp_path / "missing.json")])
         assert rc == 0
@@ -737,7 +623,7 @@ class TestPlanOrdering:
         out = str(tmp_path / "m.jsonl")
         order = []
         mod = self._ordered_bench(order)
-        self._patch(monkeypatch, tmp_path, True, mod, self.CONFIGS)
+        self._patch(monkeypatch, tmp_path, mod, self.CONFIGS)
         rc = bench_multi.main(["--out", out, "--plan", str(stale)])
         assert rc == 0
         assert order == [(4, "0"), (8, None)]
@@ -765,7 +651,7 @@ class TestPlanOrdering:
         out = str(tmp_path / "m.jsonl")
         order = []
         mod = self._ordered_bench(order)
-        self._patch(monkeypatch, tmp_path, True, mod, self.CONFIGS)
+        self._patch(monkeypatch, tmp_path, mod, self.CONFIGS)
         rc = bench_multi.main(["--out", out, "--plan", str(bad)])
         assert rc == 0
         assert order == [(4, "0"), (8, None)]  # default order kept
@@ -776,7 +662,7 @@ class TestPlanOrdering:
         out = str(tmp_path / "m.jsonl")
         order = []
         mod = self._ordered_bench(order)
-        self._patch(monkeypatch, tmp_path, True, mod, self.CONFIGS)
+        self._patch(monkeypatch, tmp_path, mod, self.CONFIGS)
         assert bench_multi.main(["--out", out]) == 0
         assert order == [(4, "0"), (8, None)]
         start = [d for d in _lines(out)

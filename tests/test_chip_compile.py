@@ -1,0 +1,238 @@
+"""Compile the main path for the real chip, without the chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is described, not attached (the `on-chip-measurement` guide, §2).
+Every case here lowers at the real size against a described ``v5e:2x2``
+with ``interpret=False`` forced FROM THE TEST — interpret-mode tests
+cannot see what Mosaic refuses (the serve-mask kernel's i1→uint8 select
+passed every one of them and could not start on the chip).
+
+Rules this file keeps: the topology is described inside a module-scoped,
+non-autouse fixture of THIS file (never at import, never in a
+skipif/parametrize/conftest); JAX's persistent compile cache is off
+around the compiles (such an entry is written but cannot be read back
+without a chip); everything compiles in the test's own process; nothing
+runs, so nothing here is a measurement.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributedpytorch_tpu.ops import kernels, pallas_kernels, wgrad_pallas
+
+B, H, W = 4, 640, 960  # the reference config: batch 4 at 640×960
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def mosaic(one_chip, no_persistent_cache, monkeypatch):
+    """Force every ``interpret=None`` kernel of the package to Mosaic —
+    from the test, not through an option of the program — and hand back
+    ``sds(shape, dtype)`` placing an abstract array on the described
+    chip."""
+    for mod in (kernels, pallas_kernels, wgrad_pallas):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return sds
+
+
+def _compile(fn, *args, **jit_kwargs):
+    return jax.jit(fn, **jit_kwargs).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# -- kernels, each at the widths the main path runs them -------------------
+
+
+def test_serve_mask_compiles(mosaic):
+    x = mosaic((B, H, W), jnp.float32)
+    c = _compile(
+        lambda v: kernels.sigmoid_threshold_mask(v, 0.5, interpret=False), x
+    )
+    assert _has_kernel(c)
+
+
+def _bn_args(sds, c):
+    # milesial level ℓ runs C = 64·2^ℓ at (H, W) / 2^ℓ
+    level = {64: 0, 128: 1, 256: 2, 512: 3, 1024: 4}[c]
+    x = sds((B, H >> level, W >> level, c), jnp.bfloat16)
+    vec = sds((c,), jnp.float32)
+    return x, vec, vec, vec, vec
+
+
+@pytest.mark.parametrize("c", [64, 128, 256, 512, 1024])
+def test_fused_bn_act_forward_compiles(mosaic, c):
+    compiled = _compile(
+        lambda *a: kernels.fused_bn_act(*a, interpret=False),
+        *_bn_args(mosaic, c),
+    )
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("c", [64, 128, 256, 512, 1024])
+def test_fused_bn_act_grad_compiles(mosaic, c):
+    def loss(*a):
+        return jnp.sum(kernels.fused_bn_act(*a, interpret=False))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *_bn_args(mosaic, c)
+    )
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("hw_ci_co", [
+    (320, 480, 128, 128),  # enc1 conv2 / dec4 block — the hot s2d shape
+    (160, 240, 128, 256),  # enc2 conv1
+    (160, 240, 256, 256),  # enc2 conv2 / dec3 block
+])
+def test_wgrad_9tap_compiles(mosaic, hw_ci_co):
+    h, w, ci, co = hw_ci_co
+    x = mosaic((B, h, w, ci), jnp.bfloat16)
+    dy = mosaic((B, h, w, co), jnp.bfloat16)
+    compiled = _compile(
+        lambda a, b: wgrad_pallas.wgrad_9tap_pallas(a, b, interpret=False),
+        x, dy,
+    )
+    assert _has_kernel(compiled)
+
+
+def test_eval_stats_compiles(mosaic):
+    x = mosaic((B, H, W, 1), jnp.float32)
+    compiled = _compile(
+        lambda p, t: pallas_kernels.eval_stats_pallas(p, t, interpret=False),
+        x, x,
+    )
+    assert _has_kernel(compiled)
+
+
+def test_fused_loss_value_and_grad_compiles(mosaic):
+    from distributedpytorch_tpu.ops.fused_loss import fused_bce_dice_loss
+
+    x = mosaic((B, H, W, 1), jnp.float32)
+    compiled = _compile(jax.value_and_grad(fused_bce_dice_loss), x, x)
+    assert _has_kernel(compiled)
+
+
+# -- the b4 640×960 bf16 s2d-2 train step and one serve bucket -------------
+
+
+def _course_unet(sds):
+    """(model, abstract params placed on the described chip)."""
+    from distributedpytorch_tpu.models.unet import UNet
+
+    # s2d depth 2 is what `-1` resolves to on a TPU backend; the described
+    # chip is not the default backend here, so the test names it
+    model = UNet(dtype=jnp.bfloat16, s2d_levels=2)
+    params = jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, H, W, 3)))["params"],
+        jax.random.key(0),
+    )
+    return model, jax.tree.map(lambda l: sds(l.shape, l.dtype), params)
+
+
+def _abstract_train_step(sds, loss_impl=None):
+    from distributedpytorch_tpu.ops.optim import adam_l2
+    from distributedpytorch_tpu.train.steps import TrainState, make_train_step
+
+    model, params = _course_unet(sds)
+    tx = adam_l2(1e-4, 1e-8)
+    opt_state = jax.eval_shape(tx.init, params)
+    state = TrainState(
+        params=params,
+        opt_state=jax.tree.map(lambda l: sds(l.shape, l.dtype), opt_state),
+        step=sds((), jnp.int32),
+        model_state=None,
+    )
+    batch = {
+        "image": sds((B, H, W, 3), jnp.float32),
+        "mask": sds((B, H, W), jnp.int32),
+    }
+    step = make_train_step(model, tx, batch_size=B, loss_impl=loss_impl)
+    return _compile(step, state, batch, donate_argnums=(0,))
+
+
+def _fits_one_chip(compiled) -> None:
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.temp_size_in_bytes < HBM_BYTES
+    assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB does not fit one v5e"
+
+
+def test_train_step_xla_policy_compiles_and_fits(mosaic):
+    """The shipping default (``--kernels xla``): no Pallas kernel in the
+    step, and it fits one chip's 16 GB with room (MODEL.md: ~6 GB)."""
+    compiled = _abstract_train_step(mosaic)
+    assert not _has_kernel(compiled)
+    _fits_one_chip(compiled)
+
+
+def test_train_step_pallas_policy_compiles_and_fits(mosaic):
+    """``--kernels pallas`` on the course UNet: the fused loss kernel is
+    in the step program."""
+    from distributedpytorch_tpu.ops.fused_loss import fused_bce_dice_loss
+
+    compiled = _abstract_train_step(mosaic, loss_impl=fused_bce_dice_loss)
+    assert _has_kernel(compiled)
+    _fits_one_chip(compiled)
+
+
+@pytest.mark.parametrize("policy", ["xla", "pallas"])
+def test_serve_bucket_forward_compiles(mosaic, policy):
+    """One serve bucket (batch 4 at 640×960), as the engine lowers it:
+    probabilities under ``xla``, the on-device ``uint8`` mask under
+    ``pallas``."""
+    from distributedpytorch_tpu.serve.infer import make_forward
+
+    model, params = _course_unet(mosaic)
+    variables = {"params": params}
+    x = mosaic((B, H, W, 3), jnp.float32)
+    fwd = make_forward(
+        model, mask_threshold=0.5 if policy == "pallas" else None
+    )
+    compiled = _compile(fwd, variables, x)
+    assert _has_kernel(compiled) == (policy == "pallas")
+    out = jax.eval_shape(fwd, variables, x)
+    assert out.shape == (B, H, W)
+    assert out.dtype == (jnp.uint8 if policy == "pallas" else jnp.float32)
+    _fits_one_chip(compiled)

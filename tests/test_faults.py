@@ -213,7 +213,7 @@ def test_call_with_retries_covers_channel_runtime_error():
     def flaky():
         calls["n"] += 1
         if calls["n"] < 3:
-            raise RuntimeError("UNAVAILABLE: relay flapped")
+            raise RuntimeError("UNAVAILABLE: peer went away")
         return "ok"
 
     out = faults.call_with_retries(

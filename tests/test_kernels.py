@@ -475,6 +475,26 @@ class TestProbesAndPriors:
         assert load_priors(path)["kernels"]["serve_mask"]["accepted"]
 
 
+    def test_probe_tool_exit_code_is_the_refusal(self, tmp_path, monkeypatch):
+        """A kernel Mosaic refuses is recorded in the priors file AND is
+        the tool's exit code — never just a line in a log."""
+        import sys
+
+        sys.path.insert(0, ".")
+        from tools import probe_kernels
+
+        path = str(tmp_path / "kernel_priors.json")
+        assert probe_kernels.main(["--out", path, "--kernels", "serve_mask"]) == 0
+
+        def refused():
+            raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+        monkeypatch.setitem(km.PROBES, "serve_mask", refused)
+        assert probe_kernels.main(["--out", path, "--kernels", "serve_mask"]) == 1
+        row = load_priors(path)["kernels"]["serve_mask"]
+        assert not row["accepted"] and "Mosaic failed" in row["reason"]
+
+
 class TestPlannerKernelsAxis:
     """ISSUE-11 acceptance: ``plan --kernel-priors`` ranks kernel-on
     points (rejected ones carrying the Mosaic reject reason) with zero

@@ -12,7 +12,6 @@ Two topology families (VERDICT r04 next-6):
     first-pod-run code paths.
 """
 
-import getpass
 import json
 import os
 import socket
@@ -43,7 +42,7 @@ def _launch_world(tmp_path, world, local_devices, method, mode="train",
     port = _free_port()
     procs = []
     for rank in range(world):
-        # CPU backend with `local_devices` virtual devices, relay disabled
+        # CPU backend with `local_devices` virtual devices
         # (ONE definition of those moves: utils/provision.py)
         env = provisioned_env(local_devices)
         env.update(
@@ -57,10 +56,10 @@ def _launch_world(tmp_path, world, local_devices, method, mode="train",
                 # per-rank but PERSISTENT compilation cache: splitting by
                 # rank avoids two ranks racing on identical entries, while
                 # keeping warm-cache speed across runs (tmp_path would be
-                # cold every invocation); per-user so shared machines don't
-                # collide on /tmp ownership
-                "JAX_COMPILATION_CACHE_DIR": (
-                    f"/tmp/dpt_test_xla_cache_{getpass.getuser()}_rank{rank}"
+                # cold every invocation): a fixed rank<R>/ beneath the
+                # suite's cache directory, as the elastic supervisor does
+                "JAX_COMPILATION_CACHE_DIR": os.path.join(
+                    os.environ["JAX_COMPILATION_CACHE_DIR"], f"rank{rank}"
                 ),
             }
         )

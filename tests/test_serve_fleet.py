@@ -884,10 +884,28 @@ class TestElasticServeWorkload:
 # ---------------------------------------------------------------------------
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+def _free_port(span: int = 1) -> int:
+    """A port P with P..P+span-1 all free right now: supervised serve
+    worker R binds base+R, so a drill that starts N workers needs N
+    consecutive free ports — asking the OS for one and hoping for its
+    neighbour cost a 600 s spawn wait whenever the neighbour was taken."""
+    while True:
+        socks = []
+        try:
+            first = socket.socket()
+            socks.append(first)
+            first.bind(("127.0.0.1", 0))
+            base = first.getsockname()[1]
+            for offset in range(1, span):
+                s = socket.socket()
+                socks.append(s)
+                s.bind(("127.0.0.1", base + offset))
+            return base
+        except OSError:
+            continue
+        finally:
+            for s in socks:
+                s.close()
 
 
 def _http_predict(port: int, body: bytes, timeout=5.0):
@@ -925,9 +943,6 @@ class TestElasticServeDrill:
         port = _free_port()
         env = dict(os.environ)
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["DPT_XLA_CACHE_PREFIX"] = (
-            f"/tmp/dpt_test_xla_cache_{getpass.getuser()}"
-        )
         # share the suite-wide AOT store (see test_serve_router's
         # _supervisor_env): relaunch + cold start become loads
         env["DPT_AOT_CACHE"] = (

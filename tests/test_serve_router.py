@@ -883,10 +883,28 @@ class TestDiurnalScaling:
 # ---------------------------------------------------------------------------
 
 
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+def _free_port(span: int = 1) -> int:
+    """A port P with P..P+span-1 all free right now: supervised serve
+    worker R binds base+R, so a drill that starts N workers needs N
+    consecutive free ports — asking the OS for one and hoping for its
+    neighbour cost a 600 s spawn wait whenever the neighbour was taken."""
+    while True:
+        socks = []
+        try:
+            first = socket.socket()
+            socks.append(first)
+            first.bind(("127.0.0.1", 0))
+            base = first.getsockname()[1]
+            for offset in range(1, span):
+                s = socket.socket()
+                socks.append(s)
+                s.bind(("127.0.0.1", base + offset))
+            return base
+        except OSError:
+            continue
+        finally:
+            for s in socks:
+                s.close()
 
 
 def _http_json(port: int, path: str, timeout=5.0):
@@ -938,9 +956,6 @@ def _supervisor_env():
 
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["DPT_XLA_CACHE_PREFIX"] = (
-        f"/tmp/dpt_test_xla_cache_{getpass.getuser()}"
-    )
     # ONE AOT store across every drill in the suite AND across pytest
     # runs (operator env wins over the supervisor's per-run default):
     # after the first run, every serve worker cold-starts as loads, not
@@ -969,7 +984,7 @@ class TestRouterSupervisorDrill:
         ckpt_dir, image_path = checkpoint
         with open(image_path, "rb") as f:
             body = f.read()
-        base_port = _free_port()
+        base_port = _free_port(span=2)  # two workers: base, base+1
         router_port = _free_port()
         env = _supervisor_env()
         sup = ElasticSupervisor(
@@ -1219,7 +1234,7 @@ class TestFleetElasticDrill:
         ckpt_dir, image_path = checkpoint
         with open(image_path, "rb") as f:
             body = f.read()
-        base_port = _free_port()
+        base_port = _free_port(span=2)  # the swell spawns worker 1
         router_port = _free_port()
         standby_port = _free_port()
         sup = ElasticSupervisor(

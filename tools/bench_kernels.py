@@ -27,8 +27,8 @@ chip already refused.
 
 Callable in-process (``kernel_sweep(budget_s=...)``) — registered as the
 ``kernel_sweep`` bench_multi config (budget-aware, single-device,
-collective-free → the static preflight's no-combos fast path), wired
-into tools/tpu_perf_program3.sh after the kernel_probe leg.
+collective-free → the static preflight's no-combos fast path), ordered
+after the kernel_probe leg.
 
 Usage: python tools/bench_kernels.py [--batch 4] [--hw 640 960]
        [--widths 32 64 128 256] [--steps 5] [--priors kernel_priors.json]

@@ -2,15 +2,10 @@
 """Single-process multi-config on-chip A/B measurement with resume and
 poison-marking.
 
-Why one process, and why this ordering: both dead chip windows this
-round died during a FRESH heavy compile in a NEW process right after a
-prior process had used the runtime (window 1, 01:06Z: the 9-tap wgrad
-graph; window 2, 03:36Z: the Pallas fused-loss config) — while long
-single-process streams of ordinary compiles+dispatches ran fine (the
-19-minute, 360-step convergence run; `bench.py`'s own two-executable
-headline). So the remaining A/B program runs in ONE process, cheapest /
-proven-safe compile classes first and the two wedge-suspect compiles
-last, with:
+Why one process: a chip belongs to one process at a time, so the whole
+A/B program runs in the process that holds it — no probe child, no
+lock — cheapest compile classes first and the largest graphs last,
+with:
 
   * a JSONL artifact appended after EVERY config (a mid-program death
     still leaves everything measured so far);
@@ -18,26 +13,22 @@ last, with:
     mid-compile attributes the kill to the config that caused it;
   * poison-marking — a config that watchdogged or whose attempt killed
     the process is recorded and NEVER retried (re-running the killer
-    compile would just re-wedge the next chip window);
-  * resume — configs with a successful line are skipped, so the
-    watcher can re-fire this program across windows and it only ever
-    spends chip time on innocent unmeasured configs;
+    compile would just wedge the next run);
+  * resume — configs with a successful line are skipped, so a
+    re-invocation only ever spends chip time on unmeasured configs;
   * ``--plan`` — an auto-planner plan file (``python -m
     distributedpytorch_tpu plan``, docs/PERFORMANCE.md "Planning")
     reorders the legs it models to predicted-winner-first and stamps
     ``plan_rank``/``plan_cost_s`` into their provenance rows, so a
-    short window measures the configs the cost model bets on before it
-    dies. Legs the planner cannot model — the Pallas/Mosaic compiles,
+    short run measures the configs the cost model bets on first. Legs the planner cannot model — the Pallas/Mosaic compiles,
     the sweeps' own grids — KEEP their hand-ordered safety position at
     the tail: prediction never moves a wedge-suspect compile earlier.
 
 Exit codes (the program wrapper's loop contract):
   0 = every config terminally resolved (measured, poisoned, or failed
       deterministically) — nothing left to spend chip time on
-  1 = innocent configs remain unmeasured (refire on a later window)
-  2 = runtime dead at start (nothing attempted)
+  1 = innocent configs remain unmeasured (re-invoke to continue)
   3 = a config hit its watchdog (poison-marked; re-invoke to continue)
-  4 = runtime died mid-sequence (remaining configs stay innocent)
 
 Measurement methodology is `bench.py`'s own `run()` — same compiled
 executables, same chained-dispatch timing, same JSON fields — driven
@@ -62,12 +53,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from distributedpytorch_tpu.obs import flight  # noqa: E402 — stdlib-only
 
 # (name, env overrides, per-config watchdog seconds). Order is the
-# safety story (see module docstring): pixel's compile class already
-# succeeded on this channel in round 3, b8 is the default graph at a
-# bigger batch, the milesial pair is plain XLA convs, and the
-# wedge-suspect compiles go last in INCREASING danger: the Pallas fused
-# loss (killed window 2), then the taps family in increasing graph size
-# — scoped-to-level-1 taps, full taps (killed window 1 mid-compile),
+# cheapest-first story (see module docstring): pixel and b8 are the
+# default graph's compile class, the milesial pair is plain XLA convs,
+# and the largest compiles go last in increasing graph size: the Pallas
+# fused loss, then the taps family — scoped-to-level-1 taps, full taps,
 # and finally full taps with the Mosaic wgrad kernel on top.
 CONFIGS = [
     ("pixel", {"BENCH_S2D_LEVELS": "0"}, 1200.0),
@@ -173,32 +162,6 @@ _INNOCENT_PREFIX = "runtime_error"
 # time. The analyzer runs in a provisioned CPU subprocess (utils/
 # provision.py): zero chip involvement, works on any window size.
 PREFLIGHT_TIMEOUT_S = 300.0
-
-# Liveness re-probe backoff after a retryable config failure: the relay
-# runtime is known to FLAP briefly (seconds to a couple of minutes) —
-# an immediate single re-probe reads a flap as a dead window and burns
-# it (both r05 windows ended this way). Probe, then back off 5/10/20 s
-# between further probes before declaring the runtime dead.
-REPROBE_ATTEMPTS = 4
-REPROBE_BASE_DELAY_S = 5.0
-
-# Error-message markers of a runtime-channel failure (grpc CHANNEL
-# status names + socket-ish strings): with a HEALTHY probe these mean
-# the in-process client blipped, not that the config is
-# deterministically broken — mark innocent (retryable), never
-# permanent. Deliberately NOT 'INTERNAL:' — Mosaic/XLA compile
-# rejections surface as INTERNAL and must stay terminal (the whole
-# point of the wgrad_pallas_probe is recording such a rejection once).
-_CHANNEL_MARKERS = (
-    "UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED",
-    "connection", "Connection", "socket", "stream terminated",
-)
-
-
-def _is_channel_error(exc) -> bool:
-    msg = str(exc)
-    return any(m in msg for m in _CHANNEL_MARKERS)
-
 
 def flight_artifact_path(out_path: str, name: str) -> str:
     """Deterministic flight-recorder artifact path for one config, next
@@ -358,25 +321,6 @@ def load_state(path: str) -> dict:
     return state
 
 
-def _reprobe_with_backoff(probe_once, timeout: float) -> dict:
-    """Re-probe a runtime that just answered dead, with exponential
-    backoff between attempts. Returns the first healthy probe (the
-    runtime was flapping, not dead) or the final dead one."""
-    delay = REPROBE_BASE_DELAY_S
-    probe = {"ok": False, "error": "no re-probe attempted"}
-    for attempt in range(REPROBE_ATTEMPTS):
-        if attempt:
-            print(f"bench_multi: runtime probe dead; backing off "
-                  f"{delay:.0f}s before re-probe "
-                  f"{attempt + 1}/{REPROBE_ATTEMPTS}")
-            time.sleep(delay)
-            delay *= 2
-        probe = probe_once(timeout)
-        if probe.get("ok"):
-            return probe
-    return probe
-
-
 def _preflight_combos(env: dict):
     """Which strategy × schedule combos a config's step will exercise —
     what the static preflight must clear. Single-device bench configs
@@ -476,11 +420,8 @@ def _run_one(bench, name: str, env: dict, budget: float) -> dict:
     measurement path (same executables/timing/fields as the driver
     artifact). Pre-existing values of the config env keys are snapshotted
     and restored afterward — an in-process run must not destroy ambient
-    state the caller (or an outer harness) set (ADVICE r05 low)."""
-    snapshot = {
-        k: os.environ.get(k)
-        for k in (*_CONFIG_ENV_KEYS, "BENCH_WATCHDOG_SECS")
-    }
+    state the caller (or an outer harness) set."""
+    snapshot = {k: os.environ.get(k) for k in _CONFIG_ENV_KEYS}
     try:
         for k in _CONFIG_ENV_KEYS:
             os.environ.pop(k, None)
@@ -555,10 +496,6 @@ def _run_one(bench, name: str, env: dict, budget: float) -> dict:
         bench.H = int(env.get("BENCH_H", 640))
         bench.W = int(env.get("BENCH_W", 960))
         bench.ARCH = env.get("BENCH_ARCH", "unet")
-        # run()'s fused-executable skip gate compares elapsed-since-_START
-        # against the watchdog budget; both must be per-config here.
-        bench._START = time.monotonic()
-        os.environ["BENCH_WATCHDOG_SECS"] = str(budget)
         return bench.run()
     finally:
         for k, v in snapshot.items():
@@ -573,7 +510,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(
         repo, ".perf_r05", "bench_multi.jsonl"))
-    ap.add_argument("--probe-timeout", type=float, default=120.0)
     ap.add_argument("--plan", default=None, metavar="PLAN_JSON",
                     help="Auto-planner plan file (python -m "
                          "distributedpytorch_tpu plan): legs the plan "
@@ -601,25 +537,7 @@ def main(argv=None) -> int:
               f"resolved in {args.out}")
         return 0
 
-    from bench import (  # SIGTERM-only subprocess probe + client lock
-        _probe_once,
-        acquire_client_lock,
-        release_client_lock,
-    )
-
-    # Mark single-client occupancy for the whole program (a hand-run
-    # bench_multi alongside a polling watcher is the two-client wedge;
-    # the lock makes the watcher hold off instead).
-    import atexit
-
-    if not acquire_client_lock("bench_multi", wait_secs=120.0):
-        print("bench_multi: client lock held; refusing to dial alongside "
-              "another TPU client")
-        return 2
-    atexit.register(release_client_lock)
-
-    probe = _probe_once(args.probe_timeout)
-    append_line(args.out, {"event": "session_start", "probe": probe,
+    append_line(args.out, {"event": "session_start",
                            "todo": [n for n, _, _ in todo],
                            "plan": (
                                {"path": args.plan,
@@ -630,35 +548,23 @@ def main(argv=None) -> int:
                                if args.plan else None
                            ),
                            "supervisor_restarts": supervisor_restarts()})
-    if not probe.get("ok"):
-        print(f"bench_multi: runtime dead at start: {probe}")
-        # dead-probe post-mortem: whatever the probe path recorded
-        artifact = flight.dump(
-            "dead_probe_at_start",
-            path=flight_artifact_path(args.out, "session"),
-            extra={"probe": probe},
-        )
-        append_line(args.out, {
-            "event": "session_end", "rc": 2,
-            "flight_recorder": artifact,
-            "supervisor_restarts": supervisor_restarts(),
-        })
-        return 2
 
+    # this process is the chip's one owner from here on: bench.run()
+    # initialises jax in-process, and nothing below starts a child that
+    # needs the device
     import bench
 
-    # env hygiene is per-config now: _run_one snapshots and restores the
-    # ambient values of every key it touches, so no process-wide cleanup
-    # (the old unconditional pop destroyed caller-set levers) is needed.
-    # The flight dump path IS process state — restore it on every exit
-    # so an embedding process (tests, a watcher) keeps its own routing.
+    # env hygiene is per-config: _run_one snapshots and restores the
+    # ambient values of every key it touches. The flight dump path IS
+    # process state — restore it on every exit so an embedding process
+    # (tests) keeps its own routing.
     try:
-        return _run_configs(args, todo, bench, _probe_once, plan_ranks)
+        return _run_configs(args, todo, bench, plan_ranks)
     finally:
         flight.set_dump_path(None)
 
 
-def _run_configs(args, todo, bench, _probe_once, plan_ranks=None) -> int:
+def _run_configs(args, todo, bench, plan_ranks=None) -> int:
     plan_ranks = plan_ranks or {}
     for name, env, budget in todo:
         # static preflight BEFORE the attempting marker and the watchdog:
@@ -675,70 +581,23 @@ def _run_configs(args, todo, bench, _probe_once, plan_ranks=None) -> int:
         aot_before = _aot_counters()
         try:
             result = _run_one(bench, name, env, budget)
-        except Exception as exc:  # noqa: BLE001 — classified below
+        except Exception as exc:  # noqa: BLE001 — recorded, sequence goes on
+            # An exception in a config is that config's error: this
+            # process holds the chip, so there is no second opinion to
+            # ask a child for. Record it as permanent and keep going —
+            # a broken config must not starve the ones ordered after it.
             dog.cancel()
-            retryable = isinstance(
-                exc,
-                (RuntimeError, OSError, ConnectionError, TimeoutError))
-            # JAX surfaces deterministic config failures as
-            # XlaRuntimeError (a RuntimeError subclass) too — only a
-            # liveness probe can tell "the runtime died under this
-            # config" from "this config is just broken". A healthy
-            # probe → the config itself failed (channel-shaped errors
-            # excepted, below) → permanent, keep going with the rest.
-            # A dead probe no longer ends the window on the spot: the
-            # relay is known to FLAP for seconds-to-minutes, and both
-            # r05 windows were burned by reading a flap as a death —
-            # re-probe with exponential backoff first, and only a
-            # still-dead runtime returns the window (rc=4). Either way
-            # the config is marked innocent (it failed while the
-            # runtime was away; a later invocation retries it).
-            probe = (
-                _probe_once(args.probe_timeout) if retryable
-                else {"ok": True}
+            artifact = flight.dump(
+                f"config_error: {name}",
+                extra={"error": f"{type(exc).__name__}: {str(exc)[:300]}"},
             )
-            if probe.get("ok"):
-                if retryable and _is_channel_error(exc):
-                    # runtime alive but the in-process client's channel
-                    # blipped mid-config: the config is innocent (retry
-                    # later), not deterministically broken
-                    append_line(args.out, {
-                        "config": name,
-                        "error":
-                            f"runtime_error: {type(exc).__name__}: {exc}",
-                    })
-                    print(f"bench_multi: channel blip at config "
-                          f"{name!r} (runtime alive): {exc}")
-                    continue
-                artifact = flight.dump(
-                    f"config_error: {name}",
-                    extra={"error": f"{type(exc).__name__}: {str(exc)[:300]}"},
-                )
-                append_line(args.out, {
-                    "config": name,
-                    "error": f"config_error: {type(exc).__name__}: {exc}",
-                    "flight_recorder": artifact,
-                })
-                print(f"bench_multi: deterministic failure in {name!r}: "
-                      f"{exc}")
-                continue
             append_line(args.out, {
                 "config": name,
-                "error": f"runtime_error: {type(exc).__name__}: {exc}",
+                "error": f"config_error: {type(exc).__name__}: {exc}",
+                "flight_recorder": artifact,
             })
-            probe = _reprobe_with_backoff(_probe_once, args.probe_timeout)
-            if probe.get("ok"):
-                print(f"bench_multi: runtime flapped at config {name!r} "
-                      f"and recovered — continuing with remaining "
-                      f"configs: {exc}")
-                continue
-            print(f"bench_multi: runtime died at config {name!r}: "
-                  f"{exc}")
-            append_line(args.out, {
-                "event": "session_end", "rc": 4,
-                "supervisor_restarts": supervisor_restarts(),
-            })
-            return 4
+            print(f"bench_multi: config {name!r} failed: {exc}")
+            continue
         dog.cancel()
         # every leg's row names its flight-recorder artifact path — the
         # file exists iff something on the leg dumped (watchdog, abort,

@@ -30,9 +30,8 @@ CANNOT observe must be stated up front:
   * From (b) the tool PREDICTS parallel step time on a real S-device mesh
     as t(S,M) ≈ (M+S−1) · w(M)/(M·S)·M = (M+S−1)·w1(M)/S with
     w1(M)=w(M)/M the per-microbatch time (balanced stages), and reports
-    theoretical efficiency M/(M+S−1) next to it. On real multi-chip
-    hardware `tools/tpu_perf_program.sh` is the channel that would close
-    the loop.
+    theoretical efficiency M/(M+S−1) next to it. Real multi-chip
+    hardware closes the loop (not measured).
 
 A fourth leg (round 6) is the SCHEDULE sweep: M ∈ {2,4,8,16} × schedule
 (gpipe vs 1f1b) at FIXED microbatch size (so the batch grows with M —

@@ -37,11 +37,12 @@ def main() -> int:
         maybe_reexec_provisioned,
     )
 
-    child_rc = maybe_reexec_provisioned(
-        1, _PROVISIONED_ENV,
-        extra_env={"JAX_COMPILATION_CACHE_DIR": "/tmp/dpt_test_xla_cache"})
+    child_rc = maybe_reexec_provisioned(1, _PROVISIONED_ENV)
     if child_rc is not None:
         return child_rc
+    from distributedpytorch_tpu.utils.backend import enable_compilation_cache
+
+    enable_compilation_cache()
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--tree",

@@ -8,17 +8,21 @@ Pallas kernel in ``ops/kernels.PROBES`` is AOT-lowered and compiled at a
 representative shape — ZERO execution — and the verdicts land in one
 versioned priors file that
 
-* ``ops/kernels.get_kernel_policy`` consumes at engagement time
-  (``--kernel-priors`` / ``$DPT_KERNEL_PRIORS``): a rejected kernel
-  disengages loudly, falling back bit-identically to XLA;
+* ``ops/kernels.get_kernel_policy`` consumes at engagement time ONLY
+  where the operator hands it over (``--kernel-priors`` /
+  ``$DPT_KERNEL_PRIORS``): a kernel the file marks rejected then
+  disengages loudly. With no priors given, a requested kernel that
+  Mosaic refuses fails the run at compile time — nothing probes and
+  falls back behind the operator's back;
 * ``python -m distributedpytorch_tpu plan --kernel-priors`` consumes as
   the ``kernels`` search axis: Mosaic-rejected kernel points are
   rejected with the probe's reason at zero device time.
 
 On a TPU the probes exercise real Mosaic lowering (the verdicts are the
-chip's); elsewhere the interpreter path compiles, which proves the
-machinery but records the PLANNING backend's verdict — the file stamps
-``platform`` so consumers can tell.
+chip's); on an operator-named CPU (``JAX_PLATFORMS=cpu``) the
+interpreter path compiles, which proves the machinery but records the
+PLANNING backend's verdict — the file stamps ``platform`` so consumers
+can tell. The exit code is non-zero when any probed kernel was refused.
 
 Registered as the 60 s ``kernel_probe`` bench_multi config (in-process
 dispatch, writes next to the session artifact); callable standalone:
@@ -76,9 +80,9 @@ def main(argv=None) -> int:
 
     summary = run_and_save(args.out, names=args.kernels, emit=emit)
     print(json.dumps(summary))
-    # a rejection is a RESULT, not a failure: the file records it and
-    # the policy/planner consume it — exit 0 either way
-    return 0
+    # the file records every verdict either way; a refusal is also this
+    # run's exit code, so it is never just a line in a log
+    return 1 if summary["rejected"] else 0
 
 
 if __name__ == "__main__":
