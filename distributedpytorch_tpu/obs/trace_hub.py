@@ -1,8 +1,8 @@
 """Trace hub: rank-tagged step-timeline spans → Perfetto/Chrome trace
 JSON, merged across ranks.
 
-``utils/trace.py`` records host-side phase spans (decode / stack / h2d /
-dispatch / readback) as JSONL; this module turns those into the Chrome
+``utils/trace.py`` records host-side phase spans (:data:`PHASES`) as
+JSONL; this module turns those into the Chrome
 trace-event format (``{"traceEvents": [...]}``) that Perfetto and
 ``chrome://tracing`` open directly, with one **process track per rank**
 and one **thread track per phase** — so a 2-rank elastic run reads as
@@ -21,8 +21,9 @@ per worker (rank 0 writes ``<path>``, rank R writes ``<path>.rankR``)
 and calls :func:`write_merged_trace` over the attempt's files when the
 job resolves. For device-side profiles, the trainer's
 ``--profile-steps N:M`` captures a ``jax.profiler`` trace over exactly
-that step range (train/loop.py) — this module stays host-side and
-jax-free.
+that step range (train/loop.py) and ties its clock to these spans'
+``perf_counter`` with a ``dpt_sync`` annotation and a ``clock_sync``
+event (utils/trace.py) — this module stays host-side and jax-free.
 """
 
 from __future__ import annotations
@@ -38,9 +39,12 @@ logger = logging.getLogger(__name__)
 
 _RANK_SUFFIX_RE = re.compile(r"\.rank(\d+)$")
 
-#: Stable thread-track ids for the known phases (unknown phases get
-#: ids after these, in first-seen order).
-_PHASE_ORDER = ("decode", "stack", "h2d", "dispatch", "readback")
+#: The package's one list of step-pipeline phases, in pipeline order
+#: (``utils/trace.py`` imports it; what each is: its module docstring).
+#: Their positions are the stable thread-track ids; unknown phases get
+#: ids after these, in first-seen order.
+PHASES = ("decode", "fetch", "slot_wait", "stack", "h2d", "h2d_ready",
+          "feed_wait", "dispatch", "readback")
 
 
 def _load_events(path: str) -> List[dict]:
@@ -64,10 +68,10 @@ def _load_events(path: str) -> List[dict]:
 
 
 def _phase_tid(phase: str, extra: Dict[str, int]) -> int:
-    if phase in _PHASE_ORDER:
-        return _PHASE_ORDER.index(phase)
+    if phase in PHASES:
+        return PHASES.index(phase)
     if phase not in extra:
-        extra[phase] = len(_PHASE_ORDER) + len(extra)
+        extra[phase] = len(PHASES) + len(extra)
     return extra[phase]
 
 

@@ -29,7 +29,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributedpytorch_tpu.config import TrainConfig
@@ -44,6 +43,7 @@ from distributedpytorch_tpu.parallel.pipeline import (
 )
 from distributedpytorch_tpu.train.steps import (
     TrainState,
+    apply_optimizer,
     grouped_eval_metrics,
     make_accum_train_step,
     make_eval_step,
@@ -352,8 +352,8 @@ class Strategy:
             grads = self.policy.cast_grads(grads)
             if grad_scale != 1.0:
                 grads = jax.tree.map(lambda g: g * grad_scale, grads)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            params, opt_state = apply_optimizer(
+                tx, grads, state.opt_state, state.params)
             return (
                 TrainState(
                     params=params,

@@ -718,9 +718,12 @@ class Trainer:
                 lo, hi, out,
             )
             jax.profiler.start_trace(out)
+            # one clock with the step timeline (utils/trace.py): dpt_sync
+            self.tracer.profile_started()
             self._profiling = True
             flight.record("profile", action="start", step=global_step)
         elif self._profiling and global_step >= hi:
+            self.tracer.profile_stopped()
             jax.profiler.stop_trace()
             self._profiling = False
             flight.record("profile", action="stop", step=global_step)
@@ -868,6 +871,7 @@ class Trainer:
             if getattr(self, "_watchdog", None) is not None:
                 self._watchdog.stop()
             if self._profiling:  # run ended inside the --profile-steps range
+                self.tracer.profile_stopped()
                 try:
                     jax.profiler.stop_trace()
                 finally:
@@ -916,6 +920,7 @@ class Trainer:
         profile_by_steps = cfg.profile_steps is not None and self.strategy.is_main
         if whole_run_profile:
             jax.profiler.start_trace(cfg.profile_dir)
+            self.tracer.profile_started()
 
         from tqdm import tqdm
 
@@ -967,7 +972,8 @@ class Trainer:
                         # non-finite step's update can be discarded
                         # (donation is off under it — _state_donation)
                         prev_state = self.state if skip_guard else None
-                        with self.tracer.span("dispatch", step=global_step + 1):
+                        with self.tracer.span("dispatch", step=global_step + 1,
+                                              epoch=epoch, seq=seq):
                             self.state, loss = self.train_step(self.state, placed)
                         if faults.fire("nan_loss", epoch=epoch,
                                        step=global_step + 1):
@@ -991,7 +997,8 @@ class Trainer:
                     def run_stack(buffered, placed):
                         nonlocal global_step
                         with self.tracer.span(
-                            "dispatch", step=global_step + 1, k=len(buffered)
+                            "dispatch", step=global_step + 1, epoch=epoch,
+                            seq=seq, k=len(buffered)
                         ):
                             self.state, losses = self.multi_step(self.state, placed)
                         # ONE memoized device→host pull for the whole (K,)
@@ -1024,7 +1031,8 @@ class Trainer:
                         # effective batch K·b, exact loss (make_accum_train_step)
                         nonlocal global_step
                         with self.tracer.span(
-                            "dispatch", step=global_step + 1, k=len(buffered)
+                            "dispatch", step=global_step + 1, epoch=epoch,
+                            seq=seq, k=len(buffered)
                         ):
                             self.state, loss = self.accum_step(self.state, placed)
                         global_step += 1
@@ -1079,7 +1087,10 @@ class Trainer:
                     # iteration; no device sync)
                     iter_t0 = None
                     with contextlib.closing(source):
-                        for (kind, payload), placed in source:
+                        # seq counts this epoch's work items as the feed
+                        # does: with `epoch`, the batch's identifier in
+                        # every span (utils/trace.py)
+                        for seq, ((kind, payload), placed) in enumerate(source):
                             now_t = time.perf_counter()
                             if iter_t0 is not None:
                                 obsm.TRAIN_STEP_SECONDS.observe(
@@ -1276,6 +1287,7 @@ class Trainer:
             epoch += 1
 
         if whole_run_profile:
+            self.tracer.profile_stopped()
             jax.profiler.stop_trace()
 
         if not self._stop_requested and not stopped_early:

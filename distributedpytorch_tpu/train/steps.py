@@ -95,9 +95,26 @@ def _prep_mask(mask: jax.Array) -> jax.Array:
     return mask[..., None].astype(jnp.float32)
 
 
+# Names in the compiled step's ``op_name`` metadata (flax already runs
+# every module under ``jax.named_scope``, so the encoder and decoder
+# levels are named; these two scopes name what no module owns). Metadata
+# only: the compiled program and its numbers do not change.
+LOSS_SCOPE = "loss"
+OPTIMIZER_SCOPE = "optimizer"
+
+
 def loss_fn(model, params, batch: Dict[str, jax.Array]) -> jax.Array:
     preds = model.apply({"params": params}, batch["image"])
-    return bce_dice_loss(preds, _prep_mask(batch["mask"]))
+    with jax.named_scope(LOSS_SCOPE):
+        return bce_dice_loss(preds, _prep_mask(batch["mask"]))
+
+
+def apply_optimizer(tx, grads, opt_state, params):
+    """``tx.update`` + ``apply_updates`` under the optimizer's scope:
+    ``(params, opt_state)`` after the update."""
+    with jax.named_scope(OPTIMIZER_SCOPE):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
 
 
 def _make_loss_fns(loss_impl):
@@ -110,7 +127,8 @@ def _make_loss_fns(loss_impl):
 
     def custom_loss_fn(model, params, batch):
         preds = model.apply({"params": params}, batch["image"])
-        return loss_impl(preds, _prep_mask(batch["mask"]))
+        with jax.named_scope(LOSS_SCOPE):
+            return loss_impl(preds, _prep_mask(batch["mask"]))
 
     def custom_stateful_loss_fn(model, params, model_state, batch):
         preds, updates = model.apply(
@@ -119,10 +137,9 @@ def _make_loss_fns(loss_impl):
             train=True,
             mutable=["batch_stats"],
         )
-        return (
-            loss_impl(preds, _prep_mask(batch["mask"])),
-            updates["batch_stats"],
-        )
+        with jax.named_scope(LOSS_SCOPE):
+            loss = loss_impl(preds, _prep_mask(batch["mask"]))
+        return loss, updates["batch_stats"]
 
     return custom_loss_fn, custom_stateful_loss_fn
 
@@ -151,7 +168,9 @@ def stateful_loss_fn(
         train=True,
         mutable=["batch_stats"],
     )
-    return bce_dice_loss(preds, _prep_mask(batch["mask"])), updates["batch_stats"]
+    with jax.named_scope(LOSS_SCOPE):
+        loss = bce_dice_loss(preds, _prep_mask(batch["mask"]))
+    return loss, updates["batch_stats"]
 
 
 def make_train_step(
@@ -204,8 +223,8 @@ def make_train_step(
         if grad_scale != 1.0:
             # (batch_size * loss).backward() parity, reference train_utils.py:69
             grads = jax.tree.map(lambda g: g * grad_scale, grads)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        params, opt_state = apply_optimizer(
+            tx, grads, state.opt_state, state.params)
         return (
             TrainState(
                 params=params,
@@ -282,7 +301,8 @@ def make_accum_train_step(
 
     def chunk_stats(params, chunk):
         preds = model.apply({"params": params}, chunk["image"])
-        return stats_fn(preds, _prep_mask(chunk["mask"]))
+        with jax.named_scope(LOSS_SCOPE):
+            return stats_fn(preds, _prep_mask(chunk["mask"]))
 
     fwd = jax.checkpoint(chunk_stats) if remat else chunk_stats
 
@@ -301,7 +321,8 @@ def make_accum_train_step(
         stats, _ = jax.lax.scan(
             pass1, jnp.zeros((4,), precision_ops.LOSS_DTYPE), stacked
         )
-        loss, ct = jax.value_and_grad(loss_from_stats)(stats)
+        with jax.named_scope(LOSS_SCOPE):
+            loss, ct = jax.value_and_grad(loss_from_stats)(stats)
 
         def pass2(carry, chunk):
             _, vjp = jax.vjp(lambda p: fwd(p, chunk), params)
@@ -320,8 +341,8 @@ def make_accum_train_step(
         grads, _ = jax.lax.scan(pass2, zeros, stacked)
         if grad_scale != 1.0:
             grads = jax.tree.map(lambda g: g * grad_scale, grads)
-        updates, opt_state = tx.update(grads, state.opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        new_params, opt_state = apply_optimizer(
+            tx, grads, state.opt_state, params)
         return (
             TrainState(
                 params=new_params,

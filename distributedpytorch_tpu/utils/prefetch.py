@@ -29,14 +29,22 @@ The serving tier (serve/server.py) runs the SAME scheduler on its
 request path: flushed request buckets are the work items, and
 ``place_fn`` stacks + pads + H2D-places each bucket onto its claimed
 replica's device, ``depth`` buckets ahead of the dispatch loop.
+
+Where the feed's time goes is recorded here, into the timeline the
+caller passes as ``tracer=`` (utils/trace.py has the phases): ``fetch``,
+``slot_wait``, ``stack`` and ``h2d`` on the worker, ``h2d_ready`` on a
+watcher thread, ``feed_wait`` on the consumer, every span of one item
+under the same ``(epoch, seq)``.
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import queue as queue_mod
 import threading
+import time
 from typing import Callable, Iterable, Iterator, Optional, Tuple, TypeVar
 
 import numpy as np
@@ -52,9 +60,43 @@ R = TypeVar("R")
 _DONE = object()
 
 
+def _nbytes(item) -> int:
+    """Host bytes of the arrays a work item holds (mappings, lists and
+    tuples of them); 0 where it holds none, as a serve bucket."""
+    if isinstance(item, dict):
+        return sum(_nbytes(v) for v in item.values())
+    if isinstance(item, (list, tuple)):
+        return sum(_nbytes(v) for v in item)
+    return int(getattr(item, "nbytes", 0))
+
+
+def _bytes_tag(item) -> dict:
+    """The ``bytes`` tag of a work item's spans: left out where the item
+    holds no arrays."""
+    nbytes = _nbytes(item)
+    return {"bytes": nbytes} if nbytes else {}
+
+
+def _fetched(items: Iterable[T], tracer, tags: dict) -> Iterator[Tuple[int, T, dict]]:
+    """``(seq, item, its bytes tag)`` of each item, the pull of each from
+    ``items`` under a ``fetch`` span; the pull that finds the end is the
+    span tagged ``end=True``."""
+    it = iter(items)
+    for seq in itertools.count():
+        with tracer.span("fetch", seq=seq, **tags) as span:
+            try:
+                item = next(it)
+            except StopIteration:
+                span["end"] = True
+                return
+            size = _bytes_tag(item)
+            span.update(size)
+        yield seq, item, size
+
+
 def bounded_prefetch(
     items: Iterable[T], fn: Callable[[T], R], depth: int = 2,
-    name: str = "dpt-prefetch",
+    name: str = "dpt-prefetch", tracer=None, epoch: Optional[int] = None,
 ) -> Iterator[Tuple[T, R]]:
     """Yield ``(item, fn(item))`` with ``fn`` running up to ``depth`` items
     ahead on a daemon thread.
@@ -65,22 +107,39 @@ def bounded_prefetch(
     are alive at once — for device placement, that many batches of device
     memory, including at ``depth=1`` (the round-3 queue-based bound kept
     one extra: a blocked put held a result the accounting missed,
-    ADVICE r03)."""
+    ADVICE r03).
+
+    Spans into ``tracer`` (the flight ring alone without one), tagged
+    ``seq`` (the item's index) and ``epoch`` where given: ``fetch`` around
+    each pull from ``items`` and ``slot_wait`` around a permit that had to
+    be waited for, both on the worker; ``feed_wait`` around the consumer's
+    wait for each result. With the timeline enabled, and only then, a
+    second daemon thread watches each result become ready (``h2d_ready``):
+    the worker itself never blocks on a copy."""
+    tracer = tracer or NULL_TIMELINE
+    tags = {} if epoch is None else {"epoch": epoch}
     in_flight = threading.Semaphore(max(1, depth))
     q: queue_mod.Queue = queue_mod.Queue()  # unbounded; the semaphore bounds
     stop = threading.Event()
+    # None while the timeline is off: no thread, nothing queued for one
+    watcher = tracer.ready_watcher("h2d_ready", name + "-ready")
 
     def worker():
         try:
-            for item in items:
-                # poll-acquire so a walked-away consumer (stop set) never
-                # leaves the worker blocked forever on a permit
-                while not in_flight.acquire(timeout=0.1):
-                    if stop.is_set():
-                        return
+            for seq, item, size in _fetched(items, tracer, tags):
+                if not in_flight.acquire(blocking=False):
+                    with tracer.span("slot_wait", seq=seq, **tags):
+                        # poll-acquire so a walked-away consumer (stop set)
+                        # never leaves the worker blocked forever on a permit
+                        while not in_flight.acquire(timeout=0.1):
+                            if stop.is_set():
+                                return
                 if stop.is_set():
                     return
-                q.put((item, fn(item)))
+                result = fn(item)
+                if watcher is not None:
+                    watcher.watch(result, seq=seq, **tags, **size)
+                q.put((item, result))
         except BaseException as exc:  # re-raised at the consumption point
             q.put(exc)
             return
@@ -88,8 +147,12 @@ def bounded_prefetch(
 
     threading.Thread(target=worker, daemon=True, name=name).start()
     try:
-        while True:
-            payload = q.get()
+        # results arrive in order: the n-th received is the worker's seq n
+        for seq in itertools.count():
+            with tracer.span("feed_wait", seq=seq, **tags) as span:
+                payload = q.get()
+                if payload is _DONE:
+                    span["end"] = True
             if payload is _DONE:
                 return
             if isinstance(payload, BaseException):
@@ -98,6 +161,8 @@ def bounded_prefetch(
             yield payload
     finally:
         stop.set()
+        if watcher is not None:
+            watcher.close()
 
 
 # ---------------------------------------------------------------------------
@@ -174,8 +239,12 @@ def pipelined_placement(
     The ``stack``/``h2d`` tracer spans recorded here are what make the
     overlap observable: their wall-clock windows interleave with the
     consumer's ``dispatch`` spans when the pipeline is actually ahead.
+    ``seq`` counts the work items here exactly as :func:`bounded_prefetch`
+    counts them, so with ``epoch`` it identifies one batch in every span
+    from ``fetch`` to the consumer's ``dispatch``.
     """
     tracer = tracer or NULL_TIMELINE
+    tags = {} if epoch is None else {"epoch": epoch}
     counter = {"n": 0}
 
     def place(item):
@@ -183,12 +252,13 @@ def pipelined_placement(
         seq = counter["n"]
         counter["n"] += 1
         if kind == STACK:
-            with tracer.span("stack", seq=seq):
+            with tracer.span("stack", seq=seq, **tags):
                 payload = {
                     key: np.stack([b[key] for b in payload])
                     for key in payload[0]
                 }
-        with tracer.span("h2d", seq=seq, kind=kind):
+        with tracer.span("h2d", seq=seq, kind=kind, **tags,
+                         **_bytes_tag(payload)):
             return faults.call_with_retries(
                 lambda: place_fn(kind, payload),
                 site="placement",
@@ -200,8 +270,20 @@ def pipelined_placement(
             )
 
     if depth <= 0:
-        return ((item, place(item)) for item in work)
-    return bounded_prefetch(work, place, depth=depth, name=name)
+        return _placed_inline(work, place, tracer, tags)
+    return bounded_prefetch(work, place, depth=depth, name=name,
+                            tracer=tracer, epoch=epoch)
+
+
+def _placed_inline(work, place, tracer, tags: dict):
+    """``depth <= 0``: fetch and placement on the consumer's own thread,
+    under their own spans, so the wait for the feed is a ``feed_wait`` of
+    no length."""
+    for seq, item, _ in _fetched(work, tracer, tags):
+        placed = place(item)
+        now = time.perf_counter()
+        tracer.record("feed_wait", now, now, seq=seq, **tags)
+        yield item, placed
 
 
 def bounded_submit(

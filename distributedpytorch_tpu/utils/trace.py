@@ -1,13 +1,26 @@
 """Step-timeline tracer: per-phase host timestamps for the async pipeline.
 
 The fully-overlapped step loop (train/loop.py + utils/prefetch.py) runs
-five host-observable phases per batch —
+these host-observable phases per batch, in pipeline order (the one list
+is ``obs.trace_hub.PHASES``, imported here) —
 
-    decode    host-side sample decode / batch assembly (data/loader.py)
-    stack     np.stack of K per-step batches into one dispatch payload
-    h2d       host→device placement (strategy.place_work on the worker)
-    dispatch  the host-side step call (async: enqueue, not execution)
-    readback  device→host drain of loss scalars (utils/metrics.py)
+    decode     host-side sample decode / batch assembly (data/loader.py;
+               on the loader's thread, inside the feed's ``fetch``)
+    fetch      the prefetch worker pulling its next work item from the
+               loader (cache lookup, decode, the batch's np.stack); on a
+               serve path this includes waiting for traffic
+    slot_wait  the worker waiting for a prefetch slot to free: only the
+               time actually waited, so none while the loop keeps up
+    stack      np.stack of K per-step batches into one dispatch payload
+    h2d        host→device placement (strategy.place_work on the worker):
+               the ENQUEUE of the copy, which returns before it has run
+    h2d_ready  from the end of ``h2d`` until every placed array is ready
+               on its device, recorded by a watcher thread that exists
+               only while the timeline is enabled (:class:`ReadyWatcher`)
+    feed_wait  the step loop waiting for a placed batch (the consumer's
+               side of utils/prefetch.bounded_prefetch)
+    dispatch   the host-side step call (async: enqueue, not execution)
+    readback   device→host drain of loss scalars (utils/metrics.py)
 
 — and whether they actually overlap is invisible in aggregate throughput
 numbers. This tracer records ``(phase, t0, t1)`` wall spans (a shared
@@ -16,6 +29,25 @@ worker, main loop), appends them as JSONL, and summarizes per-phase
 totals so a throughput regression is attributable to the phase that
 grew. `bench.py` emits the summary alongside imgs/sec; the overlap test
 (tests/test_async_pipeline.py) asserts on the raw spans.
+
+Every span of one batch carries the same ``(epoch, seq)`` tags: ``seq``
+counts the feed's work items from 0 in each epoch (a K-stack is one
+item), so a batch can be followed from ``fetch`` to ``dispatch`` and an
+epoch's first batches told from the rest. The feed closes an epoch with
+one ``fetch`` and one ``feed_wait`` tagged ``end=True`` under the ``seq``
+after the last batch's. ``fetch`` and ``h2d`` also carry ``bytes``, the
+host size of the item's arrays.
+
+One clock with the device trace: where the program itself starts a
+``jax.profiler`` trace (``--profile-steps``, ``--profile-dir``) it calls
+:meth:`StepTimeline.profile_started`, which writes one
+``TraceAnnotation("dpt_sync", pc_ns=<perf_counter_ns>)`` into the profile
+and the same reading as a ``clock_sync`` event here. A reader shifts a
+span onto the profiler's clock by ``sync.start − pc_ns·1e-9``. Until
+:meth:`StepTimeline.profile_stopped`, every ``span()`` also opens a
+``TraceAnnotation("dpt_<phase>", **tags)``, so the host spans sit in the
+same ``.xplane.pb`` beside the device operations (jax is imported only
+then: this module needs no backend).
 
 Disabled (the default: no path) it is a no-op cheap enough to leave the
 call sites unconditional.
@@ -34,13 +66,13 @@ from __future__ import annotations
 
 import contextlib
 import json
+import queue
 import threading
 import time
 from typing import Dict, Iterable, List, Optional
 
 from distributedpytorch_tpu.obs import flight
-
-PHASES = ("decode", "stack", "h2d", "dispatch", "readback")
+from distributedpytorch_tpu.obs.trace_hub import PHASES  # noqa: F401 — the one list
 
 
 class StepTimeline:
@@ -62,6 +94,9 @@ class StepTimeline:
         # per-phase running totals survive flush(): the summary covers the
         # whole run even though events are dumped incrementally
         self._totals: Dict[str, List[float]] = {}  # phase -> [count, total_s]
+        # jax.profiler.TraceAnnotation while a profile that the program
+        # started is running (profile_started), else None
+        self._annotation = None
 
     def record(self, phase: str, t0: float, t1: float,
                wall: Optional[float] = None, **tags) -> None:
@@ -85,14 +120,44 @@ class StepTimeline:
 
     @contextlib.contextmanager
     def span(self, phase: str, **tags):
-        if not self.enabled and not flight.get().enabled:
-            yield
+        """Time the block as one ``phase`` span. Yields ``tags``: what the
+        block adds to it before it ends is recorded with the span (the
+        ``seq`` of an item known only once it has arrived)."""
+        annotation = self._annotation
+        if annotation is None and not self.enabled and not flight.get().enabled:
+            yield tags
             return
         t0 = time.perf_counter()
         try:
-            yield
+            with (annotation("dpt_" + phase, **tags) if annotation
+                  else contextlib.nullcontext()):
+                yield tags
         finally:
             self.record(phase, t0, time.perf_counter(), **tags)
+
+    def profile_started(self) -> None:
+        """Tie this timeline's clock to a ``jax.profiler`` trace that the
+        caller has just started: one ``dpt_sync`` annotation in the
+        profile carries the ``perf_counter_ns`` reading that a
+        ``clock_sync`` event records here, and until
+        :meth:`profile_stopped` every ``span()`` is also an annotation
+        ``dpt_<phase>`` in that profile."""
+        import jax  # only here: the module stays importable without a backend
+
+        pc_ns = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation("dpt_sync", pc_ns=pc_ns):
+            pass
+        self.record("clock_sync", pc_ns * 1e-9, pc_ns * 1e-9, pc_ns=pc_ns)
+        self._annotation = jax.profiler.TraceAnnotation
+
+    def profile_stopped(self) -> None:
+        self._annotation = None
+
+    def ready_watcher(self, phase: str, name: str) -> Optional["ReadyWatcher"]:
+        """A started :class:`ReadyWatcher` that records ``phase`` spans
+        here, or None while the timeline is disabled: then no thread
+        exists and nothing waits for a device value."""
+        return ReadyWatcher(self, phase, name) if self.enabled else None
 
     def events(self, phase: Optional[str] = None) -> List[dict]:
         """Unflushed events (optionally one phase), in record order."""
@@ -112,11 +177,52 @@ class StepTimeline:
                 f.write(json.dumps(e) + "\n")
 
     def summary(self) -> Dict[str, Optional[dict]]:
-        """Per-phase ``{count, total_ms, mean_ms}`` over the whole run;
-        phases never observed report None (distinguishable from 0 ms)."""
+        """Per-phase ``{count, total_ms, mean_ms}`` over the whole run:
+        the listed phases first, one never observed reporting None
+        (distinguishable from 0 ms), then every other phase seen."""
         with self._lock:
             totals = {k: list(v) for k, v in self._totals.items()}
         return _format_totals(totals)
+
+
+class ReadyWatcher:
+    """A daemon thread that turns "this device result was enqueued" into
+    a span that ends when the result is ready: :meth:`watch` stamps the
+    hand-over and returns at once, the thread blocks on the result
+    (``jax.block_until_ready``) and records the span, and holds the
+    result no longer than that. The caller's own thread never waits for
+    the device. :meth:`close` ends the thread once it has seen what was
+    handed over before."""
+
+    _CLOSE = object()
+
+    def __init__(self, tracer: StepTimeline, phase: str, name: str):
+        self._tracer, self._phase = tracer, phase
+        self._q: queue.Queue = queue.Queue()
+        threading.Thread(target=self._run, daemon=True, name=name).start()
+
+    def watch(self, result, **tags) -> None:
+        self._q.put((time.perf_counter(), result, tags))
+
+    def close(self) -> None:
+        self._q.put(self._CLOSE)
+
+    def _run(self) -> None:
+        import jax
+
+        while True:
+            entry = self._q.get()
+            if entry is self._CLOSE:
+                return
+            t0, result, tags = entry
+            del entry
+            try:
+                jax.block_until_ready(result)
+            except RuntimeError:  # the consumer meets the same failure on use
+                continue
+            finally:
+                del result
+            self._tracer.record(self._phase, t0, time.perf_counter(), **tags)
 
 
 def _format_totals(totals: Dict[str, List[float]]) -> Dict[str, Optional[dict]]:
@@ -124,7 +230,7 @@ def _format_totals(totals: Dict[str, List[float]]) -> Dict[str, Optional[dict]]:
     StepTimeline.summary and summarize_events (one formatter: bench.py
     emits both side by side, and they must never drift apart)."""
     out: Dict[str, Optional[dict]] = {}
-    for phase in PHASES:
+    for phase in (*PHASES, *(p for p in totals if p not in PHASES)):
         if phase not in totals:
             out[phase] = None
             continue

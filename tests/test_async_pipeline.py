@@ -248,6 +248,268 @@ def test_trainer_writes_timeline_jsonl(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# One batch followed through the feed: fetch, slot wait, h2d to ready, the
+# loop's wait, all under one (epoch, seq)
+# ---------------------------------------------------------------------------
+
+FEED_THREADS = ("dpt-prefetch", "dpt-prefetch-ready")
+
+
+def _feed_threads():
+    import threading
+
+    return [t.name for t in threading.enumerate() if t.name in FEED_THREADS]
+
+
+def _wait_feed_threads_gone(timeout=5.0):
+    deadline = time.monotonic() + timeout
+    while _feed_threads() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return _feed_threads()
+
+
+def _drive_feed(tracer, n_batches=5, epochs=1, produce_s=0.0, consume_s=0.0,
+                depth=2):
+    """The feed as the trainer composes it, over ``epochs`` epochs of
+    ``n_batches``: the loader takes ``produce_s`` a batch, the step
+    ``consume_s``; placement is a real ``device_put``."""
+    import jax
+
+    def batches():
+        for _ in range(n_batches):
+            time.sleep(produce_s)
+            yield {"image": np.zeros((4, 8, 8, 3), np.float32),
+                   "mask": np.zeros((4, 8, 8), np.int32)}
+
+    for epoch in range(epochs):
+        pipe = pipelined_placement(
+            stacked_work(batches(), 1, 4),
+            lambda kind, payload: jax.device_put(payload),
+            depth=depth, tracer=tracer, epoch=epoch,
+        )
+        for seq, ((_kind, _payload), _placed) in enumerate(pipe):
+            with tracer.span("dispatch", epoch=epoch, seq=seq):
+                time.sleep(consume_s)
+    assert _wait_feed_threads_gone() == []
+
+
+def _by_batch(events):
+    """{(epoch, seq): {phase: event}} of the spans that name a batch (the
+    epoch's closing ``end`` spans left out)."""
+    out = {}
+    for e in events:
+        if "seq" in e and not e.get("end"):
+            out.setdefault((e["epoch"], e["seq"]), {})[e["phase"]] = e
+    return out
+
+
+def _dur(e):
+    return e["t1"] - e["t0"]
+
+
+@pytest.mark.parametrize("depth", [2, 0])
+def test_every_span_of_a_batch_carries_its_epoch_and_seq(depth):
+    """fetch -> h2d -> feed_wait -> dispatch in that order under one
+    (epoch, seq); a second epoch restarts seq at 0 with epoch + 1; an
+    epoch closes with one fetch and one feed_wait tagged end."""
+    tracer = StepTimeline(enabled=True)
+    _drive_feed(tracer, n_batches=4, epochs=2, consume_s=0.005, depth=depth)
+    events = tracer.events()
+    batches = _by_batch(events)
+    assert sorted(batches) == [(e, s) for e in (0, 1) for s in range(4)]
+    for key, spans in batches.items():
+        assert {"fetch", "h2d", "feed_wait", "dispatch"} <= set(spans), key
+        order = [spans[p] for p in ("fetch", "h2d", "feed_wait", "dispatch")]
+        assert all(a["t1"] <= b["t1"] for a, b in zip(order, order[1:])), key
+        assert spans["fetch"]["t1"] <= spans["h2d"]["t0"]
+        assert spans["feed_wait"]["t1"] <= spans["dispatch"]["t0"]
+        # two arrays a batch: 4*8*8*3 float32 + 4*8*8 int32
+        assert spans["fetch"]["bytes"] == spans["h2d"]["bytes"] == 4096
+    ends = [(e["phase"], e["epoch"], e["seq"]) for e in events if e.get("end")]
+    # placed inline (depth 0) the loop never waits, for the end either
+    assert sorted(ends) == [(phase, epoch, 4) for phase in
+                            (("feed_wait", "fetch") if depth else ("fetch",))
+                            for epoch in (0, 1)]
+    first = min(e["t0"] for e in events if e.get("epoch") == 1)
+    assert first >= max(e["t1"] for e in events if e.get("epoch") == 0
+                        and e["phase"] != "h2d_ready")
+
+
+def test_slow_consumer_shows_slot_wait_and_no_feed_wait():
+    """The feed runs ahead: the worker waits for a slot, the loop for
+    nothing (past the first batch, which nothing can have prefetched)."""
+    tracer = StepTimeline(enabled=True)
+    _drive_feed(tracer, n_batches=6, consume_s=0.05)
+    slot = tracer.events("slot_wait")
+    assert slot and sum(map(_dur, slot)) > 0.05
+    assert all(e["epoch"] == 0 and 2 <= e["seq"] <= 5 for e in slot)
+    waits = [e for e in tracer.events("feed_wait") if e["seq"] >= 1]
+    assert len(waits) == 6  # five batches and the end
+    assert sum(map(_dur, waits)) < 0.02
+
+
+def test_slow_producer_shows_feed_wait_and_no_slot_wait():
+    tracer = StepTimeline(enabled=True)
+    _drive_feed(tracer, n_batches=6, produce_s=0.05)
+    assert tracer.events("slot_wait") == []
+    waits = [e for e in tracer.events("feed_wait") if not e.get("end")]
+    assert len(waits) == 6
+    assert all(_dur(e) > 0.03 for e in waits)
+    fetch = [e for e in tracer.events("fetch") if not e.get("end")]
+    assert all(_dur(e) > 0.04 for e in fetch)
+
+
+def test_h2d_ready_closes_after_its_h2d_off_the_worker_thread(monkeypatch):
+    """h2d_ready runs from the end of the batch's h2d until its arrays are
+    ready, on the watcher: the worker never waits for a copy."""
+    import threading
+
+    import jax
+
+    blocked_on = []
+    real = jax.block_until_ready
+
+    def block(x):
+        blocked_on.append(threading.current_thread().name)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    tracer = StepTimeline(enabled=True)
+    _drive_feed(tracer, n_batches=4)
+    batches = _by_batch(tracer.events())
+    for key, spans in batches.items():
+        ready, h2d = spans["h2d_ready"], spans["h2d"]
+        assert ready["t0"] >= h2d["t1"] and ready["t1"] >= ready["t0"], key
+        assert ready["t0"] - h2d["t1"] < 0.005, key
+        assert ready["bytes"] == h2d["bytes"]
+    assert blocked_on == ["dpt-prefetch-ready"] * 4
+
+
+def test_disabled_timeline_stores_nothing_and_starts_no_watcher(monkeypatch):
+    """With the timeline disabled: no span stored, no watcher thread, no
+    TraceAnnotation opened, and nobody waits for a copy."""
+    import jax
+
+    seen = {"threads": set(), "annotations": 0, "blocked": 0}
+
+    class Annotation:
+        def __init__(self, *a, **kw):
+            seen["annotations"] += 1
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: seen.__setitem__("blocked", seen["blocked"] + 1))
+    tracer = StepTimeline(None)
+    assert not tracer.enabled
+
+    def batches():
+        for _ in range(4):
+            seen["threads"].update(_feed_threads())
+            yield {"image": np.zeros((2, 4, 4, 3), np.float32)}
+
+    pipe = pipelined_placement(stacked_work(batches(), 1, 2),
+                               lambda kind, payload: jax.device_put(payload),
+                               depth=2, tracer=tracer, epoch=0)
+    assert len(list(pipe)) == 4
+    assert tracer.events() == []
+    assert seen == {"threads": {"dpt-prefetch"}, "annotations": 0, "blocked": 0}
+    assert _wait_feed_threads_gone() == []
+
+
+def test_closing_the_feed_mid_epoch_stops_worker_and_watcher():
+    import contextlib
+
+    tracer = StepTimeline(enabled=True)
+    batches = ({"image": np.zeros((2, 4, 4, 3), np.float32)} for _ in range(50))
+    pipe = pipelined_placement(stacked_work(batches, 1, 2),
+                               lambda kind, payload: payload,
+                               depth=2, tracer=tracer, epoch=3)
+    with contextlib.closing(pipe):
+        next(pipe)
+        assert sorted(_feed_threads()) == sorted(FEED_THREADS)
+    assert _wait_feed_threads_gone() == []
+    assert len(tracer.events("h2d")) < 10
+
+
+def test_a_program_started_profile_shares_the_timelines_clock(tmp_path):
+    """profile_started writes dpt_sync into the profile with the reading
+    that the timeline's clock_sync event holds, and until profile_stopped
+    every span is also a dpt_<phase> annotation there: shifted by the
+    sync, the timeline's span and the profile's annotation coincide."""
+    import glob
+
+    import jax
+
+    tracer = StepTimeline(enabled=True)
+    with tracer.span("dispatch", step=0):
+        pass  # before the profile: no annotation
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tracer.profile_started()
+        with tracer.span("fetch", epoch=2, seq=5):
+            time.sleep(0.01)
+        tracer.profile_stopped()
+        with tracer.span("h2d", epoch=2, seq=5):
+            pass  # after it: none either
+    finally:
+        jax.profiler.stop_trace()
+    (sync,) = tracer.events("clock_sync")
+    (fetch,) = tracer.events("fetch")
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("dpt_"):
+                    found[e.name] = (e.start_ns * 1e-9, e.duration_ns * 1e-9,
+                                     dict(e.stats))
+    assert set(found) == {"dpt_sync", "dpt_fetch"}
+    at, _, stats = found["dpt_sync"]
+    assert stats["pc_ns"] == sync["pc_ns"]
+    shift = at - sync["pc_ns"] * 1e-9
+    start, dur, stats = found["dpt_fetch"]
+    assert (stats["epoch"], stats["seq"]) == (2, 5)
+    assert start == pytest.approx(fetch["t0"] + shift, abs=2e-3)
+    assert dur == pytest.approx(fetch["t1"] - fetch["t0"], abs=2e-3)
+
+
+def test_summary_reports_every_phase_it_saw():
+    """One phase list in the package (obs/trace_hub.py); a phase outside
+    it is reported after the listed ones, not dropped."""
+    from distributedpytorch_tpu.obs import trace_hub
+    from distributedpytorch_tpu.utils import trace
+
+    assert trace.PHASES is trace_hub.PHASES
+    assert trace.PHASES == ("decode", "fetch", "slot_wait", "stack", "h2d",
+                            "h2d_ready", "feed_wait", "dispatch", "readback")
+    tracer = StepTimeline(enabled=True)
+    tracer.record("eval", 1.0, 1.5)
+    tracer.record("fetch", 2.0, 2.25)
+    summary = tracer.summary()
+    assert list(summary) == [*trace.PHASES, "eval"]
+    assert summary["fetch"]["total_ms"] == 250.0 and summary["h2d"] is None
+    assert summary["eval"] == {"count": 1, "total_ms": 500.0, "mean_ms": 500.0}
+    assert trace.summarize_events(tracer.events()) == summary
+
+
+def test_trainer_dispatch_spans_name_their_batch(tmp_path):
+    """Trainer._run end to end: each dispatch carries the (epoch, seq) of
+    the batch the feed handed over, beside the global step."""
+    path = tmp_path / "timeline.jsonl"
+    cfg = _config(tmp_path, timeline_path=str(path), prefetch_batches=2)
+    Trainer(cfg).train()
+    events = load_events(str(path))
+    dispatch = [e for e in events if e["phase"] == "dispatch"]
+    assert [(e["epoch"], e["seq"], e["step"]) for e in dispatch] == [
+        (0, 0, 1), (0, 1, 2), (0, 2, 3), (1, 0, 4), (1, 1, 5), (1, 2, 6)]
+    batches = _by_batch(e for e in events if "epoch" in e)
+    assert sorted(batches) == [(e, s) for e in (0, 1) for s in range(3)]
+    for spans in batches.values():
+        assert {"fetch", "h2d", "h2d_ready", "feed_wait", "dispatch"} <= set(spans)
+
+
+# ---------------------------------------------------------------------------
 # Non-blocking checkpoints
 # ---------------------------------------------------------------------------
 
