@@ -24,6 +24,25 @@ s2d tensors is NOT the s2d of the pixel concatenation — kernel builders
 take ``in_segments`` describing the per-tensor channel counts so the skip
 concat in the UNet decoder needs no data movement at all.
 
+The pool (``group_max``). The 2×2 window is the s2d group, so the
+maxpool is a maximum over the four channel slices ``x[..., g*C:(g+1)*C]``
+— taken as elementwise maxima of slices, never as ``reshape(b, h, w, 4,
+C)``: on the TPU the channels lie in the 128 lanes, and splitting the lane
+dimension is a relayout of the whole activation (two 629 MB copies a step
+at level 1 of the course UNet at batch 16, and as many again in the
+backward). It carries its own VJP. The forward picks each window's winner
+once, from the operand it is given: the FIRST maximum in window order
+(g = 2*di + dj), which is what ``torch.nn.MaxPool2d``,
+``lax.reduce_window``'s gradient and ``nn.max_pool`` at the pixel levels
+pick, stored as a group index 0..3 in the activation's own dtype (so that
+XLA writes maximum and index in one pass). The backward hands ``dy`` whole to
+that group and zero to the others, as one select at the full 4C lanes
+(``dy`` and the index laid four times side by side, compared with each
+lane's group), which XLA fuses into the ReLU backward next to it. There is
+no comparison with a stored maximum — ``jnp.max``'s gradient made one, and
+behind BatchNorm + ReLU in the jitted step it missed in one window of
+eight (PERF.md §6, PR 26) — no count of ties and no divide.
+
 Every builder here mirrors one reference op:
   * 3×3 SAME conv           (reference model/unet_parts.py:10-12)
   * 2×2 stride-2 maxpool    (reference model/unet_parts.py:26)
@@ -37,6 +56,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def space_to_depth(x: jax.Array) -> jax.Array:
@@ -141,13 +161,54 @@ def tile_bias(b: jax.Array) -> jax.Array:
     return jnp.tile(b, 4)
 
 
+def _max_and_winner(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Maximum over the four lane slices of an s2d tensor and the group it
+    came from, 0..3 in ``x``'s own dtype: with the same type and shape as
+    the maximum, XLA writes both in one pass over ``x`` (an int8 index costs
+    a second pass, 1.1 ms a step more on the v5e; PERF.md §6, PR 26).
+    ``>`` is strict, so a tie stays with the first group in window order
+    (g = 2*di + dj)."""
+    c = x.shape[-1] // 4
+    best = x[..., :c]
+    winner = jnp.zeros_like(best)
+    for g in range(1, 4):
+        part = x[..., g * c : (g + 1) * c]
+        winner = jnp.where(part > best, jnp.asarray(g, x.dtype), winner)
+        best = jnp.maximum(best, part)
+    return best, winner
+
+
+@jax.custom_vjp
+def _pool(x: jax.Array) -> jax.Array:
+    return _max_and_winner(x)[0]
+
+
+def _pool_fwd(x):
+    return _max_and_winner(x)
+
+
+def _pool_bwd(winner, dy):
+    # at the full 4C lanes, in one select: a lane takes dy where its group won
+    group_of_lane = jnp.asarray(np.repeat(np.arange(4), dy.shape[-1]), winner.dtype)
+
+    def four_times(a):  # not jnp.tile: its broadcast + reshape splits the lanes
+        return jnp.concatenate([a] * 4, axis=-1)
+
+    zero = jnp.zeros((), dy.dtype)
+    return (jnp.where(four_times(winner) == group_of_lane, four_times(dy), zero),)
+
+
+_pool.defvjp(_pool_fwd, _pool_bwd)
+
+
 def group_max(x: jax.Array) -> jax.Array:
     """2×2 stride-2 maxpool of the underlying pixel image, evaluated on its
     s2d form: the pool window IS the s2d group. (B,h,w,4C) → (B,h,w,C) at
-    what is now the next level's pixel resolution."""
-    b, h, w, c4 = x.shape
-    assert c4 % 4 == 0
-    return jnp.max(x.reshape(b, h, w, 4, c4 // 4), axis=3)
+    what is now the next level's pixel resolution. The gradient goes, whole,
+    to the window's first maximum (module docstring, "The pool")."""
+    assert x.shape[-1] % 4 == 0
+    with jax.named_scope("s2d_pool"):
+        return _pool(x)
 
 
 def conv_same(x: jax.Array, kernel: jax.Array) -> jax.Array:

@@ -343,13 +343,57 @@ class TestMilesialS2D:
                 np.asarray(b), np.asarray(a), rtol=2e-5, atol=2e-6
             )
 
+    def test_jitted_f32_grads_match_pixel_every_leaf(self):
+        """The fault ROADMAP M1 named: with ``jnp.max`` as the s2d pool the
+        jitted float32 gradients of ``inc/*`` and ``down1/conv/*`` came out
+        up to 15% low in norm at these widths and this size (BatchNorm and
+        ReLU are recomputed beside the pool in the compiled step, and the
+        gradient's equality with the stored maximum missed). The pool now
+        picks one winner from the operand it is given: every leaf's norm is
+        within 5e-3 of the pixel path's, and the difference within 2e-2 of
+        the norm (the readings of benchmark/tools/s2d_pool_gradient.py)."""
+        widths, hw = (8, 16, 32, 64), (32, 48)
+        x = jax.random.uniform(jax.random.key(0), (2, *hw, 3))
+        t = (jax.random.uniform(jax.random.key(1), (2, *hw, 1)) > 0.5).astype(
+            jnp.float32
+        )
+        params, stats = init_milesial(
+            MilesialUNet(widths=widths, dtype=jnp.float32, s2d_levels=0),
+            jax.random.key(2), input_hw=hw,
+        )
+
+        def grads(levels):
+            model = MilesialUNet(
+                widths=widths, dtype=jnp.float32, s2d_levels=levels
+            )
+
+            def loss(p):
+                y, _ = model.apply(
+                    {"params": p, "batch_stats": stats}, x, train=True,
+                    mutable=["batch_stats"],
+                )
+                return jnp.mean((y - t) ** 2)
+
+            return jax.jit(jax.grad(loss))(params)
+
+        g0, g2 = grads(0), grads(2)
+        for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(g0), jax.tree.leaves(g2)
+        ):
+            norm = float(jnp.linalg.norm(a))
+            ratio = float(jnp.linalg.norm(b)) / norm
+            apart = float(jnp.linalg.norm(b - a)) / norm
+            name = jax.tree_util.keystr(path)
+            assert abs(ratio - 1) <= 5e-3 and apart <= 2e-2, (name, ratio, apart)
+
     def test_grads_match_pixel(self):
         """float64 (subprocess: x64 is a process-wide jax config): the two
         execution domains are mathematically the SAME function, so
         gradients agree to ~1e-6 relative. (In float32 the BatchNorm
-        backward amplifies summation-order noise to ~1e-2 on the earliest
-        layers — measured identically ill-conditioned for both paths, so
-        f32 equality is not the right assertion.)"""
+        backward amplifies summation-order noise to some 1e-3 of a leaf's
+        norm on the earliest layers, so element-wise f32 equality is not
+        the right assertion; the test above holds float32, jitted, by
+        norms.)"""
         import os
         import subprocess
         import sys

@@ -5,6 +5,8 @@ approximation. Verified op-by-op against the flax/lax pixel-domain ops and
 end-to-end on the full model (forward, gradients, param-tree identity).
 """
 
+import dataclasses
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -50,6 +52,115 @@ class TestRearranges:
         np.testing.assert_allclose(
             np.asarray(s2d.group_max(s2d.space_to_depth(x))), np.asarray(pooled)
         )
+
+
+#: planted ties: which groups of a window hold its maximum, window after window
+_TIED_GROUPS = [(0, 1), (0, 2), (0, 3), (1, 2), (1, 3), (2, 3), (0, 1, 2, 3)]
+
+
+def _pool_input(kind, dtype):
+    """A pixel image (2, 8, 12, 5) for the pool's gradient cases."""
+    rng = np.random.default_rng(11)
+    x = rng.standard_normal((2, 8, 12, 5)).astype(np.float32)
+    if kind == "relu_zero_windows":
+        x = np.maximum(x, 0.0)
+        x[:, 0:4, 0:6, :] = 0.0  # whole windows of zeros
+    elif kind == "planted_ties":
+        # every window holds its maximum twice or four times, in non-zero
+        # values, at every pair of positions in turn
+        x = np.abs(x) + 0.5
+        blocks = x.reshape(2, 4, 2, 6, 2, 5)  # (b, i, di, j, dj, c), a view
+        top = blocks.max(axis=(2, 4)) + 1.0
+        for i in range(4):
+            for j in range(6):
+                for g in _TIED_GROUPS[(i * 6 + j) % len(_TIED_GROUPS)]:
+                    blocks[:, i, g // 2, j, g % 2, :] = top[:, i, j, :]
+    else:
+        assert kind == "random"
+    return jnp.asarray(x, dtype)
+
+
+class TestPoolGradient:
+    """``group_max`` routes its gradient as ``nn.max_pool`` on the pixel
+    form does (and torch's MaxPool2d): whole, to the window's first
+    maximum in row-major window order."""
+
+    @pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+    @pytest.mark.parametrize(
+        "kind", ["random", "relu_zero_windows", "planted_ties"]
+    )
+    @pytest.mark.parametrize(
+        "dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"]
+    )
+    def test_gradient_is_maxpools(self, dtype, kind, jit):
+        x = _pool_input(kind, dtype)
+        dy = jnp.asarray(
+            np.random.default_rng(12).standard_normal((2, 4, 6, 5)), dtype
+        )
+
+        def via_s2d(x):
+            y = s2d.group_max(s2d.space_to_depth(x))
+            return jnp.sum((y * dy).astype(jnp.float32))
+
+        def via_pixels(x):
+            y = nn.max_pool(x, window_shape=(2, 2), strides=(2, 2))
+            return jnp.sum((y * dy).astype(jnp.float32))
+
+        wrap = jax.jit if jit else (lambda f: f)
+        got = np.asarray(wrap(jax.grad(via_s2d))(x).astype(jnp.float32))
+        want = np.asarray(wrap(jax.grad(via_pixels))(x).astype(jnp.float32))
+        np.testing.assert_array_equal(got, want)
+        # one winner a window: the whole of dy arrives, in one place
+        assert np.count_nonzero(got) == np.count_nonzero(np.asarray(dy))
+        if kind == "planted_ties":
+            blocks = got.reshape(2, 4, 2, 6, 2, 5)
+            for i in range(4):
+                for j in range(6):
+                    g = _TIED_GROUPS[(i * 6 + j) % len(_TIED_GROUPS)][0]
+                    np.testing.assert_array_equal(
+                        blocks[:, i, g // 2, j, g % 2, :],
+                        np.asarray(dy.astype(jnp.float32))[:, i, j, :],
+                    )
+
+    @pytest.mark.parametrize(
+        "dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"]
+    )
+    def test_forward_is_max_over_group_bit_for_bit(self, dtype):
+        """The body this pool replaced, ``jnp.max`` over the split lanes."""
+        x = jnp.asarray(
+            np.random.default_rng(13).standard_normal((2, 6, 10, 28)), dtype
+        )
+        want = jnp.max(x.reshape(2, 6, 10, 4, 7), axis=3)
+        for fn in (s2d.group_max, jax.jit(s2d.group_max)):
+            got = fn(x)
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(
+                np.asarray(got.astype(jnp.float32)),
+                np.asarray(want.astype(jnp.float32)),
+            )
+
+    def test_backward_has_no_divide_reduce_or_lane_split(self):
+        """The backward is selects and one concatenate: no equality against
+        a broadcast maximum, no count of ties, no divide; and neither pass
+        reshapes the channel (lane) dimension."""
+        x = jnp.zeros((2, 4, 6, 128), jnp.bfloat16)
+        y, vjp = jax.vjp(s2d.group_max, x)
+        fwd = jax.make_jaxpr(s2d.group_max)(x)
+        bwd = jax.make_jaxpr(vjp)(y)
+
+        def names(jaxpr):
+            out = set()
+            for eqn in jaxpr.eqns:
+                out.add(eqn.primitive.name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    out |= names(sub)
+            return out
+
+        bwd_names = names(bwd.jaxpr)
+        assert "div" not in bwd_names
+        assert not [n for n in bwd_names if n.startswith("reduce")]
+        for n in names(fwd.jaxpr) | bwd_names:
+            assert n not in ("reshape", "transpose"), n
 
 
 class TestKernelBuilders:
@@ -275,6 +386,76 @@ class TestS2DUnderParallelism:
             float(jax.jit(ref_loss)(params)),
             rtol=1e-5, atol=1e-6,
         )
+
+    @pytest.mark.parametrize("how", ["DP", "MP-gpipe", "MP-1f1b"])
+    def test_pool_vjp_traces_under_parallelism(self, devices, how):
+        """``group_max`` carries its own VJP: it has to trace under a DP
+        mesh (the strategy's jitted step) and inside the stage functions of
+        a 2-stage pipeline under both schedules, and give the plain step's
+        gradients there."""
+        from distributedpytorch_tpu.config import TrainConfig
+        from distributedpytorch_tpu.ops.losses import bce_dice_loss
+        from distributedpytorch_tpu.parallel import build_strategy
+        from distributedpytorch_tpu.parallel.pipeline import (
+            make_pipeline_value_and_grad_fn,
+        )
+        from distributedpytorch_tpu.train.steps import create_train_state
+
+        H, W, B = 16, 24, 8
+        model = UNet(dtype=jnp.float32, widths=(8,), s2d_levels=1)
+        params = model.init(jax.random.key(0), jnp.zeros((1, H, W, 3)))["params"]
+        rng = np.random.default_rng(0)
+        image = rng.random((B, H, W, 3), dtype=np.float32)
+        mask = (rng.random((B, H, W)) > 0.5).astype(np.int32)
+        target = jnp.asarray(mask)[..., None].astype(jnp.float32)
+
+        def ref_loss(p):
+            return bce_dice_loss(
+                model.apply({"params": p}, jnp.asarray(image)), target
+            )
+
+        ref, ref_grads = jax.jit(jax.value_and_grad(ref_loss))(params)
+        method, _, schedule = how.partition("-")
+        cfg = TrainConfig(
+            train_method=method, batch_size=B, compute_dtype="float32",
+            image_size=(W, H), model_widths=(8,), s2d_levels=1,
+        )
+        strat = build_strategy(cfg)
+        if method == "MP":
+            fn = make_pipeline_value_and_grad_fn(
+                model, strat.mesh, num_microbatches=2, schedule=schedule
+            )
+            loss, grads, _ = jax.jit(fn)(
+                params, None, {"image": jnp.asarray(image), "mask": target}
+            )
+            for a, b in zip(jax.tree.leaves(ref_grads), jax.tree.leaves(grads)):
+                np.testing.assert_allclose(
+                    np.asarray(b), np.asarray(a), rtol=2e-4, atol=1e-5
+                )
+        else:
+            assert dict(strat.mesh.shape) == {"data": 8}
+
+            def stepped(strategy):
+                # one Adam step of the strategy's own jitted train step
+                state, tx = create_train_state(
+                    jax.tree.map(jnp.array, params), cfg.learning_rate,
+                    cfg.weight_decay,
+                )
+                new_state, loss = strategy.build_train_step(model, tx)(
+                    strategy.place_state(state),
+                    strategy.place_batch({"image": image, "mask": mask}),
+                )
+                return jax.device_get(new_state.params), loss
+
+            got, loss = stepped(strat)
+            want, _ = stepped(
+                build_strategy(dataclasses.replace(cfg, train_method="singleGPU"))
+            )
+            # Adam moves a parameter by lr whatever the gradient's size: the
+            # tolerance is TestStrategySteps' (3 lr)
+            for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+                np.testing.assert_allclose(b, a, rtol=5e-4, atol=3e-4)
+        np.testing.assert_allclose(float(loss), float(ref), rtol=1e-5, atol=1e-6)
 
 
 class TestPropertyEquivalence:
