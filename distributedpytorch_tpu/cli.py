@@ -148,11 +148,20 @@ def get_args():
                         help="Weight gradients of the s2d 3x3 convs as 9 "
                              "tap matmuls instead of XLA's conv backward "
                              "(identical numerics; perf A/B lever)")
-    parser.add_argument("--model", dest="model_arch", type=str, default="unet",
-                        choices=["unet", "milesial"],
-                        help="Model family: the reference course UNet "
-                             "(7.76M params) or the original "
-                             "milesial/Pytorch-UNet (31M params, BatchNorm)")
+    parser.add_argument("--model", "--model-arch", dest="model_arch",
+                        type=str, default="unet",
+                        choices=["unet", "milesial", "twotower"],
+                        help="Model (models/__init__.py holds the table): "
+                             "the reference course UNet (7.76M params), the "
+                             "original milesial/Pytorch-UNet (31M params, "
+                             "BatchNorm), or 'twotower': one chip's share "
+                             "(667M params) of the Mamba-2 + expert + "
+                             "attention tower of Nemotron-Labs-TwoTower-"
+                             "30B-A3B's config.json, trained on packed "
+                             "token sequences (-t singleGPU only)")
+    parser.add_argument("--seq-len", type=int, default=8192,
+                        help="Tokens to a packed sequence of a token "
+                             "model's batch (-b counts sequences)")
     parser.add_argument("--model-widths", type=int, nargs="+", default=None,
                         help="Encoder channel widths (default 32 64 128 256, "
                              "the reference model; e.g. 64 128 256 512 for a "
@@ -301,6 +310,7 @@ def main():
         kernel_priors=args.kernel_priors,
         model_arch=args.model_arch,
         model_widths=tuple(args.model_widths) if args.model_widths else None,
+        seq_len=args.seq_len,
         dtype=args.dtype,
         s2d_levels=args.s2d_levels,
         wgrad_taps=args.wgrad_taps,

@@ -119,6 +119,13 @@ class TrainConfig:
     # None = the architecture's documented channel plan. Narrower tuples
     # build faster-compiling variants for tests.
     model_widths: Optional[Tuple[int, ...]] = None
+    # "twotower" (models/twotower.py; models/__init__.py holds the table)
+    # is built at the published share; a mapping of its size keys here
+    # shrinks it for tests and rehearsals (as model_widths does a UNet).
+    model_overrides: Optional[dict] = None
+    # Tokens to a packed sequence of a token model's batch (data/tokens.py);
+    # -b counts sequences.
+    seq_len: int = 8192
     # Shallow levels executed in the space-to-depth domain (ops/s2d.py):
     # exactly equivalent numerics, measured ~1.9× step-time win on TPU v5e at
     # the reference config (the full-res C=32/64 convs starve the 128-lane

@@ -77,11 +77,18 @@ class ShardSpec:
         return padded[self.rank :: self.world]
 
 
+def _stack_items(items) -> Batch:
+    """A batch from its items: every field stacked on a new leading axis."""
+    return {k: np.stack([it[k] for it in items]) for k in items[0]}
+
+
 class DataLoader:
     """Batched, optionally sharded, thread-prefetched iterator over a dataset.
 
-    `dataset` is anything with `__len__` and `__getitem__` returning
-    ``{'image': (H,W,C) f32, 'mask': (H,W) i32}`` (see data/dataset.py).
+    `dataset` is anything with `__len__` and `__getitem__` returning a
+    dict of arrays, the same fields for every item: ``{'image': (H,W,C)
+    f32, 'mask': (H,W) i32}`` (data/dataset.py) or ``{'tokens': (S,) i32}``
+    (data/tokens.py). A batch stacks each field on a new leading axis.
     """
 
     def __init__(
@@ -175,10 +182,7 @@ class DataLoader:
             if missing:
                 fresh = self._decode_batch(missing)
                 for row, i in enumerate(missing):
-                    item = {
-                        "image": fresh["image"][row],
-                        "mask": fresh["mask"][row],
-                    }
+                    item = {k: v[row] for k, v in fresh.items()}
                     self.cache.put(i, item)
                     items[i] = item
                 if len(missing) == len(idx_list):
@@ -187,10 +191,7 @@ class DataLoader:
                     # order — the steady state of a full cache must not
                     # pay a redundant split + re-stack per batch
                     return fresh
-            return {
-                "image": np.stack([items[int(i)]["image"] for i in idx_list]),
-                "mask": np.stack([items[int(i)]["mask"] for i in idx_list]),
-            }
+            return _stack_items([items[int(i)] for i in idx_list])
 
     def _decode_batch(self, idx_list) -> Batch:
         """Decode one batch from the backing dataset; uses the native C++
@@ -214,11 +215,7 @@ class DataLoader:
                         n_threads=max(self.num_workers, 4),
                     )
                     return {"image": imgs, "mask": masks}
-        items = [ds[int(i)] for i in idx_list]
-        return {
-            "image": np.stack([it["image"] for it in items]),
-            "mask": np.stack([it["mask"] for it in items]),
-        }
+        return _stack_items([ds[int(i)] for i in idx_list])
 
     def batch_slices(self, epoch: int = 0) -> list:
         """This epoch's batches as index slices, in order — THE definition

@@ -1,5 +1,167 @@
+"""Model table: one entry per ``TrainConfig.model_arch``.
+
+An entry says how the model is built, what its training loss is, what a
+batch of it holds, which counters ride back with its loss, and whether
+``serve/`` can run it. The trainer, the strategies and the loader ask the
+entry; none of them names a model.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Optional, Tuple
+
 from distributedpytorch_tpu.models.unet import UNet, ConvBlock, Encoder, Decoder  # noqa: F401
 from distributedpytorch_tpu.models.milesial import MilesialUNet  # noqa: F401
+
+
+@dataclasses.dataclass(frozen=True)
+class BatchSpec:
+    """What one batch holds: its fields (every one with the samples on its
+    leading axis) and what a sample is called in logs and rates."""
+
+    fields: Tuple[str, ...]
+    unit: str
+
+    def rows(self, batch) -> int:
+        return int(batch[self.fields[0]].shape[0])
+
+
+IMAGE_BATCH = BatchSpec(("image", "mask"), "img")
+#: ``tokens`` (B, S) int32: packed documents, no padding; position t
+#: predicts token t + 1 (data/tokens.py).
+TOKEN_BATCH = BatchSpec(("tokens",), "seq")
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelEntry:
+    """``build(config, compute_dtype) -> (model, init_fn)`` with
+    ``init_fn(rng, input_hw) -> (params, model_state or None)``;
+    ``loss(model, params, model_state, batch, loss_impl) -> (loss,
+    model_state, train/steps.Counted or None)``; ``evaluate(model, variables, batch)
+    -> {'loss', 'dice'}`` (None: the image metrics of train/steps.py);
+    ``dataset(config)`` builds the data set where the caller gave none
+    (None: the image data sets of data/dataset.py); ``counters(model)``
+    names what ``loss`` counts."""
+
+    build: Callable
+    loss: Callable
+    batch: BatchSpec = IMAGE_BATCH
+    evaluate: Optional[Callable] = None
+    dataset: Optional[Callable] = None
+    counters: Callable = lambda model: ()
+    adam_b2: float = 0.999
+    # the reference's ``(batch_size * loss).backward()`` quirk
+    # (TrainConfig.faithful_loss_scaling) belongs to its image models
+    batch_scaled_backward: bool = True
+    servable: bool = True
+    single_device_only: bool = False
+
+
+def _image_loss(model, params, model_state, batch, loss_impl=None):
+    from distributedpytorch_tpu.train.steps import image_loss
+
+    return image_loss(model, params, model_state, batch, loss_impl)
+
+
+def _build_unet(config, compute_dtype):
+    from distributedpytorch_tpu.models.unet import create_unet, init_unet_params
+
+    model = create_unet(config, dtype=compute_dtype)
+
+    def init_fn(rng, input_hw):
+        return init_unet_params(model, rng, input_hw=input_hw), None
+
+    return model, init_fn
+
+
+def _build_milesial(config, compute_dtype):
+    from distributedpytorch_tpu.models.milesial import (
+        MILESIAL_WIDTHS,
+        init_milesial,
+    )
+    from distributedpytorch_tpu.ops.kernels import conv_epilogue_engaged
+
+    widths = tuple(config.model_widths) if config.model_widths else MILESIAL_WIDTHS
+    model = MilesialUNet(
+        widths=widths,
+        dtype=compute_dtype,
+        s2d_levels=getattr(config, "s2d_levels", -1),
+        wgrad_taps=getattr(config, "wgrad_taps", False),
+        conv_epilogue=conv_epilogue_engaged(config),
+    )
+
+    def init_fn(rng, input_hw):
+        return init_milesial(model, rng, input_hw=input_hw)
+
+    return model, init_fn
+
+
+def _build_twotower(config, compute_dtype):
+    from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
+
+    model = TwoTower(twotower_config(getattr(config, "model_overrides", None)),
+                     dtype=compute_dtype)
+
+    def init_fn(rng, input_hw):
+        return model.init(rng), None
+
+    return model, init_fn
+
+
+def _token_loss(model, params, model_state, batch, loss_impl=None):
+    from distributedpytorch_tpu.train.steps import Counted
+
+    loss, counters, biases = model.loss(params, batch["tokens"])
+    return loss, model_state, Counted(counters.reshape(-1), biases)
+
+
+def _token_eval(model, variables, batch):
+    import jax.numpy as jnp
+
+    loss = model.loss(variables, batch["tokens"])[0]
+    # no Dice for a token model: the trainer's second validation number
+    # reads NaN, and --save-best (highest Dice) never fires
+    return {"loss": loss, "dice": jnp.full((), jnp.nan, loss.dtype)}
+
+
+def _token_dataset(config):
+    from distributedpytorch_tpu.data.tokens import build_token_dataset
+    from distributedpytorch_tpu.models.twotower import twotower_config
+
+    return build_token_dataset(
+        config, twotower_config(config.model_overrides).vocab_size)
+
+
+def _twotower_counters(model):
+    from distributedpytorch_tpu.models.twotower import counter_names
+
+    return counter_names(model.cfg)
+
+
+MODELS = {
+    "unet": ModelEntry(build=_build_unet, loss=_image_loss),
+    "milesial": ModelEntry(build=_build_milesial, loss=_image_loss),
+    # the 52-block tower of Nemotron-Labs-TwoTower-30B-A3B-Base-BF16's
+    # config.json, one chip's share (models/twotower.py); its denoising
+    # tower and block-diffusion decoding are not supported
+    "twotower": ModelEntry(
+        build=_build_twotower, loss=_token_loss, batch=TOKEN_BATCH,
+        evaluate=_token_eval, dataset=_token_dataset,
+        counters=_twotower_counters, adam_b2=0.95,
+        batch_scaled_backward=False, servable=False,
+        single_device_only=True),
+}
+
+
+def model_entry(config_or_arch: Any) -> ModelEntry:
+    arch = (config_or_arch if isinstance(config_or_arch, str)
+            else getattr(config_or_arch, "model_arch", "unet"))
+    try:
+        return MODELS[arch]
+    except KeyError:
+        raise ValueError(
+            f"unknown model_arch {arch!r} (known: {sorted(MODELS)})") from None
 
 
 def create_model(config):
@@ -16,36 +178,4 @@ def create_model(config):
     """
     from distributedpytorch_tpu.ops.precision import get_policy
 
-    compute_dtype = get_policy(config).compute_dtype
-    arch = getattr(config, "model_arch", "unet")
-    if arch == "unet":
-        from distributedpytorch_tpu.models.unet import create_unet, init_unet_params
-
-        model = create_unet(config, dtype=compute_dtype)
-
-        def init_fn(rng, input_hw):
-            return init_unet_params(model, rng, input_hw=input_hw), None
-
-        return model, init_fn
-    if arch == "milesial":
-        from distributedpytorch_tpu.models.milesial import (
-            MILESIAL_WIDTHS,
-            init_milesial,
-        )
-
-        from distributedpytorch_tpu.ops.kernels import conv_epilogue_engaged
-
-        widths = tuple(config.model_widths) if config.model_widths else MILESIAL_WIDTHS
-        model = MilesialUNet(
-            widths=widths,
-            dtype=compute_dtype,
-            s2d_levels=getattr(config, "s2d_levels", -1),
-            wgrad_taps=getattr(config, "wgrad_taps", False),
-            conv_epilogue=conv_epilogue_engaged(config),
-        )
-
-        def init_fn(rng, input_hw):
-            return init_milesial(model, rng, input_hw=input_hw)
-
-        return model, init_fn
-    raise ValueError(f"unknown model_arch {arch!r} (expected 'unet' or 'milesial')")
+    return model_entry(config).build(config, get_policy(config).compute_dtype)

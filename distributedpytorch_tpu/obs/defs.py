@@ -57,6 +57,40 @@ CACHE_MISSES = REGISTRY.counter(
 CACHE_HIT_RATIO = REGISTRY.gauge(
     "dpt_host_cache_hit_ratio", "Decoded-sample cache hit rate [0, 1]")
 
+# -- sparse expert layers (ops/moe.py): counted inside the compiled step,
+#    read back WITH the step's loss (utils/metrics.StepReadout), per expert
+#    block of the model ------------------------------------------------------
+MOE_ROWS_ROUTED = REGISTRY.counter(
+    "dpt_moe_rows_routed_total",
+    "(token, slot) rows routed to the experts held here", ("block",))
+MOE_ROWS_COMPUTED = REGISTRY.counter(
+    "dpt_moe_rows_computed_total",
+    "Rows the grouped expert product multiplied, tile padding included",
+    ("block",))
+MOE_ROWS_MAX_EXPERT = REGISTRY.gauge(
+    "dpt_moe_rows_max_expert",
+    "Rows of the fullest held expert in the last step read back",
+    ("block",))
+_STEP_COUNTERS = {
+    "moe_rows_routed": MOE_ROWS_ROUTED.labels,
+    "moe_rows_computed": MOE_ROWS_COMPUTED.labels,
+    "moe_rows_max_expert": MOE_ROWS_MAX_EXPERT.labels,
+}
+
+
+def record_step_counters(names, values) -> None:
+    """One step's counters, named ``<family>/<block>`` by the model
+    (models/twotower.counter_names), into their families: totals are
+    added to, a ``max`` is set."""
+    for name, value in zip(names, values):
+        family, _, block = name.partition("/")
+        child = _STEP_COUNTERS[family](block=block)
+        if family.endswith("_max_expert"):
+            child.set(float(value))
+        else:
+            child.inc(float(value))
+
+
 # -- serve (recorded by serve/metrics.py off the dispatch loop) -------------
 SERVE_REQUESTS = REGISTRY.counter(
     "dpt_serve_requests_total", "Requests resolved", ("status",))

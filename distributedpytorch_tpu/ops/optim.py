@@ -21,15 +21,17 @@ import jax.numpy as jnp
 import optax
 
 
-def adam_l2(learning_rate: float, weight_decay: float = 1e-8) -> optax.GradientTransformation:
+def adam_l2(learning_rate: float, weight_decay: float = 1e-8,
+            b2: float = 0.999) -> optax.GradientTransformation:
     """torch.optim.Adam(lr, weight_decay) parity (defaults b1=0.9, b2=0.999,
-    eps=1e-8 match torch's)."""
+    eps=1e-8 match torch's); a model-table entry may state another ``b2``
+    (models/__init__.py)."""
 
     @optax.inject_hyperparams
     def _make(lr):
         return optax.chain(
             optax.add_decayed_weights(weight_decay),
-            optax.scale_by_adam(b1=0.9, b2=0.999, eps=1e-8),
+            optax.scale_by_adam(b1=0.9, b2=b2, eps=1e-8),
             optax.scale(-lr),
         )
 

@@ -85,6 +85,12 @@ REDUCE_DTYPE = jnp.float32  # cross-device grad/stats psums
 # every policy). Named here so the fused conv-epilogue kernel
 # (ops/kernels.py) spells the same contract the XLA BN path implements.
 NORM_DTYPE = jnp.float32
+# The token models' f32 islands inside a bf16 step (models/twotower.py):
+# an expert router's scores and gates (a rounding there picks another
+# expert), and a chunked scan's decay, cumulative sums and carried state
+# (ops/sequence.py). Softmax and logits are LOSS_DTYPE, RMSNorm NORM_DTYPE.
+ROUTER_DTYPE = jnp.float32
+SCAN_DTYPE = jnp.float32
 
 
 def _is_float_leaf(x) -> bool:

@@ -24,6 +24,7 @@ Method-name parity with the reference CLI (reference train.py:17,
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -33,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributedpytorch_tpu.config import TrainConfig
 from distributedpytorch_tpu.data.loader import ShardSpec
+from distributedpytorch_tpu.models import model_entry
 from distributedpytorch_tpu.ops.precision import get_policy
 from distributedpytorch_tpu.parallel import mesh as mesh_rules
 from distributedpytorch_tpu.parallel.mesh import MeshConfig
@@ -138,6 +140,12 @@ class Strategy:
         self.mesh: Optional[Mesh] = mesh_rules.build_mesh(
             self.mesh_config, devs
         )
+        if self.mesh is not None and model_entry(config).single_device_only:
+            raise ValueError(
+                f"model_arch {config.model_arch!r} trains on one device "
+                f"(-t singleGPU): its expert exchange and sequence split "
+                f"across chips do not exist yet (ROADMAP M4, M5); "
+                f"{config.train_method!r} builds a mesh")
         self.batch_sharding: Optional[NamedSharding] = (
             None if self.mesh is None
             else NamedSharding(
@@ -308,14 +316,17 @@ class Strategy:
         # Quirk-1 scale uses the PER-PROCESS batch_size (the reference's
         # `-b` value): fit_DDP scales by its local -b then
         # mean-allreduces, so the global batch would overscale by world.
+        entry = model_entry(self.config)
         return make_train_step(
             model,
             tx,
             batch_size=self.config.batch_size,
-            faithful_loss_scaling=self.config.faithful_loss_scaling,
+            faithful_loss_scaling=(self.config.faithful_loss_scaling
+                                   and entry.batch_scaled_backward),
             remat=self.config.remat,
             loss_impl=self._train_loss_impl(),
             policy=self.policy,
+            loss_fn=entry.loss,
         )
 
     def _pipeline_raw_step(self, model, tx) -> Callable:
@@ -408,6 +419,11 @@ class Strategy:
         )
 
     def build_eval_step(self, model) -> Callable:
+        evaluate = model_entry(self.config).evaluate
+        if evaluate is not None:
+            # the model table's own validation metrics (a token model has
+            # no mask to take a Dice against)
+            return jax.jit(functools.partial(evaluate, model))
         if self.is_pipeline:
             # Eval runs the pipelined forward too (the reference
             # evaluates through the pipe model, train.py:62-64 →

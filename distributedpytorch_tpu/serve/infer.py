@@ -167,9 +167,16 @@ def load_inference_bundle(
     loudly, since the serving numerics change."""
     from distributedpytorch_tpu.checkpoint import resolve_checkpoint
     from distributedpytorch_tpu.config import TrainConfig
-    from distributedpytorch_tpu.models import create_model
+    from distributedpytorch_tpu.models import create_model, model_entry
     from distributedpytorch_tpu.ops import quant
 
+    if not model_entry(model_arch).servable:
+        raise ValueError(
+            f"serve/ and predict run an image forward (image in, mask "
+            f"out); model {model_arch!r} is a token model that this "
+            f"package only trains: it has no prefill, decode or cache "
+            f"here, and the published model's generation by diffusion "
+            f"over blocks is not supported (ROADMAP M10)")
     if quantize not in (None, "int8"):
         raise ValueError(
             f"quantize must be None or 'int8', got {quantize!r}"

@@ -74,7 +74,7 @@ from distributedpytorch_tpu.ops.schedule import ReduceLROnPlateau
 from distributedpytorch_tpu.train.steps import create_train_state
 from distributedpytorch_tpu.utils import faults
 from distributedpytorch_tpu.utils.faults import NonFiniteLossError, StepWatchdog
-from distributedpytorch_tpu.utils.metrics import LossRecords
+from distributedpytorch_tpu.utils.metrics import LossRecords, StepReadout
 from distributedpytorch_tpu.utils.prefetch import (
     pipelined_placement,
     stacked_work,
@@ -96,7 +96,11 @@ class Trainer:
         # module scope would be circular
         from distributedpytorch_tpu.parallel import build_strategy
 
+        from distributedpytorch_tpu.models import create_model, model_entry
+
         self.config = config
+        # the model table's entry: loss, batch fields, counters, data set
+        self.entry = model_entry(config)
         self.strategy = strategy or build_strategy(config)
         self.dataset = dataset if dataset is not None else self._build_dataset()
         self.rng = rng if rng is not None else jax.random.key(config.seed)
@@ -154,9 +158,11 @@ class Trainer:
         self._heartbeat = None
 
         # model + state
-        from distributedpytorch_tpu.models import create_model
-
         self.model, init_fn = create_model(config)
+        # names of what the model's loss counts: they ride back with the
+        # loss in one array (train/steps.pack_readout) and reach the
+        # registry when LossRecords reads that loss (StepReadout)
+        self.counter_names = tuple(self.entry.counters(self.model))
         params, model_state = init_fn(
             self.rng, (config.image_size[1], config.image_size[0])
         )
@@ -172,7 +178,7 @@ class Trainer:
         self.policy = self.strategy.policy
         state, self.tx = create_train_state(
             params, lr0, config.weight_decay, model_state=model_state,
-            policy=self.policy,
+            policy=self.policy, adam_b2=self.entry.adam_b2,
         )
         self.scheduler = ReduceLROnPlateau(
             lr=lr0, patience=config.plateau_patience, factor=config.plateau_factor
@@ -250,6 +256,12 @@ class Trainer:
                 f"early_stop_patience must be >= 0 (0 = off), got "
                 f"{config.early_stop_patience}"
             )
+        if self.grad_accum > 1 and "image" not in self.entry.batch.fields:
+            raise ValueError(
+                "--grad-accum is exact accumulation of the image loss's "
+                "batch statistics (train/steps.make_accum_train_step); "
+                f"model_arch {config.model_arch!r} has no such step"
+            )
         if self.k_dispatch > 1 and self.grad_accum > 1:
             raise ValueError(
                 "--steps-per-dispatch and --grad-accum both stack loader "
@@ -294,6 +306,8 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def _build_dataset(self):
+        if self.entry.dataset is not None:
+            return self.entry.dataset(self.config)
         if self.config.synthetic_samples > 0:
             from distributedpytorch_tpu.data import SyntheticSegmentationDataset
 
@@ -382,7 +396,8 @@ class Trainer:
             from distributedpytorch_tpu.ops.optim import adam_l2
 
             saved_tx = saved_policy.wrap_optimizer(
-                adam_l2(self.scheduler.lr, self.config.weight_decay)
+                adam_l2(self.scheduler.lr, self.config.weight_decay,
+                        b2=self.entry.adam_b2)
             )
             # abstract target: from_state_dict needs only the STRUCTURE,
             # so eval_shape builds it without a host copy of the params
@@ -746,6 +761,16 @@ class Trainer:
             obsm.CACHE_HIT_RATIO.set(hits / total)
 
     # ------------------------------------------------------------------
+    def _readout(self, loss):
+        """A step's second output as LossRecords takes it: the loss
+        itself, or, for a model that counts (``counter_names``), the
+        packed ``[loss, *counters]`` behind a ``StepReadout`` whose one
+        readback feeds the loss to the records and the counters to the
+        registry."""
+        if not self.counter_names:
+            return loss
+        return StepReadout(loss, self.counter_names)
+
     def _record(self, loss, n_imgs: int, global_step: int, pbar) -> None:
         rows_before = len(self.records.train_rows)
         self.records.record_train(global_step, loss, n_imgs)
@@ -959,13 +984,13 @@ class Trainer:
                 with tqdm(
                     total=min(n_train, len(self.train_loader) * cfg.batch_size),
                     desc=f"Epoch {epoch + 1}/{cfg.epochs}",
-                    unit="img",
+                    unit=self.entry.batch.unit,
                     disable=not self.strategy.is_main,
                     leave=False,
                 ) as pbar:
                     def run_one(batch, placed=None):
                         nonlocal global_step
-                        n_imgs = batch["image"].shape[0]
+                        n_imgs = self.entry.batch.rows(batch)
                         if placed is None:
                             placed = self.strategy.place_batch(batch)
                         # policy 'skip' holds the pre-step state so a
@@ -975,6 +1000,7 @@ class Trainer:
                         with self.tracer.span("dispatch", step=global_step + 1,
                                               epoch=epoch, seq=seq):
                             self.state, loss = self.train_step(self.state, placed)
+                        loss = self._readout(loss)
                         if faults.fire("nan_loss", epoch=epoch,
                                        step=global_step + 1):
                             loss = float("nan")  # forced step output
@@ -1012,7 +1038,7 @@ class Trainer:
                             def pull():
                                 if "host" not in memo:
                                     memo["host"] = np.asarray(losses)
-                                return memo["host"][i]
+                                return float(self._readout(memo["host"][i]))
 
                             # LossRecords' non-blocking drain starts an
                             # async host copy when a row is parked; expose
@@ -1024,7 +1050,8 @@ class Trainer:
 
                         for i, b in enumerate(buffered):
                             global_step += 1
-                            self._record(lazy(i), b["image"].shape[0], global_step, pbar)
+                            self._record(lazy(i), self.entry.batch.rows(b),
+                                         global_step, pbar)
 
                     def run_accum(buffered, placed):
                         # ONE optimizer step over the K stacked batches —
@@ -1038,7 +1065,7 @@ class Trainer:
                         global_step += 1
                         self._record(
                             loss,
-                            sum(b["image"].shape[0] for b in buffered),
+                            sum(self.entry.batch.rows(b) for b in buffered),
                             global_step,
                             pbar,
                         )

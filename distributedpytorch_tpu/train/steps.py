@@ -26,7 +26,7 @@ policy (ops/losses.py pins it). Inputs are NHWC.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import flax.struct
 import jax
@@ -60,6 +60,7 @@ def create_train_state(
     weight_decay: float = 1e-8,
     model_state=None,
     policy: Optional[PrecisionPolicy] = None,
+    adam_b2: float = 0.999,
 ) -> Tuple[TrainState, optax.GradientTransformation]:
     """Build the TrainState + optimizer under a precision policy.
 
@@ -69,7 +70,7 @@ def create_train_state(
     optimizer is wrapped with f32 master weights (the master is seeded
     from the params BEFORE the down-cast, so fresh-init and restored f32
     weights lose nothing to the storage dtype)."""
-    tx = adam_l2(learning_rate, weight_decay)
+    tx = adam_l2(learning_rate, weight_decay, b2=adam_b2)
     if policy is not None:
         tx = policy.wrap_optimizer(tx)
         # init the (wrapped) optimizer on the FULL-precision params: the
@@ -103,10 +104,62 @@ LOSS_SCOPE = "loss"
 OPTIMIZER_SCOPE = "optimizer"
 
 
-def loss_fn(model, params, batch: Dict[str, jax.Array]) -> jax.Array:
-    preds = model.apply({"params": params}, batch["image"])
+def image_loss(model, params, model_state, batch: Dict[str, jax.Array],
+               loss_impl: Callable = None):
+    """The image models' training loss, as every model-table entry's
+    ``loss`` is called (models/__init__.py): ``(loss, model state after
+    the forward pass, counters or None)``. BCE − log-dice of the sigmoid
+    probabilities against ``batch['mask']``; ``loss_impl(preds, target)``
+    swaps the loss computation (the strategy's hook for the fused Pallas
+    kernel, ops/fused_loss.py). A stateful model (BatchNorm) is applied
+    with ``mutable=['batch_stats']`` and hands back the updated
+    statistics: under a sharded batch the statistics XLA computes are
+    global-batch statistics — SyncBN semantics for free
+    (models/milesial.py notes)."""
+    impl = loss_impl or bce_dice_loss
+    if is_stateful_model(model):
+        preds, updates = model.apply(
+            {"params": params, "batch_stats": model_state},
+            batch["image"],
+            train=True,
+            mutable=["batch_stats"],
+        )
+        model_state = updates["batch_stats"]
+    else:
+        preds = model.apply({"params": params}, batch["image"])
     with jax.named_scope(LOSS_SCOPE):
-        return bce_dice_loss(preds, _prep_mask(batch["mask"]))
+        return impl(preds, _prep_mask(batch["mask"])), model_state, None
+
+
+def pack_readout(loss, counters):
+    """One step's loss and the counters its model counts, as ONE float32
+    array ``[loss, *counters]``: the counters ride back to the host with
+    the loss, through the readback the loss already has
+    (utils/metrics.StepReadout unpacks it there)."""
+    return jnp.concatenate([
+        jnp.reshape(loss, (1,)).astype(precision_ops.LOSS_DTYPE),
+        jnp.ravel(counters).astype(precision_ops.LOSS_DTYPE)])
+
+
+class Counted(NamedTuple):
+    """What a model's loss hands back beside the loss and the model state
+    (the third output of a model-table entry's ``loss``; None where it has
+    neither). ``counters`` ride back to the host with the loss
+    (``pack_readout``). ``buffers`` is a part of the parameter tree (the
+    same nesting, fewer leaves) that the model sets itself after each
+    step: the optimiser's result for those leaves is dropped (the routers'
+    selection biases, models/twotower.py)."""
+
+    counters: Any = None
+    buffers: Any = None
+
+
+def set_buffers(params, buffers):
+    """``params`` with the leaves of ``buffers`` in place of its own."""
+    if not isinstance(buffers, dict):
+        return buffers
+    return {k: set_buffers(v, buffers[k]) if k in buffers else v
+            for k, v in params.items()}
 
 
 def apply_optimizer(tx, grads, opt_state, params):
@@ -115,33 +168,6 @@ def apply_optimizer(tx, grads, opt_state, params):
     with jax.named_scope(OPTIMIZER_SCOPE):
         updates, opt_state = tx.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state
-
-
-def _make_loss_fns(loss_impl):
-    """The (pure, stateful) loss pair with a pluggable ``loss_impl(preds,
-    target) -> loss`` — the strategy's hook for routing the training loss
-    through the fused Pallas kernel (ops/fused_loss.py); None keeps the
-    XLA loss."""
-    if loss_impl is None:
-        return loss_fn, stateful_loss_fn
-
-    def custom_loss_fn(model, params, batch):
-        preds = model.apply({"params": params}, batch["image"])
-        with jax.named_scope(LOSS_SCOPE):
-            return loss_impl(preds, _prep_mask(batch["mask"]))
-
-    def custom_stateful_loss_fn(model, params, model_state, batch):
-        preds, updates = model.apply(
-            {"params": params, "batch_stats": model_state},
-            batch["image"],
-            train=True,
-            mutable=["batch_stats"],
-        )
-        with jax.named_scope(LOSS_SCOPE):
-            loss = loss_impl(preds, _prep_mask(batch["mask"]))
-        return loss, updates["batch_stats"]
-
-    return custom_loss_fn, custom_stateful_loss_fn
 
 
 def is_stateful_model(model) -> bool:
@@ -155,24 +181,6 @@ def is_stateful_model(model) -> bool:
 _is_stateful = is_stateful_model  # historical internal alias
 
 
-def stateful_loss_fn(
-    model, params, model_state, batch: Dict[str, jax.Array]
-) -> Tuple[jax.Array, Any]:
-    """Training loss for a stateful model: applies with
-    ``mutable=['batch_stats']`` and returns the updated stats as aux.
-    Under a sharded batch the statistics XLA computes are global-batch
-    statistics — SyncBN semantics for free (models/milesial.py notes)."""
-    preds, updates = model.apply(
-        {"params": params, "batch_stats": model_state},
-        batch["image"],
-        train=True,
-        mutable=["batch_stats"],
-    )
-    with jax.named_scope(LOSS_SCOPE):
-        loss = bce_dice_loss(preds, _prep_mask(batch["mask"]))
-    return loss, updates["batch_stats"]
-
-
 def make_train_step(
     model,
     tx: optax.GradientTransformation,
@@ -181,9 +189,17 @@ def make_train_step(
     remat: bool = False,
     loss_impl: Callable = None,
     policy: Optional[PrecisionPolicy] = None,
+    loss_fn: Callable = image_loss,
 ) -> Callable[[TrainState, Dict[str, jax.Array]], Tuple[TrainState, jax.Array]]:
     """Build the (unjitted) train step; the strategy decides how to jit/shard
     it. Returns ``step(state, batch) -> (state, unscaled_loss)``.
+
+    ``loss_fn`` is the model-table entry's loss (models/__init__.py):
+    ``(model, params, model_state, batch, loss_impl) -> (loss, model_state,
+    Counted or None)``. Where it counts something, the step's second
+    output is ``pack_readout(loss, counters)``, not the bare loss; where
+    it sets leaves of the parameter tree itself, they replace what the
+    optimiser made of them.
 
     `remat=True` rematerializes the forward during the backward
     (jax.checkpoint): activations are recomputed instead of stored, cutting
@@ -202,22 +218,19 @@ def make_train_step(
     """
 
     grad_scale = float(batch_size) if faithful_loss_scaling else 1.0
-    stateful = _is_stateful(model)
-    pure_fn, stateful_fn = _make_loss_fns(loss_impl)
-    raw_fwd = stateful_fn if stateful else pure_fn
+    def raw_fwd(model, params, model_state, batch):
+        loss, model_state, counted = loss_fn(
+            model, params, model_state, batch, loss_impl)
+        return loss, (model_state, counted or Counted())
+
     fwd = jax.checkpoint(raw_fwd, static_argnums=(0,)) if remat else raw_fwd
 
     def train_step(state: TrainState, batch: Dict[str, jax.Array]):
-        # one update body for both model kinds: the pure path carries the
-        # (None) model_state through as aux so the optimizer/step logic
-        # exists exactly once
-        if stateful:
-            value_fn = lambda p: fwd(model, p, state.model_state, batch)  # noqa: E731
-        else:
-            value_fn = lambda p: (fwd(model, p, batch), state.model_state)  # noqa: E731
-        (loss, model_state), grads = jax.value_and_grad(value_fn, has_aux=True)(
-            state.params
-        )
+        # one update body for every model: a pure model carries its (None)
+        # model_state through as aux
+        (loss, (model_state, counted)), grads = jax.value_and_grad(
+            lambda p: fwd(model, p, state.model_state, batch), has_aux=True
+        )(state.params)
         if policy is not None:
             grads = policy.cast_grads(grads)
         if grad_scale != 1.0:
@@ -225,6 +238,8 @@ def make_train_step(
             grads = jax.tree.map(lambda g: g * grad_scale, grads)
         params, opt_state = apply_optimizer(
             tx, grads, state.opt_state, state.params)
+        if counted.buffers is not None:
+            params = set_buffers(params, counted.buffers)
         return (
             TrainState(
                 params=params,
@@ -232,7 +247,8 @@ def make_train_step(
                 step=state.step + 1,
                 model_state=model_state,
             ),
-            loss,
+            loss if counted.counters is None
+            else pack_readout(loss, counted.counters),
         )
 
     return train_step

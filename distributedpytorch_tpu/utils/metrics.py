@@ -45,6 +45,30 @@ def _start_async_copy(x) -> None:
         pass
 
 
+class StepReadout:
+    """One step's ``[loss, *counters]`` (train/steps.pack_readout) as the
+    loss that ``LossRecords`` takes: ``float()`` is the loss, and the one
+    host copy that gives it also hands the counters to the registry
+    (obs/defs.record_step_counters). The counters have no readback of
+    their own, and none before the loss's lagged one."""
+
+    def __init__(self, packed, names):
+        self.packed = packed
+        self.names = names
+        self._loss = None
+
+    def copy_to_host_async(self) -> None:
+        _start_async_copy(self.packed)
+
+    def __float__(self) -> float:
+        if self._loss is None:
+            host = np.asarray(self.packed, dtype=np.float64)
+            self._loss = float(host[0])
+            obsm.record_step_counters(self.names, host[1:])
+            self.packed = None
+        return self._loss
+
+
 class LossRecords:
     """Accumulates train/val loss rows and writes reference-format pickles."""
 

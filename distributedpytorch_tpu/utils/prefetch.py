@@ -70,11 +70,21 @@ def _nbytes(item) -> int:
     return int(getattr(item, "nbytes", 0))
 
 
+def _tokens(item) -> int:
+    """Tokens a work item holds: the elements of its ``tokens`` fields."""
+    if isinstance(item, dict):
+        return sum(int(getattr(v, "size", 0)) if k == "tokens" else _tokens(v)
+                   for k, v in item.items())
+    if isinstance(item, (list, tuple)):
+        return sum(_tokens(v) for v in item)
+    return 0
+
+
 def _bytes_tag(item) -> dict:
-    """The ``bytes`` tag of a work item's spans: left out where the item
-    holds no arrays."""
-    nbytes = _nbytes(item)
-    return {"bytes": nbytes} if nbytes else {}
+    """The ``bytes`` tag of a work item's spans, and ``tokens`` for a
+    batch of them: each left out where the item holds none."""
+    tags = {"bytes": _nbytes(item), "tokens": _tokens(item)}
+    return {k: v for k, v in tags.items() if v}
 
 
 def _fetched(items: Iterable[T], tracer, tags: dict) -> Iterator[Tuple[int, T, dict]]:
@@ -197,7 +207,7 @@ def stacked_work(
         return
     buffer: list = []
     for b in batches:
-        if b["image"].shape[0] == batch_size:
+        if next(iter(b.values())).shape[0] == batch_size:
             buffer.append(b)
             if len(buffer) == stack_size:
                 yield (STACK, buffer)
