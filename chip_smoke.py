@@ -67,6 +67,10 @@ KERNEL_RTOL = {
     "fused_bn_act": 1e-5,
     "fused_bn_act_grad": 1e-4,
     "wgrad_9tap": 1e-5,
+    # bf16 outputs and gradients of two roundings of the same float32
+    # mathematics (the kernel normalises after the values' product, the
+    # XLA path before): a few bf16 ulps (2^-8) of the largest entry
+    "causal_attention": 2e-2,
 }
 # served mask vs direct forward, and --kernels pallas vs xla: share of
 # pixels that may differ (probabilities within bf16 rounding of 0.5)
@@ -93,6 +97,9 @@ class Sizes:
     param_count: int | None = 7_760_097
     bn_widths: tuple = (64, 128, 256, 512, 1024)
     wgrad_hw_ci_co: tuple = (320, 480, 128, 128)
+    # the token cell's attention block: batch, length, query heads,
+    # key-value heads, head size
+    attention_bshgd: tuple = (2, 8192, 32, 2, 128)
     multichip_batch: int = 8
     multichip_steps: int = 3
 
@@ -341,7 +348,8 @@ def phase_kernels(out: str, seed: int, sizes: Sizes) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from distributedpytorch_tpu.ops import losses
+    from distributedpytorch_tpu.ops import attention_pallas, losses
+    from distributedpytorch_tpu.ops import sequence as seq
     from distributedpytorch_tpu.ops.conv_backward import _wgrad_einsum
     from distributedpytorch_tpu.ops.fused_loss import fused_bce_dice_loss
     from distributedpytorch_tpu.ops.kernels import (
@@ -450,6 +458,30 @@ def phase_kernels(out: str, seed: int, sizes: Sizes) -> dict:
     dy = jnp.asarray(rng.standard_normal((b, wh, ww, co)), jnp.bfloat16)
     run("wgrad_9tap", wgrad_9tap_pallas, _wgrad_einsum, (x, dy),
         [("", lambda r: r, KERNEL_RTOL["wgrad_9tap"])])
+    del x, dy
+
+    # the fused attention kernel against the blocked XLA path it replaces
+    # on a TPU: output and the three gradients, bf16 as the token model runs
+    ab, s, hq, hkv, d = sizes.attention_bshgd
+    tile = seq.attention_path("tpu", s, d, hq, hkv)
+    q = jnp.asarray(rng.standard_normal((ab, s, hq, d)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((ab, s, hkv, d)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((ab, s, hkv, d)), jnp.bfloat16)
+    weight = jnp.asarray(rng.standard_normal((ab, s, hq, d)), jnp.float32)
+
+    def attention_grads(fn):
+        def loss(q, k, v, weight):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    run("causal_attention",
+        attention_grads(lambda q, k, v: attention_pallas.causal_attention(
+            q, k, v, tile)),
+        attention_grads(seq.blocked_attention), (q, k, v, weight),
+        [("", lambda r: r[1], KERNEL_RTOL["causal_attention"])]
+        + [(f"d{n}_", (lambda r, i=i: r[0][i]), KERNEL_RTOL["causal_attention"])
+           for i, n in enumerate("qkv")])
     return {**_device_fields(), "kernels": rows,
             "peak_bytes_in_use": _peak_bytes()}
 

@@ -42,7 +42,8 @@ class ModelEntry:
     -> {'loss', 'dice'}`` (None: the image metrics of train/steps.py);
     ``dataset(config)`` builds the data set where the caller gave none
     (None: the image data sets of data/dataset.py); ``counters(model)``
-    names what ``loss`` counts."""
+    names what ``loss`` counts; ``attention_kernel_blocks(model, config)``
+    is how many attention blocks take the fused kernel on this backend."""
 
     build: Callable
     loss: Callable
@@ -50,6 +51,7 @@ class ModelEntry:
     evaluate: Optional[Callable] = None
     dataset: Optional[Callable] = None
     counters: Callable = lambda model: ()
+    attention_kernel_blocks: Callable = lambda model, config: 0
     adam_b2: float = 0.999
     # the reference's ``(batch_size * loss).backward()`` quirk
     # (TrainConfig.faithful_loss_scaling) belongs to its image models
@@ -139,6 +141,12 @@ def _twotower_counters(model):
     return counter_names(model.cfg)
 
 
+def _twotower_kernel_blocks(model, config):
+    import jax
+
+    return model.attention_kernel_blocks(jax.default_backend(), config.seq_len)
+
+
 MODELS = {
     "unet": ModelEntry(build=_build_unet, loss=_image_loss),
     "milesial": ModelEntry(build=_build_milesial, loss=_image_loss),
@@ -148,7 +156,8 @@ MODELS = {
     "twotower": ModelEntry(
         build=_build_twotower, loss=_token_loss, batch=TOKEN_BATCH,
         evaluate=_token_eval, dataset=_token_dataset,
-        counters=_twotower_counters, adam_b2=0.95,
+        counters=_twotower_counters,
+        attention_kernel_blocks=_twotower_kernel_blocks, adam_b2=0.95,
         batch_scaled_backward=False, servable=False,
         single_device_only=True),
 }

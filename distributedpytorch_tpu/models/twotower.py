@@ -232,6 +232,14 @@ class TwoTower:
             return seq.matmul(y.reshape(lead + (-1,)), p["o"]["kernel"],
                               "bse,ed->bsd")
 
+    def attention_kernel_blocks(self, platform: str, seq_len: int) -> int:
+        """How many of the model's blocks run attention on the fused
+        kernel at this length on ``platform`` (0: blocked XLA)."""
+        c = self.cfg
+        tile = seq.attention_path(platform, seq_len, c.head_dim,
+                                  c.num_attention_heads, c.num_key_value_heads)
+        return c.hybrid_override_pattern.count("*") if tile else 0
+
     def _experts(self, p, x):
         """``(shared(x) + the held experts' part, counters (3,), the
         chosen experts (T, k), the router's bias after this step)``."""

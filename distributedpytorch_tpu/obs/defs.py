@@ -71,6 +71,13 @@ MOE_ROWS_MAX_EXPERT = REGISTRY.gauge(
     "dpt_moe_rows_max_expert",
     "Rows of the fullest held expert in the last step read back",
     ("block",))
+# -- the fused attention kernel (ops/attention_pallas.py): which path the
+#    model's attention blocks took, decided from platform and shapes
+#    (ops/sequence.attention_path) and set once when the Trainer is built --
+ATTENTION_KERNEL_BLOCKS = REGISTRY.gauge(
+    "dpt_attention_kernel_blocks",
+    "Attention blocks of the model whose shapes take the fused kernel "
+    "(0: all run as blocked XLA)")
 _STEP_COUNTERS = {
     "moe_rows_routed": MOE_ROWS_ROUTED.labels,
     "moe_rows_computed": MOE_ROWS_COMPUTED.labels,

@@ -1,11 +1,17 @@
-"""Sequence mixers and the token loss in plain XLA: RMSNorm, rotary
-positions, causal grouped-query attention in query blocks, Mamba-2's
-state-space dual (SSD) as a chunked scan, and next-token cross-entropy
-in token blocks.
+"""Sequence mixers and the token loss: RMSNorm, rotary positions, causal
+grouped-query attention, Mamba-2's state-space dual (SSD) as a chunked
+scan, and next-token cross-entropy in token blocks.
 
-Everything here is differentiated by jax: the chunked scan's backward is
-the chunked scan's transpose, attention's scores are recomputed block by
-block (``jax.checkpoint``), and so are the logits. Matrix products take
+Attention runs on one of two paths, chosen by ``attention_path`` from what
+the code observes (platform and shapes), never by a user: on a TPU, with a
+head size that fills the lanes and a length that a tile divides, the
+fused kernel of ``ops/attention_pallas.py`` (forward and its own backward;
+scores stay in VMEM); everywhere else (the CPU, toy sizes, a ragged
+length) ``blocked_attention``, query blocks in plain XLA, which is also
+the kernel's oracle. Everything else here is plain XLA differentiated by
+jax: the chunked scan's backward is the chunked scan's transpose, the
+blocked attention's scores are recomputed block by block
+(``jax.checkpoint``), and so are the logits. Matrix products take
 operands in the compute dtype and add up in float32; what rounding would
 bend (norm statistics, softmax, the scan's decays and carried state, the
 logits) is float32 under every policy, spelled by ``ops/precision``'s
@@ -20,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributedpytorch_tpu.ops import attention_pallas
 from distributedpytorch_tpu.ops.precision import (
     LOSS_DTYPE,
     NORM_DTYPE,
@@ -31,6 +38,10 @@ from distributedpytorch_tpu.ops.precision import (
 #: logits (block x vocabulary) near half a gigabyte at the published sizes.
 ATTENTION_BLOCK = 512
 LOSS_BLOCK = 2048
+#: Query and key rows to a tile of the fused attention kernel, the largest
+#: that divides the length (on the chip at 8192: 39.9, 51.4, 97.1 ms a
+#: block against plain XLA's 145.5, PERF.md §6).
+ATTENTION_TILES = (1024, 512, 256)
 
 
 def matmul(x, w, spec: str):
@@ -68,7 +79,31 @@ def rotary(x, theta: float):
     return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(x.dtype)
 
 
+def attention_path(platform: str, s: int, d: int, hq: int, hkv: int) -> int:
+    """The fused kernel's tile where attention of these shapes runs on it,
+    0 where it runs as ``blocked_attention``: the kernel on a TPU when the
+    head size is a multiple of the 128 lanes, a tile divides the length,
+    the query heads divide evenly over the key-value heads and one
+    key-value head's sequence fits the kernel's share of VMEM."""
+    if (platform != "tpu" or d % 128 or hq % hkv
+            or not attention_pallas.fits_vmem(s, d)):
+        return 0
+    return next((t for t in ATTENTION_TILES if s % t == 0), 0)
+
+
 def causal_attention(q, k, v, block: int = ATTENTION_BLOCK):
+    """Causal grouped-query attention with scores scaled by 1 / sqrt(D):
+    ``q`` (B, S, Hq, D), ``k`` and ``v`` (B, S, Hkv, D), Hq a multiple of
+    Hkv. The fused kernel where ``attention_path`` says so, else query
+    blocks of ``block`` rows in plain XLA."""
+    tile = attention_path(jax.default_backend(), q.shape[1], q.shape[3],
+                          q.shape[2], k.shape[2])
+    if tile:
+        return attention_pallas.causal_attention(q, k, v, tile)
+    return blocked_attention(q, k, v, block)
+
+
+def blocked_attention(q, k, v, block: int = ATTENTION_BLOCK):
     """Causal grouped-query attention, one block of queries at a time
     against the keys up to that block's end, so that no (S x S) score
     matrix exists: ``q`` (B, S, Hq, D), ``k`` and ``v`` (B, S, Hkv, D),

@@ -163,6 +163,11 @@ class Trainer:
         # loss in one array (train/steps.pack_readout) and reach the
         # registry when LossRecords reads that loss (StepReadout)
         self.counter_names = tuple(self.entry.counters(self.model))
+        # which path attention takes is decided from platform and shapes
+        # (ops/sequence.attention_path); said once here, outside any trace
+        self.attention_kernel_blocks = self.entry.attention_kernel_blocks(
+            self.model, config)
+        obsm.ATTENTION_KERNEL_BLOCKS.set(self.attention_kernel_blocks)
         params, model_state = init_fn(
             self.rng, (config.image_size[1], config.image_size[0])
         )
@@ -929,12 +934,14 @@ class Trainer:
         cfg = self.config
         n_train = self.train_loader.num_samples()
         logger.info(
-            "Training %s: %d epochs, global batch %d, lr %.2e, %d train batches/shard",
+            "Training %s: %d epochs, global batch %d, lr %.2e, %d train "
+            "batches/shard, %d attention blocks on the fused kernel",
             cfg.train_method,
             cfg.epochs,
             self.strategy.global_batch_size,
             get_learning_rate(self.state.opt_state),
             len(self.train_loader),
+            self.attention_kernel_blocks,
         )
         # whole-run capture only when no step range was asked for — the
         # two would race one another's start/stop on the same profiler

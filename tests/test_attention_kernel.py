@@ -1,0 +1,115 @@
+"""The fused attention kernel (ops/attention_pallas.py), interpreted on
+the CPU: its output and three gradients against full attention in float32
+and against the blocked XLA path, and where ``attention_path`` sends what.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from distributedpytorch_tpu.obs import defs
+from distributedpytorch_tpu.ops import attention_pallas
+from distributedpytorch_tpu.ops import sequence as seq
+
+
+def full_attention(q, k, v):
+    """Softmax over the whole (S x S) scores, float32, nothing blocked."""
+    s, rep = q.shape[1], q.shape[2] // k.shape[2]
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        precision="highest") / math.sqrt(q.shape[-1])
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v,
+                      precision="highest")
+
+
+def output_and_gradients(fn, q, k, v, weight):
+    """[out, dq, dk, dv] of ``sum(fn(q, k, v) * weight)``, in float32."""
+    def loss(q, k, v):
+        out = fn(q, k, v).astype(jnp.float32)
+        return jnp.sum(out * weight), out
+
+    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return [out] + [g.astype(jnp.float32) for g in grads]
+
+
+def gap(a, b):
+    return float(jnp.max(jnp.abs(a - b)))
+
+
+# (S, query heads a key-value head, D, tile): every group holds more than
+# one query head, so dK and dV are sums over heads as well as query tiles
+SHAPES = [(256, 2, 128, 128), (512, 4, 128, 256), (384, 3, 128, 128),
+          (256, 16, 128, 256), (256, 2, 256, 128)]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s,rep,d,tile", SHAPES)
+def test_kernel_is_full_attention_and_the_blocked_path(s, rep, d, tile, dtype):
+    dtype = jnp.dtype(dtype)
+    hkv = 2
+    keys = jax.random.split(jax.random.key(s + rep + d), 4)
+    q = jax.random.normal(keys[0], (1, s, hkv * rep, d)).astype(dtype)
+    k = jax.random.normal(keys[1], (1, s, hkv, d)).astype(dtype)
+    v = jax.random.normal(keys[2], (1, s, hkv, d)).astype(dtype)
+    weight = jax.random.normal(keys[3], q.shape)
+
+    def kernel(q, k, v):
+        return attention_pallas.causal_attention(q, k, v, tile, interpret=True)
+
+    def blocked(q, k, v):
+        return seq.blocked_attention(q, k, v, block=tile // 2)
+
+    whole, fused, xla = jax.jit(lambda *a: [
+        output_and_gradients(fn, *a) for fn in (full_attention, kernel, blocked)
+    ])(q, k, v, weight)
+    for name, exact, mine, theirs in zip(("out", "dq", "dk", "dv"),
+                                         whole, fused, xla):
+        if dtype == jnp.float32:
+            size = float(jnp.max(jnp.abs(exact)))
+            assert gap(mine, exact) < 1e-4 * max(1.0, size), name
+            assert gap(mine, theirs) < 1e-4 * max(1.0, size), name
+        else:
+            # no further from the float32 answer than twice the XLA path
+            assert gap(mine, exact) <= 2 * gap(theirs, exact), name
+
+
+@pytest.mark.parametrize("platform,s,d,hq,hkv,tile", [
+    ("tpu", 8192, 128, 32, 2, 1024),                # the published block
+    ("tpu", 8192 + 512, 128, 32, 2, 512),           # a smaller tile divides it
+    ("tpu", 8192 + 72, 128, 32, 2, 0),              # a ragged length
+    ("tpu", 72, 16, 4, 2, 0),                       # the rehearsal's toy head
+    ("tpu", 8192, 64, 32, 2, 0),                    # half the lanes
+    ("tpu", 65536, 128, 32, 2, 0),                  # a head VMEM cannot hold
+    ("cpu", 8192, 128, 32, 2, 0),
+])
+def test_attention_path_follows_platform_and_shapes(platform, s, d, hq, hkv, tile):
+    assert seq.attention_path(platform, s, d, hq, hkv) == tile
+
+
+@pytest.mark.parametrize("platform,calls", [("cpu", 0), ("tpu", 2)])
+def test_causal_attention_takes_the_path_it_is_told(monkeypatch, platform, calls):
+    """On the CPU no kernel; where the backend says TPU (here: said to),
+    forward and backward are one named ``pallas_call`` each."""
+    monkeypatch.setattr(seq.jax, "default_backend", lambda: platform)
+    q = jnp.ones((1, 256, 4, 128), jnp.bfloat16)
+    kv = jnp.ones((1, 256, 2, 128), jnp.bfloat16)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(seq.causal_attention(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, kv, kv))
+    assert text.count("pallas_call") == calls
+    for name in ("causal_attention_fwd", "causal_attention_bwd"):
+        assert (name in text) == bool(calls)
+
+
+def test_gauge_counts_the_blocks_that_take_the_kernel():
+    from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
+
+    model = TwoTower(twotower_config(None), dtype=jnp.bfloat16)
+    assert model.attention_kernel_blocks("cpu", 8192) == 0
+    assert model.attention_kernel_blocks("tpu", 8192) == 1
+    assert model.attention_kernel_blocks("tpu", 8192 + 72) == 0
+    assert defs.ATTENTION_KERNEL_BLOCKS.name == "dpt_attention_kernel_blocks"
