@@ -58,15 +58,14 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 # relative comparison of a kernel with its XLA reference:
-# max|got - ref| / max|ref|, held to the rtol tests/test_kernels.py,
-# tests/test_pallas.py and tests/test_wgrad_pallas.py use for that kernel
+# max|got - ref| / max|ref|, held to the rtol tests/test_kernels.py and
+# tests/test_pallas.py use for that kernel
 KERNEL_RTOL = {
     "eval_stats": 1e-5,
     "fused_loss": 2e-5,
     "fused_loss_grad": 1e-5,
     "fused_bn_act": 1e-5,
     "fused_bn_act_grad": 1e-4,
-    "wgrad_9tap": 1e-5,
     # bf16 outputs and gradients of two roundings of the same float32
     # mathematics (the kernel normalises after the values' product, the
     # XLA path before): a few bf16 ulps (2^-8) of the largest entry
@@ -96,7 +95,6 @@ class Sizes:
     n_requests: int = 5
     param_count: int | None = 7_760_097
     bn_widths: tuple = (64, 128, 256, 512, 1024)
-    wgrad_hw_ci_co: tuple = (320, 480, 128, 128)
     # the token cell's attention block: batch, length, query heads,
     # key-value heads, head size
     attention_bshgd: tuple = (2, 8192, 32, 2, 128)
@@ -350,14 +348,12 @@ def phase_kernels(out: str, seed: int, sizes: Sizes) -> dict:
 
     from distributedpytorch_tpu.ops import attention_pallas, losses
     from distributedpytorch_tpu.ops import sequence as seq
-    from distributedpytorch_tpu.ops.conv_backward import _wgrad_einsum
     from distributedpytorch_tpu.ops.fused_loss import fused_bce_dice_loss
     from distributedpytorch_tpu.ops.kernels import (
         fused_bn_act,
         sigmoid_threshold_mask,
     )
     from distributedpytorch_tpu.ops.pallas_kernels import eval_stats_pallas
-    from distributedpytorch_tpu.ops.wgrad_pallas import wgrad_9tap_pallas
     from distributedpytorch_tpu.serve.infer import postprocess_mask
 
     platform = jax.default_backend()
@@ -452,13 +448,6 @@ def phase_kernels(out: str, seed: int, sizes: Sizes) -> dict:
             [(f"d{n}_", (lambda r, i=i: r[i]), KERNEL_RTOL["fused_bn_act_grad"])
              for i, n in enumerate(("x", "mean", "var", "scale", "bias"))])
         del args
-
-    wh, ww, ci, co = sizes.wgrad_hw_ci_co
-    x = jnp.asarray(rng.standard_normal((b, wh, ww, ci)), jnp.bfloat16)
-    dy = jnp.asarray(rng.standard_normal((b, wh, ww, co)), jnp.bfloat16)
-    run("wgrad_9tap", wgrad_9tap_pallas, _wgrad_einsum, (x, dy),
-        [("", lambda r: r, KERNEL_RTOL["wgrad_9tap"])])
-    del x, dy
 
     # the fused attention kernel against the blocked XLA path it replaces
     # on a TPU: output and the three gradients, bf16 as the token model runs

@@ -12,15 +12,15 @@ analyzer:
 
 which runs dptlint (analysis/: jaxpr collective checker + SPMD source
 lint; docs/ANALYSIS.md) on a self-provisioned CPU mesh — the CI
-``lint-distributed`` gate and the bench/elastic preflights call this —
+``lint-distributed`` gate and the elastic launch preflight call this —
 the parallelism auto-planner:
 
     python -m distributedpytorch_tpu plan --out plan.json
 
 which searches strategy × schedule × memory levers with zero device
-execution and emits a ranked plan file for ``bench_multi --plan``
-(analysis/planner.py, docs/PERFORMANCE.md "Planning") — its serving
-twin:
+execution and emits a ranked plan file (``analyze --plan`` checks it
+for staleness; analysis/planner.py, docs/PERFORMANCE.md "Planning") —
+its serving twin:
 
     python -m distributedpytorch_tpu plan-serve --profile profile.json
 

@@ -25,10 +25,8 @@ Two layers, one CLI (``python -m distributedpytorch_tpu analyze``):
   host-sync hazards in the step hot path, and collectives gated on
   ``process_index()`` Python conditionals.
 
-Wired as the ``lint-distributed`` CI job ahead of tier-1, as a chip-window
-preflight in ``tools/bench_multi.py`` (a config whose step fails static
-checks is poison-marked before spending budget), and as a launch preflight
-in ``dist/elastic.py``. Rule catalog: docs/ANALYSIS.md.
+Wired as the ``lint-distributed`` CI job ahead of tier-1 and as a launch
+preflight in ``dist/elastic.py``. Rule catalog: docs/ANALYSIS.md.
 
 This module stays import-light (no jax): ``Finding`` is shared by the
 jax-tracing layer and the pure-AST layer, and jax-free callers (the
@@ -45,8 +43,8 @@ from typing import Optional
 #: needs (DDP_MP's 4×2 mesh). Single source for ``cli`` (self-provision
 #: re-exec) and ``preflight`` (pre-provisioned subprocess) — if one
 #: provisioned N and the other M, the sentinel would make ``cli.run``
-#: trust the wrong mesh and fail as an rc-2 infra error, which both
-#: preflight call sites treat as "proceed": the gate would be silently
+#: trust the wrong mesh and fail as an rc-2 infra error, which the
+#: launch preflight treats as "proceed": the gate would be silently
 #: disabled.
 MESH_DEVICES = 8
 
@@ -57,7 +55,7 @@ PROVISIONED_SENTINEL = "DPT_ANALYZE_PROVISIONED"
 #: Strategies the jaxpr collective checker covers, and the pipeline
 #: schedules that apply to the MP ones. Defined here (not in
 #: ``collectives``, which re-exports them as its defaults) so jax-free
-#: callers — the elastic supervisor, bench_multi — can gate their
+#: callers — the elastic supervisor — can gate their
 #: preflights on "is this a collective strategy the analyzer owns"
 #: without paying for a backend import.
 ANALYSIS_STRATEGIES = ("DP", "SP", "TP", "FSDP", "MP", "DDP_MP")

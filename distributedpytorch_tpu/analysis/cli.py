@@ -4,7 +4,7 @@ Runs every static pass, prints one actionable line per finding, and
 exits 0 (clean) / 1 (findings) / 2 (analyzer infrastructure failure —
 callers must NOT treat this as a finding). ``--json`` writes the
 machine-readable report (``-`` = stdout), which the CI job uploads as
-an artifact on failure and the bench_multi / elastic preflights parse;
+an artifact on failure and the elastic launch preflight parses;
 ``--sarif`` additionally projects the findings into SARIF 2.1.0 for
 CI PR-diff annotation (the JSON report stays canonical).
 
@@ -30,7 +30,7 @@ an 8-device virtual CPU mesh, and jax backends initialize once per
 process — so unless this process was already provisioned (the
 ``DPT_ANALYZE_PROVISIONED`` sentinel), the CLI exec-replaces itself via
 ``utils/provision.reexec_provisioned_cmd``: pinned to CPU, zero chip
-involvement no matter where it's invoked from (laptop, CI, a bench
+involvement no matter where it's invoked from (laptop, CI, a
 session whose parent holds the chip).
 """
 

@@ -1217,8 +1217,8 @@ def analyze_combo(method: str, schedule: Optional[str] = None,
         if is_mesh_spec(method):
             # a mesh spec that cannot BUILD (model x stage, divisibility,
             # device count) is a CONFIG refusal, not an analyzer crash:
-            # report it as a finding so the launch preflights (elastic,
-            # bench_multi) refuse the geometry pre-spawn with the reason,
+            # report it as a finding so the launch preflight (elastic)
+            # refuses the geometry pre-spawn with the reason,
             # and an `analyze --mesh` run keeps its other combos' results
             return [Finding(
                 rule="mesh-config",

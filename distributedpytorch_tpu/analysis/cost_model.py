@@ -2,8 +2,8 @@
 static artifacts — XLA ``cost_analysis()`` flops, ``memory_analysis()``
 traced liveness, and the extracted ordered collective program — into one
 comparable predicted step cost. No jax import: the planner feeds this
-module plain numbers, and jax-free consumers (``tools/bench_multi.py``
-reading a plan file) can import it for the mesh tables alone.
+module plain numbers, and jax-free consumers (anything reading a plan
+file) can import it for the mesh tables alone.
 
 The model is deliberately simple — three additive terms:
 

@@ -202,7 +202,7 @@ F32_LITERAL_DOTTED = frozenset({
 #: Modules whose f32 literals ARE the policy: the precision module
 #: itself, the loss family (f32 loss/stats is the LOSS_DTYPE contract's
 #: implementation), and the structured-conv rewrites. The Pallas kernel
-#: modules (ops/{pallas_kernels,wgrad_pallas,fused_loss,kernels}.py)
+#: modules (ops/{pallas_kernels,fused_loss,kernels}.py)
 #: are deliberately NOT here: the rule reaches kernel bodies (via the
 #: ``pallas_call``/``defvjp`` entrypoints above) and their accumulators
 #: spell the named contract constants (LOSS_DTYPE/WGRAD_DTYPE/
@@ -211,7 +211,6 @@ DTYPE_POLICY_SANCTIONED_MODULES = (
     os.path.join("ops", "precision.py"),
     os.path.join("ops", "losses.py"),
     os.path.join("ops", "quant.py"),
-    os.path.join("ops", "conv_backward.py"),
     os.path.join("ops", "s2d.py"),
 )
 

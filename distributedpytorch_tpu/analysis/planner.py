@@ -29,12 +29,10 @@ Everything runs on a self-provisioned virtual CPU mesh (same dance as
 the ``analyze`` CLI): zero device execution, zero chip involvement, safe
 to run while a window is idle or from a laptop.
 
-The output is a versioned JSON plan file. ``tools/bench_multi.py
---plan`` orders its chip-window legs by the plan's predicted rank
-(``rank_legs`` below maps a bench leg's env levers onto plan points) and
-stamps ``plan_rank``/``plan_cost_s`` into each leg row's provenance;
-A bench session generates and passes the plan so a short run
-spends its first minutes on predicted winners.
+The output is a versioned JSON plan file: every point with its verdict,
+the survivors ranked. ``analyze --plan`` re-traces its fingerprinted
+points (``check_plan_staleness``), so a plan that outlived the code it
+was built from is flagged before anyone acts on it.
 """
 
 from __future__ import annotations
@@ -56,20 +54,14 @@ from distributedpytorch_tpu.analysis import (
     PROVISIONED_SENTINEL as _SENTINEL,
 )
 from distributedpytorch_tpu.analysis import cost_model as cm
-# import-light at module level (no jax): safe on bench_multi's jax-free
-# load_plan/rank_legs path
+# import-light at module level (no jax): load_plan stays jax-free
 from distributedpytorch_tpu.analysis.collectives import PIPELINE_STRATEGIES
 # the mesh rule engine (parallel/mesh.py, jax-free): mesh-shape specs
-# (``4x1x2``) enter the search grid exactly like strategy names, and
-# the leg mapping recognizes hybrid geometries
-from distributedpytorch_tpu.parallel.mesh import (
-    spec_is_hybrid,
-    spec_is_pipeline,
-)
+# (``4x1x2``) enter the search grid exactly like strategy names
+from distributedpytorch_tpu.parallel.mesh import spec_is_pipeline
 
-#: Plan-file schema version: bench_multi refuses (degrades to its own
-#: ordering) on any other value — a stale plan must never silently
-#: reorder a window.
+#: Plan-file schema version: ``load_plan`` returns None on any other
+#: value — a version-skewed plan is never read as a current one.
 PLAN_VERSION = 1
 PLAN_KIND = "dpt_plan"
 
@@ -704,7 +696,7 @@ def MESH_MODELS_LOOKUP(name: str) -> cm.MeshModel:
         ) from None
 
 
-# -- plan-file IO (jax-free: bench_multi imports these) ----------------------
+# -- plan-file IO (jax-free) -------------------------------------------------
 def save_plan(payload: dict, path: str) -> None:
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"
@@ -714,9 +706,8 @@ def save_plan(payload: dict, path: str) -> None:
 
 
 def load_plan(path: str) -> Optional[dict]:
-    """The plan file, or None for missing/unreadable/stale — callers
-    (bench_multi ``--plan``) degrade to their own ordering on None; a
-    half-written or version-skewed plan must never reorder a window."""
+    """The plan file, or None for missing/unreadable/stale: a
+    half-written or version-skewed plan is never acted on."""
     try:
         with open(path) as f:
             payload = json.load(f)
@@ -756,10 +747,10 @@ def check_plan_staleness(payload: Mapping) -> List:
     A drifted fingerprint means the code that traces the train step —
     strategy, model, optimizer wrapping, sharding rules — changed
     since the plan was built: its rankings and comms predictions
-    describe a program that no longer exists, and acting on them
-    (bench_multi leg ordering, preflight gates) is planning from
-    fiction. Rows without a fingerprint (kernel-derived points, plans
-    predating the stamp) are skipped — no trace, nothing to compare.
+    describe a program that no longer exists, and acting on them is
+    planning from fiction. Rows without a fingerprint (kernel-derived
+    points, plans predating the stamp) are skipped — no trace, nothing
+    to compare.
     Infeasible-at-plan-time rows are still checked when they carry a
     fingerprint: their *rejection* was also computed from the trace."""
     from distributedpytorch_tpu.analysis import Finding
@@ -815,128 +806,6 @@ def check_plan_staleness(payload: Mapping) -> List:
     return findings
 
 
-# -- bench_multi leg mapping (jax-free) --------------------------------------
-#: The ONLY env levers the planner's search space models. This is an
-#: ALLOWLIST on purpose: a leg carrying any other lever (Pallas/Mosaic
-#: kernels, the serve and dtype sweeps' own grids, compile-only probes,
-#: levers added to bench_multi after this table) is unmodeled and keeps
-#: bench_multi's hand-ordered safety position — an unknown lever must
-#: fail SAFE (unranked), never fall through to the default point and
-#: move a wedge-suspect compile to the front of a chip window.
-_MODELED_LEVERS = frozenset(
-    {"BENCH_S2D_LEVELS", "BENCH_BATCH", "BENCH_ARCH",
-     "BENCH_PIPELINE_SWEEP", "BENCH_PALLAS_LOSS", "BENCH_KERNEL_SWEEP",
-     "BENCH_MESH_SWEEP"}
-)
-
-#: Selector sentinel: match any ranked HYBRID mesh-spec point (>= 2
-#: non-trivial axes). The mesh_sweep leg's predicted win is its hybrid
-#: cells, so its rank is the best hybrid geometry the plan found — a
-#: plan without ranked hybrid points leaves the leg hand-ordered.
-HYBRID_MESH = "__hybrid_mesh__"
-
-#: Point fields a selector may constrain that old plan files (written
-#: before the axis existed) don't carry: a missing field reads as its
-#: historical value, so pre-kernels plans keep ranking the same legs.
-_SELECTOR_DEFAULTS = {"kernels": "xla"}
-
-
-def _leg_selector(env: Mapping[str, str]) -> Optional[Dict[str, object]]:
-    """A bench_multi leg's env levers → the plan-point fields it must
-    match, or None for legs the planner doesn't model."""
-    if any(k not in _MODELED_LEVERS for k in env):
-        return None
-    if env.get("BENCH_ARCH", "unet") != "unet":
-        return None
-    if env.get("BENCH_PIPELINE_SWEEP") == "1":
-        # the sweep leg measures a whole M × schedule GRID; its rank is
-        # a best-case proxy (where do MP configs land at all), so only
-        # the strategy is constrained
-        return {"strategy": "MP"}
-    if env.get("BENCH_MESH_SWEEP") == "1":
-        # the mesh sweep A/Bs hybrid vs pure geometries; its rank is
-        # the best ranked hybrid mesh point (pure points already rank
-        # through their own legs)
-        return {"strategy": HYBRID_MESH}
-    selector = {
-        "strategy": "singleGPU",
-        "batch": int(env.get("BENCH_BATCH", "4")),
-        # bench.py's s2d auto resolves to 2 on the TPU backend
-        "s2d_levels": int(env.get("BENCH_S2D_LEVELS", "2")),
-        "remat": False,
-        # bench.py hardcodes bf16 compute (no BENCH_DTYPE lever): a
-        # bf16_params point's rank must not stamp a leg that runs bf16
-        "dtype": "bf16",
-        # ...and the same logic for kernels: a pallas-kernels point's
-        # rank must not stamp a leg that runs the xla paths
-        "kernels": "pallas" if env.get("BENCH_PALLAS_LOSS") == "1" else "xla",
-    }
-    if env.get("BENCH_KERNEL_SWEEP") == "1":
-        # The sweep's predicted win is its PALLAS cells, and requiring a
-        # pallas point is also the ordering safety: a plan only carries
-        # ranked pallas points when it was generated against a Mosaic
-        # priors file (--kernel-priors), i.e. the probe already ran and
-        # its file exists for the sweep's own rejected-cell skips. On a
-        # priors-less window no pallas point exists, the sweep stays
-        # unranked, and the hand order keeps it BEHIND kernel_probe —
-        # prediction never moves a Mosaic-unvetted compile earlier.
-        selector["kernels"] = "pallas"
-    return selector
-
-
-def _selector_field_matches(point: dict, field: str, want) -> bool:
-    got = point.get(field, _SELECTOR_DEFAULTS.get(field))
-    if want == HYBRID_MESH:
-        return spec_is_hybrid(got or "")
-    return got == want
-
-
-def rank_legs(payload: dict, configs) -> Dict[str, dict]:
-    """{leg name: {plan_rank, plan_cost_s, plan_point}} for every bench
-    config whose levers match a ranked feasible plan point (a leg is
-    ranked by the BEST point it could run — e.g. its fastest dtype).
-    Legs without a match are simply absent: bench_multi keeps their
-    hand-ordered position."""
-    ranked_points = [
-        p for p in payload.get("points", ())
-        if isinstance(p, dict) and p.get("feasible")
-        # bool is an int subclass; a hand-edited "rank": true must not
-        # sneak in as rank 1
-        and isinstance(p.get("rank"), int)
-        and not isinstance(p.get("rank"), bool)
-    ]
-    out: Dict[str, dict] = {}
-    for name, env, _budget in configs:
-        selector = _leg_selector(env)
-        if selector is None:
-            continue
-        if selector.get("kernels") == "pallas" and not payload.get(
-            "kernel_priors"
-        ):
-            # defense in depth for the probe-first ordering invariant:
-            # even a hand-built plan carrying ranked pallas points must
-            # not promote a Pallas-compiling leg unless the plan records
-            # that it was generated against a Mosaic priors file
-            continue
-        matches = [
-            p for p in ranked_points
-            if all(
-                _selector_field_matches(p, k, v)
-                for k, v in selector.items()
-            )
-        ]
-        if not matches:
-            continue
-        best = min(matches, key=lambda p: p["rank"])
-        predicted = best.get("predicted") or {}
-        out[name] = {
-            "plan_rank": int(best["rank"]),
-            "plan_cost_s": predicted.get("cost_s"),
-            "plan_point": best.get("key"),
-        }
-    return out
-
-
 # -- CLI ---------------------------------------------------------------------
 def build_parser() -> argparse.ArgumentParser:
     g = DEFAULT_GRID
@@ -945,8 +814,9 @@ def build_parser() -> argparse.ArgumentParser:
         description="Compiler-driven parallelism auto-planner: search "
         "strategy × schedule × memory levers with zero device execution, "
         "reject statically-broken / memory-infeasible points, rank the "
-        "rest by an analytic cost model, and emit a plan file for "
-        "bench_multi --plan. See docs/PERFORMANCE.md 'Planning'.",
+        "rest by an analytic cost model, and emit a plan file "
+        "(analyze --plan checks it for staleness). See "
+        "docs/PERFORMANCE.md 'Planning'.",
     )
     ap.add_argument("--out", default="plan.json",
                     help="Plan file to write (versioned JSON)")
@@ -1036,9 +906,8 @@ def run(argv: Optional[Sequence[str]] = None) -> int:
     elif priors is not None:
         # a LOADED priors file is the opt-in: search kernel-on vs
         # kernel-off. A --kernel-priors path whose file is missing/stale
-        # must NOT widen the axis — an unprobed pallas point would rank,
-        # and bench_multi --plan would promote the kernel legs ahead of
-        # the probe leg that vets them.
+        # must NOT widen the axis — an unprobed pallas point would rank
+        # beside points the chip's compiler has vetted.
         kernels = ("xla", "pallas")
     else:
         kernels = DEFAULT_GRID["kernels"]

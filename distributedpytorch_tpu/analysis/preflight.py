@@ -1,20 +1,18 @@
-"""The shared preflight runner: one definition of "invoke the analyzer
-in a provisioned CPU subprocess and parse its report".
+"""The preflight runner: "invoke the analyzer in a provisioned CPU
+subprocess and parse its report".
 
-Both preflight call sites — tools/bench_multi.py (chip-window configs)
-and dist/elastic.py (rank launches) — need exactly this: run ``python -m
+The launch preflight of dist/elastic.py (rank launches) runs ``python -m
 distributedpytorch_tpu analyze`` pinned to a virtual CPU mesh (never
 dialing a TPU runtime), scoped to the collective layer for the given
-strategy × schedule, and turn the JSON report into printable findings
-lines. Keeping two hand-rolled copies had already drifted on ``--layer``
-scoping by review time; this module is the single seam, and it stays
-jax-free so the elastic supervisor can import it.
+strategy × schedule, and turns the JSON report into printable findings
+lines. The module stays jax-free so the elastic supervisor can import
+it.
 
 Return contract: ``(rc, findings_lines)`` where rc is the analyzer's
 exit code (0 clean / 1 findings / 2 infra) — a crashed or timed-out
-subprocess reports rc 2. POLICY IS THE CALLER'S: both preflights treat
-rc 2 as "proceed" (analyzer plumbing must never block a measurement or
-a launch), but that decision lives at the call sites.
+subprocess reports rc 2. POLICY IS THE CALLER'S: the launch preflight
+treats rc 2 as "proceed" (analyzer plumbing must never block a launch),
+but that decision lives at the call site.
 """
 
 from __future__ import annotations
@@ -71,7 +69,7 @@ def run_preflight(
             # rc 1 WITHOUT any JSON report is not findings — it's a
             # crashed interpreter (import error, unhandled traceback;
             # Python itself exits 1 for both): an INFRA failure, which
-            # must never refuse a launch or poison a config
+            # must never refuse a launch
             detail = (proc.stderr or proc.stdout).strip()[-300:]
             return 2, [f"analyzer exited 1 without a report: {detail}"]
         try:

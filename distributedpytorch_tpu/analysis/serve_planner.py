@@ -72,8 +72,8 @@ def point_key(scenario_label: str, bucket_sizes: Sequence[int],
               slo_ms: float, replicas: int, eager: bool,
               queue_cap: Optional[int]) -> str:
     """The stable grid-point key — also what bench_serve stamps into a
-    leg row's ``plan_point`` provenance (bench_multi's plan_rank
-    pattern), so a leg names the exact point it validates."""
+    leg row's ``plan_point`` provenance, so a leg names the exact point
+    it validates."""
     ladder = "x".join(str(int(b)) for b in bucket_sizes)
     return (
         f"{scenario_label}/b{ladder}/slo{slo_ms:g}/r{int(replicas)}/"

@@ -91,7 +91,8 @@ def get_args():
                         metavar="PATH",
                         help="Append per-phase step-timeline spans "
                              "(decode/stack/h2d/dispatch/readback) to this "
-                             "JSONL file; summarize with bench.py, export "
+                             "JSONL file; summarize with "
+                             "utils/trace.summarize_timeline, export "
                              "to Perfetto via obs/trace_hub.py (rank R of "
                              "a multi-process run writes PATH.rankR)")
     parser.add_argument("--metrics-port", type=int, default=None,
@@ -144,10 +145,6 @@ def get_args():
                              "space-to-depth domain (exact numerics, ~1.9x "
                              "faster on TPU); 0 disables, -1 = auto "
                              "(2 on TPU, 0 elsewhere)")
-    parser.add_argument("--wgrad-taps", action="store_true",
-                        help="Weight gradients of the s2d 3x3 convs as 9 "
-                             "tap matmuls instead of XLA's conv backward "
-                             "(identical numerics; perf A/B lever)")
     parser.add_argument("--model", "--model-arch", dest="model_arch",
                         type=str, default="unet",
                         choices=["unet", "milesial", "twotower"],
@@ -313,7 +310,6 @@ def main():
         seq_len=args.seq_len,
         dtype=args.dtype,
         s2d_levels=args.s2d_levels,
-        wgrad_taps=args.wgrad_taps,
         checkpoint_name=resolve_checkpoint_arg(args),
         synthetic_samples=args.synthetic,
         profile_dir=args.profile_dir,

@@ -86,8 +86,8 @@ class TrainConfig:
     #             becomes a free throughput lever (the M=8/16 rows that
     #             OOM or remat under gpipe at batch 4). Grad-equivalent
     #             to gpipe (tests/test_pipeline_1f1b.py).
-    # Default gpipe until the on-chip A/B lands (tools/bench_pipeline.py
-    # --schedule sweep / bench_multi pipeline config).
+    # Default gpipe: no on-chip A/B of the two schedules is on record
+    # (PERF.md §7 row 4).
     pipeline_schedule: str = "gpipe"
 
     # -- precision (ops/precision.py, docs/PERFORMANCE.md "Precision") ------
@@ -137,11 +137,6 @@ class TrainConfig:
     # auto stays at 2 (level 3 regressed at the reference geometry,
     # docs/PERFORMANCE.md).
     s2d_levels: int = -1
-    # Compute the s2d 3×3 convs' weight gradients as 9 tap matmuls
-    # (ops/conv_backward.py) instead of XLA's conv-backward-filter —
-    # identical numerics (tests/test_s2d.py), different schedule. The
-    # round-3 step was backward-dominated; this is the A/B lever.
-    wgrad_taps: bool = False
 
     @property
     def model_levels(self) -> int:
@@ -276,7 +271,8 @@ class TrainConfig:
     profile_dir: Optional[str] = None  # jax.profiler trace capture when set
     # Step-timeline tracer (utils/trace.py): per-phase host spans
     # (decode/stack/h2d/dispatch/readback) appended to this JSONL path;
-    # summarized by bench.py, exported to Perfetto by obs/trace_hub.py.
+    # summarized by utils/trace.summarize_timeline, exported to Perfetto
+    # by obs/trace_hub.py.
     # Multi-process runs: rank 0 writes the path, rank R appends .rankR.
     # None = JSONL off (spans still feed the flight recorder's ring).
     timeline_path: Optional[str] = None

@@ -52,9 +52,8 @@ class SampleCache:
     The epoch loop re-reads the SAME samples every epoch, yet the seed
     pipeline re-ran PIL/libjpeg decode + resize for each of them, every
     epoch — on a 1-core host that decode bound the whole run
-    (docs/PERFORMANCE.md input-pipeline table; VERDICT r05 item 7 asks
-    for one-time host staging). This cache sits under DataLoader's batch
-    assembly: the first epoch decodes and stores items until the byte
+    (docs/PERFORMANCE.md input-pipeline table). This cache sits under
+    DataLoader's batch assembly: the first epoch decodes and stores items until the byte
     budget is full, later epochs serve hits straight from host memory.
 
     Deliberately no eviction: the access pattern is a uniform re-scan of

@@ -1053,8 +1053,7 @@ class ElasticSupervisor:
         must never refuse a launch because the analyzer itself broke.
 
         Strategies the analyzer doesn't cover (``singleGPU``, the
-        multi-process-only ``DDP``) skip the check entirely — same
-        rationale as bench_multi's ``_preflight_combos``: nothing to
+        multi-process-only ``DDP``) skip the check entirely: nothing to
         verify statically, so don't pay a provisioned analyzer
         subprocess on every launch of a non-collective job."""
         from distributedpytorch_tpu.analysis import ANALYSIS_STRATEGIES
@@ -1062,9 +1061,8 @@ class ElasticSupervisor:
 
         if self.workload == "serve":
             # serving is collective-free by construction (independent
-            # single-device replica executables — the same reason
-            # bench_multi's serve config is in the no-combos class):
-            # nothing to verify statically, nothing to pay for
+            # single-device replica executables): nothing to verify
+            # statically, nothing to pay for
             return []
         from distributedpytorch_tpu.parallel.mesh import is_mesh_spec
 

@@ -89,7 +89,6 @@ def _build_milesial(config, compute_dtype):
         widths=widths,
         dtype=compute_dtype,
         s2d_levels=getattr(config, "s2d_levels", -1),
-        wgrad_taps=getattr(config, "wgrad_taps", False),
         conv_epilogue=conv_epilogue_engaged(config),
     )
 

@@ -36,11 +36,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributedpytorch_tpu.models.unet import (
-    _S2DConv,
-    _TapsPixelConv,
-    center_crop,
-)
+from distributedpytorch_tpu.models.unet import _S2DConv, center_crop
 from distributedpytorch_tpu.ops import s2d as s2d_ops
 
 MILESIAL_WIDTHS = (64, 128, 256, 512, 1024)
@@ -104,20 +100,18 @@ class DoubleConvS2D(nn.Module):
     in_features: int
     in_segments: Optional[Tuple[int, ...]] = None
     dtype: Any = jnp.bfloat16
-    wgrad_taps: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         x = _S2DConv(
             self.features, self.in_features, "conv3x3", dtype=self.dtype,
-            in_segments=self.in_segments, wgrad_taps=self.wgrad_taps,
-            use_bias=False, name="conv1",
+            in_segments=self.in_segments, use_bias=False, name="conv1",
         )(x)
         x = _S2DBatchNorm(self.features, name="bn1")(x, train)
         x = nn.relu(x).astype(self.dtype)
         x = _S2DConv(
             self.features, self.features, "conv3x3", dtype=self.dtype,
-            wgrad_taps=self.wgrad_taps, use_bias=False, name="conv2",
+            use_bias=False, name="conv2",
         )(x)
         x = _S2DBatchNorm(self.features, name="bn2")(x, train)
         return nn.relu(x).astype(self.dtype)
@@ -134,7 +128,6 @@ class _DownS2D(nn.Module):
     prev_s2d: bool  # input arrives in s2d form
     this_s2d: bool  # this level's DoubleConv runs in the s2d domain
     dtype: Any = jnp.bfloat16
-    wgrad_taps: bool = False
     epilogue: bool = False  # pixel-domain DoubleConv only (the boundary)
 
     @nn.compact
@@ -148,11 +141,11 @@ class _DownS2D(nn.Module):
             x = s2d_ops.space_to_depth(x)
             return DoubleConvS2D(
                 self.features, in_features=self.in_features,
-                dtype=self.dtype, wgrad_taps=self.wgrad_taps, name="conv",
+                dtype=self.dtype, name="conv",
             )(x, train)
         return DoubleConv(
-            self.features, dtype=self.dtype, wgrad_taps=self.wgrad_taps,
-            epilogue=self.epilogue, name="conv",
+            self.features, dtype=self.dtype, epilogue=self.epilogue,
+            name="conv",
         )(x, train)
 
 
@@ -167,7 +160,6 @@ class _UpS2D(nn.Module):
     skip_features: int
     prev_s2d: bool  # x arrives in s2d form (previous Up ran s2d)
     dtype: Any = jnp.bfloat16
-    wgrad_taps: bool = False
 
     @nn.compact
     def __call__(
@@ -189,7 +181,6 @@ class _UpS2D(nn.Module):
             in_features=self.skip_features + up_feats,
             in_segments=(self.skip_features, up_feats),
             dtype=self.dtype,
-            wgrad_taps=self.wgrad_taps,
             name="conv",
         )(x, train)
 
@@ -258,23 +249,16 @@ class DoubleConv(nn.Module):
     features: int
     mid_features: int = 0  # 0 = features (bilinear Up passes in//2)
     dtype: Any = jnp.bfloat16
-    wgrad_taps: bool = False
     epilogue: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         mid = self.mid_features or self.features
         for i, feats in enumerate((mid, self.features)):
-            if self.wgrad_taps:
-                x = _TapsPixelConv(
-                    feats, dtype=self.dtype, use_bias=False,
-                    name=f"conv{i + 1}",
-                )(x)
-            else:
-                x = nn.Conv(
-                    feats, (3, 3), padding=1, use_bias=False, dtype=self.dtype,
-                    name=f"conv{i + 1}",
-                )(x)
+            x = nn.Conv(
+                feats, (3, 3), padding=1, use_bias=False, dtype=self.dtype,
+                name=f"conv{i + 1}",
+            )(x)
             if self.epilogue:
                 x = _FusedEpilogueBatchNorm(
                     feats, name=f"bn{i + 1}"
@@ -295,15 +279,14 @@ class Down(nn.Module):
 
     features: int
     dtype: Any = jnp.bfloat16
-    wgrad_taps: bool = False
     epilogue: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         x = nn.max_pool(x, window_shape=(2, 2), strides=(2, 2))
         return DoubleConv(
-            self.features, dtype=self.dtype, wgrad_taps=self.wgrad_taps,
-            epilogue=self.epilogue, name="conv",
+            self.features, dtype=self.dtype, epilogue=self.epilogue,
+            name="conv",
         )(x, train)
 
 
@@ -318,7 +301,6 @@ class Up(nn.Module):
     features: int
     bilinear: bool = False
     dtype: Any = jnp.bfloat16
-    wgrad_taps: bool = False
     epilogue: bool = False
 
     @nn.compact
@@ -341,7 +323,7 @@ class Up(nn.Module):
         x = jnp.concatenate([skip, x], axis=-1)
         return DoubleConv(
             self.features, mid_features=mid, dtype=self.dtype,
-            wgrad_taps=self.wgrad_taps, epilogue=self.epilogue, name="conv",
+            epilogue=self.epilogue, name="conv",
         )(x, train)
 
 
@@ -364,7 +346,6 @@ class MilesialUNet(nn.Module):
     widths: Sequence[int] = MILESIAL_WIDTHS
     dtype: Any = jnp.bfloat16
     s2d_levels: int = -1
-    wgrad_taps: bool = False
     # Fuse every pixel-domain DoubleConv's BN-normalize + ReLU into one
     # VMEM pass (ops/kernels.fused_bn_act, --kernels pallas). Identical
     # param/batch_stats trees; s2d-domain levels keep _S2DBatchNorm.
@@ -474,11 +455,11 @@ class MilesialUNet(nn.Module):
                     xs = s2d_ops.space_to_depth(x)
                     x = DoubleConvS2D(
                         w[0], in_features=x.shape[-1], dtype=self.dtype,
-                        wgrad_taps=self.wgrad_taps, name="inc",
+                        name="inc",
                     )(xs, train)
                 else:
                     x = DoubleConv(
-                        w[0], dtype=self.dtype, wgrad_taps=self.wgrad_taps,
+                        w[0], dtype=self.dtype,
                         epilogue=self.conv_epilogue, name="inc",
                     )(x, train)
                 skips = skips + (x,)
@@ -492,12 +473,12 @@ class MilesialUNet(nn.Module):
                     x = _DownS2D(
                         feats, in_features=w[level - 1],
                         prev_s2d=level - 1 < lv, this_s2d=level < lv,
-                        dtype=self.dtype, wgrad_taps=self.wgrad_taps,
-                        epilogue=self.conv_epilogue, name=f"down{level}",
+                        dtype=self.dtype, epilogue=self.conv_epilogue,
+                        name=f"down{level}",
                     )(x, train)
                 else:
                     x = Down(
-                        feats, dtype=self.dtype, wgrad_taps=self.wgrad_taps,
+                        feats, dtype=self.dtype,
                         epilogue=self.conv_epilogue, name=f"down{level}",
                     )(x, train)
                 if level < L:  # the deepest Down is the bottleneck, no skip
@@ -515,7 +496,6 @@ class MilesialUNet(nn.Module):
                         skip_features=feats,
                         prev_s2d=i - 1 >= L - lv,
                         dtype=self.dtype,
-                        wgrad_taps=self.wgrad_taps,
                         name=f"up{i + 1}",
                     )(x, skip, train)
                 else:
@@ -523,7 +503,6 @@ class MilesialUNet(nn.Module):
                         out_feats,
                         bilinear=self.bilinear,
                         dtype=self.dtype,
-                        wgrad_taps=self.wgrad_taps,
                         epilogue=self.conv_epilogue,
                         name=f"up{i + 1}",
                     )(x, skip, train)

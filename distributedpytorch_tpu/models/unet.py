@@ -82,9 +82,6 @@ class _S2DConv(nn.Module):
     mode: str = "conv3x3"
     dtype: Any = jnp.bfloat16
     in_segments: Optional[Tuple[int, ...]] = None
-    # Route the 3x3 weight gradient through the 9-tap-matmul backward
-    # (ops/conv_backward.py) instead of XLA's conv-backward-filter.
-    wgrad_taps: bool = False
     # False for BatchNorm-following convs (milesial DoubleConv) — the
     # param tree then matches nn.Conv(use_bias=False) exactly.
     use_bias: bool = True
@@ -106,50 +103,13 @@ class _S2DConv(nn.Module):
             dense = s2d_ops.upconv_kernel(w)
         else:
             dense = s2d_ops.head1x1_kernel(w, self.in_segments)
-        if self.wgrad_taps and self.mode == "conv3x3":
-            from distributedpytorch_tpu.ops.conv_backward import (
-                conv3x3_same_taps,
-            )
-
-            y = conv3x3_same_taps(x, dense)
-        else:
-            y = s2d_ops.conv_same(x, dense)
+        y = s2d_ops.conv_same(x, dense)
         if not self.use_bias:
             return y
         b = self.param(
             "bias", nn.initializers.zeros_init(), (self.features,), jnp.float32
         )
         return y + s2d_ops.tile_bias(b).astype(y.dtype)
-
-
-class _TapsPixelConv(nn.Module):
-    """Param-compatible stand-in for ``nn.Conv(features, (3,3), padding=1)``
-    whose weight gradient runs through the 9-tap-matmul backward
-    (ops/conv_backward.py). For a 3×3 stride-1 conv, flax's ``padding=1``
-    IS 'SAME', so forward numerics are identical; only the backward
-    schedule differs."""
-
-    features: int
-    dtype: Any = jnp.bfloat16
-    use_bias: bool = True  # False matches nn.Conv(use_bias=False) (BN convs)
-
-    @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        from distributedpytorch_tpu.ops.conv_backward import conv3x3_same_taps
-
-        w = self.param(
-            "kernel",
-            nn.initializers.lecun_normal(),
-            (3, 3, x.shape[-1], self.features),
-            jnp.float32,
-        )
-        y = conv3x3_same_taps(x.astype(self.dtype), w.astype(self.dtype))
-        if not self.use_bias:
-            return y
-        b = self.param(
-            "bias", nn.initializers.zeros_init(), (self.features,), jnp.float32
-        )
-        return y + b.astype(y.dtype)
 
 
 class ConvBlock(nn.Module):
@@ -167,7 +127,6 @@ class ConvBlock(nn.Module):
     s2d: bool = False
     in_features: Optional[int] = None
     in_segments: Optional[Tuple[int, ...]] = None
-    wgrad_taps: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -179,22 +138,17 @@ class ConvBlock(nn.Module):
                 "conv3x3",
                 dtype=self.dtype,
                 in_segments=self.in_segments,
-                wgrad_taps=self.wgrad_taps,
                 name="conv1",
             )(x)
             x = nn.relu(x)
             x = _S2DConv(
                 self.features, self.features, "conv3x3", dtype=self.dtype,
-                wgrad_taps=self.wgrad_taps, name="conv2"
+                name="conv2",
             )(x)
             x = nn.relu(x)
             return x
-        conv = (
-            functools.partial(_TapsPixelConv, dtype=self.dtype)
-            if self.wgrad_taps
-            else functools.partial(
-                nn.Conv, kernel_size=(3, 3), padding=1, dtype=self.dtype
-            )
+        conv = functools.partial(
+            nn.Conv, kernel_size=(3, 3), padding=1, dtype=self.dtype
         )
         x = conv(self.features, name="conv1")(x)
         x = nn.relu(x)
@@ -226,7 +180,6 @@ class Encoder(nn.Module):
     dtype: Any = jnp.bfloat16
     s2d_levels: int = 0
     in_features: int = 3  # input channels (RGB images)
-    wgrad_taps: bool = False
 
     def setup(self):
         blocks = []
@@ -238,13 +191,11 @@ class Encoder(nn.Module):
                     dtype=self.dtype,
                     s2d=True,
                     in_features=in_feats,
-                    wgrad_taps=self.wgrad_taps,
                     name=f"block{i + 1}",
                 ))
             else:
                 blocks.append(ConvBlock(
-                    w, dtype=self.dtype, wgrad_taps=self.wgrad_taps,
-                    name=f"block{i + 1}",
+                    w, dtype=self.dtype, name=f"block{i + 1}",
                 ))
             in_feats = w
         self.blocks = blocks
@@ -274,7 +225,6 @@ class Decoder(nn.Module):
     dtype: Any = jnp.bfloat16
     s2d_levels: int = 0
     in_features: Optional[int] = None  # bottleneck channels (default 2·widths[0])
-    wgrad_taps: bool = False
 
     def setup(self):
         # The shallowest s2d_levels iterations (i ≥ n − s2d_levels) run in
@@ -297,7 +247,6 @@ class Decoder(nn.Module):
                     s2d=True,
                     in_features=2 * w,
                     in_segments=(w, w),
-                    wgrad_taps=self.wgrad_taps,
                     name=f"block{i + 1}",
                 ))
             else:
@@ -306,8 +255,7 @@ class Decoder(nn.Module):
                     name=f"upconv{i + 1}",
                 ))
                 blocks.append(ConvBlock(
-                    w, dtype=self.dtype, wgrad_taps=self.wgrad_taps,
-                    name=f"block{i + 1}",
+                    w, dtype=self.dtype, name=f"block{i + 1}",
                 ))
         self.ups = ups
         self.blocks = blocks
@@ -362,9 +310,6 @@ class UNet(nn.Module):
     # builds its level-1 kernels from it; the data pipeline always emits
     # RGB, so non-3 is for library users feeding other imagery.
     in_channels: int = 3
-    # 9-tap-matmul weight gradients for the s2d 3x3 convs
-    # (ops/conv_backward.py); measured A/B on TPU before defaulting.
-    wgrad_taps: bool = False
     # How many shallow levels execute in the space-to-depth domain
     # (ops/s2d.py) — exactly equivalent, measured ~2× faster on TPU for the
     # full-resolution C=32/64 levels. 0 disables; -1 = auto (2 on a TPU
@@ -385,17 +330,13 @@ class UNet(nn.Module):
             dtype=self.dtype,
             s2d_levels=lv,
             in_features=self.in_channels,
-            wgrad_taps=self.wgrad_taps,
         )
-        self.mid = ConvBlock(
-            mid, dtype=self.dtype, wgrad_taps=self.wgrad_taps
-        )
+        self.mid = ConvBlock(mid, dtype=self.dtype)
         self.decoder = Decoder(
             widths=tuple(reversed(self.widths)),
             dtype=self.dtype,
             s2d_levels=lv,
             in_features=mid,
-            wgrad_taps=self.wgrad_taps,
         )
         if lv > 0:
             self.segmap = _S2DConv(
@@ -507,9 +448,7 @@ def create_unet(config=None, dtype=None) -> UNet:
     if config is not None and getattr(config, "model_widths", None):
         widths = tuple(config.model_widths)
     s2d_levels = getattr(config, "s2d_levels", -1) if config is not None else -1
-    wgrad_taps = getattr(config, "wgrad_taps", False) if config is not None else False
-    return UNet(dtype=dtype, widths=widths, s2d_levels=s2d_levels,
-                wgrad_taps=wgrad_taps)
+    return UNet(dtype=dtype, widths=widths, s2d_levels=s2d_levels)
 
 
 def init_unet_params(model: UNet, rng: jax.Array, input_hw=(640, 960)):

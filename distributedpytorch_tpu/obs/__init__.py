@@ -24,8 +24,7 @@ now a first-class subsystem every run carries by default:
 * :mod:`~distributedpytorch_tpu.obs.flight` — the always-on bounded
   ring buffer of recent events, dumped to a JSON post-mortem artifact
   on watchdog timeout, dispatch-loop death, non-finite-loss abort,
-  SIGTERM, and unhandled exit, and referenced from bench_multi
-  poison/provenance lines.
+  SIGTERM, and unhandled exit.
 
 Hot-path contract (enforced by dptlint's ``obs-hot-path`` rule,
 docs/ANALYSIS.md): nothing in a record path blocks on a device value or

@@ -13,7 +13,6 @@ that already know the run is dying:
 * the non-finite-loss ``abort`` policy,
 * the SIGTERM/SIGINT checkpoint-and-stop handler,
 * the serve dispatch loop's death path (``serve/server.py``),
-* ``bench_multi``'s poison/dead-probe marks (``tools/bench_multi.py``),
 * an optional unhandled-exception hook (:func:`install_excepthook`).
 
 What flows in (always-on, no flags): step-timeline spans
@@ -30,7 +29,7 @@ GIL, so the record path takes **no lock**.
 
 ``DPT_OBS=0`` disables recording (the overhead A/B lever used for the
 numbers in docs/OBSERVABILITY.md). Dump-path precedence:
-:func:`set_dump_path` (explicit caller, e.g. bench_multi per leg) >
+:func:`set_dump_path` (explicit caller) >
 ``$DPT_FLIGHT_PATH`` > ``$DPT_FLIGHT_DIR``/flight_rank<R>.json >
 the default installed by the owning subsystem (trainer: under its log
 dir) > ``./logs/flight_rank<R>.json``.

@@ -2,9 +2,8 @@
 
 Round 3 shipped the fused BCE+dice stats kernel (ops/pallas_kernels.py)
 eval-only: differentiating a ``pallas_call`` needs a hand-written VJP, and
-the training path stayed XLA (VERDICT r03 weak-3: "Pallas is barely
-load-bearing"). This module supplies that VJP at the right altitude — the
-SUFFICIENT-STATISTICS level (ops/losses.py `bce_dice_stats`):
+the training path stayed XLA. This module supplies that VJP at the right
+altitude — the SUFFICIENT-STATISTICS level (ops/losses.py `bce_dice_stats`):
 
     stats = [bce_sum, count, intersection, output_sum + target_sum]
 

@@ -4,8 +4,7 @@ boundary.
 
 Before this module the kernel tier was scattered conventions: an
 eval-only stats kernel (ops/pallas_kernels.py) behind ``use_pallas``, a
-differentiable fused loss (ops/fused_loss.py) enabled per-strategy, a
-wgrad kernel behind a trace-time env var (ops/conv_backward.py), and
+differentiable fused loss (ops/fused_loss.py) enabled per-strategy, and
 nothing the planner could see. A convention cannot be selected, probed,
 or searched; a policy object can.
 
@@ -41,16 +40,11 @@ Engagement sites (the full table lives in docs/PERFORMANCE.md
   (:func:`sigmoid_threshold_mask`): probabilities → ``{0,255} uint8``
   masks INSIDE the serve tier's AOT bucket executables
   (serve/infer.make_forward), so the D2H transfer carries 1 byte/pixel
-  instead of 4 and the host threshold pass disappears;
-* ``wgrad_pallas``     — the existing single-pass 9-tap weight-gradient
-  kernel (ops/wgrad_pallas.py): surfaces the decision here; the
-  trace-time selection stays ``DPT_WGRAD_BACKEND`` (the bench lever)
-  because the taps path itself is still an A/B, not a default.
+  instead of 4 and the host threshold pass disappears.
 
 **Mosaic probe priors.** Every kernel has a compile-only probe
-(``PROBES`` — the ``wgrad_pallas_probe`` pattern generalized): lower +
-compile at a representative shape, record accepted-or-rejected with the
-Mosaic reason, ZERO execution. ``tools/probe_kernels.py`` runs the
+(``PROBES``): lower + compile at a representative shape, record
+accepted-or-rejected with the Mosaic reason, ZERO execution. ``tools/probe_kernels.py`` runs the
 registry on the chip and writes a per-chip priors file (exit code
 non-zero on any refusal). The file is an EXPLICIT input
 (``--kernel-priors`` / ``$DPT_KERNEL_PRIORS``): only then does
@@ -115,18 +109,17 @@ class KernelPolicy:
     eval_stats_fused: bool   # ops/pallas_kernels.py on the eval path
     conv_epilogue: bool      # fused_bn_act in milesial DoubleConv
     serve_mask: bool         # sigmoid_threshold_mask in the AOT serve fwd
-    wgrad_pallas: bool       # ops/wgrad_pallas.py allowed on the taps path
 
     def any_engaged(self) -> bool:
         return any(
             (self.train_loss_fused, self.eval_stats_fused,
-             self.conv_epilogue, self.serve_mask, self.wgrad_pallas)
+             self.conv_epilogue, self.serve_mask)
         )
 
 
 KERNEL_POLICIES: Dict[str, KernelPolicy] = {
-    "xla": KernelPolicy("xla", False, False, False, False, False),
-    "pallas": KernelPolicy("pallas", True, True, True, True, True),
+    "xla": KernelPolicy("xla", False, False, False, False),
+    "pallas": KernelPolicy("pallas", True, True, True, True),
 }
 
 #: Probe-registry kernel name → the policy field(s) it gates: a priors
@@ -137,7 +130,6 @@ KERNEL_GATES: Dict[str, Tuple[str, ...]] = {
     "eval_stats": ("eval_stats_fused",),
     "conv_epilogue": ("conv_epilogue",),
     "serve_mask": ("serve_mask",),
-    "wgrad_9tap": ("wgrad_pallas",),
 }
 
 
@@ -163,7 +155,7 @@ def get_kernel_policy(
     elif isinstance(config_or_name, str):
         policy = _by_name(config_or_name)
         if priors is None:
-            # name-based resolution (the serve engine, bench cells)
+            # name-based resolution (the serve engine)
             # still honors the session's probe verdicts
             policy = apply_priors(policy, _env_priors() or {})
     else:
@@ -276,8 +268,6 @@ def train_step_kernels(config) -> Tuple[str, ...]:
     names = ["fused_loss"]
     if getattr(config, "model_arch", "unet") == "milesial":
         names.append("conv_epilogue")
-    if getattr(config, "wgrad_taps", False):
-        names.append("wgrad_9tap")
     return tuple(names)
 
 
@@ -367,7 +357,7 @@ def _sequential_grid_params(interpret):
     if interpret:
         return {}
     # sequential grid: the accumulator output block is carried across
-    # steps (the wgrad_pallas.py pattern)
+    # steps
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("arbitrary",)
     )}
@@ -578,18 +568,9 @@ def _probe_serve_mask():
     ).lower(x).compile()
 
 
-def _probe_wgrad_9tap():
-    from distributedpytorch_tpu.ops.wgrad_pallas import wgrad_9tap_pallas
-
-    x = jnp.zeros((1, 8, 30, 128), jnp.bfloat16)
-    dy = jnp.zeros((1, 8, 30, 128), jnp.bfloat16)
-    jax.jit(wgrad_9tap_pallas).lower(x, dy).compile()
-
-
 #: The probe registry: kernel name → a compile-only callable (AOT
-#: ``lower().compile()``, ZERO execution — the wgrad_pallas_probe
-#: pattern per kernel). On a TPU utils/backend.pallas_interpret resolves
-#: to real Mosaic lowering, so an exception IS the chip's accept/reject
+#: ``lower().compile()``, ZERO execution). On a TPU
+#: utils/backend.pallas_interpret resolves to real Mosaic lowering, so an exception IS the chip's accept/reject
 #: verdict; on an operator-named CPU the interpreter path compiles,
 #: proving the machinery.
 PROBES: Dict[str, Callable[[], None]] = {
@@ -597,7 +578,6 @@ PROBES: Dict[str, Callable[[], None]] = {
     "fused_loss": _probe_fused_loss,
     "conv_epilogue": _probe_conv_epilogue,
     "serve_mask": _probe_serve_mask,
-    "wgrad_9tap": _probe_wgrad_9tap,
 }
 
 

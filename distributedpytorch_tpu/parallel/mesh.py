@@ -274,15 +274,6 @@ def spec_is_pipeline(name) -> bool:
     return m is not None and int(m.group(3)) > 1
 
 
-def spec_is_hybrid(name) -> bool:
-    """>= 2 non-trivial axes — what the bench sweep and the planner's
-    leg mapping mean by a 'hybrid' geometry."""
-    m = _SPEC_RE.match(str(name)) if isinstance(name, str) else None
-    if m is None:
-        return False
-    return sum(int(m.group(i)) > 1 for i in (1, 2, 3)) >= 2
-
-
 # -- legacy strategies as named points ---------------------------------------
 #: Structural pattern of each legacy ``-t`` strategy (axis sizes are
 #: placeholders — 2 means "spans devices", resolved concretely at

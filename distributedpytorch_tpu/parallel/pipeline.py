@@ -9,8 +9,8 @@ written explicitly: `shard_map` over a ``stage`` mesh axis, a static loop
 over schedule ticks, `lax.cond` selecting each device's stage work, and
 `jax.lax.ppermute` carrying inter-stage payloads over ICI.
 
-Generalized from the round-3 two-stage schedule to S stages (VERDICT r03
-next-3): the model exposes its linear block order as 2L+1 segments
+Generalized from the round-3 two-stage schedule to S stages: the model
+exposes its linear block order as 2L+1 segments
 (models/unet.py `UNet.apply_segment`, models/milesial.py the same), a stage
 is any contiguous run of segments, and ``cuts`` picks the boundaries. The
 default for S=2 is the faithful reference cut (encoder+mid | decoder+head,

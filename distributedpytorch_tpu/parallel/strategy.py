@@ -96,8 +96,7 @@ def _state_donation(config: Optional[TrainConfig] = None) -> tuple:
 def _shrunk_data_degree(name: str, batch_size: int, n_devices: int) -> int:
     """Largest data degree <= n_devices dividing the batch, warning
     loudly when devices are left idle (torch DataParallel would scatter
-    unevenly instead; GSPMD needs the batch to divide the mesh —
-    VERDICT r03 missing-3)."""
+    unevenly instead; GSPMD needs the batch to divide the mesh)."""
     n = n_devices
     while batch_size % n:
         n -= 1

@@ -130,8 +130,7 @@ class Trainer:
             timeline_path = f"{timeline_path}.rank{rank}"
         self.tracer = StepTimeline(timeline_path, rank=rank)
         # flight recorder (obs/flight.py): always-on ring; the dump path
-        # defaults under this run's log dir unless the caller/env chose
-        # one (bench_multi points it at the leg's artifact)
+        # defaults under this run's log dir unless the caller/env chose one
         flight.set_rank(rank)
         flight.set_default_dump_path(os.path.join(
             config.log_dir, f"flight_{config.method_tag}_rank{rank}.json"

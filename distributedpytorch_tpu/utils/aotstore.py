@@ -3,7 +3,7 @@
 Every serve worker pays (buckets x replicas) XLA compiles at startup —
 minutes of redundant work on TPU for programs that are byte-identical
 across incarnations of the same engine (fleet cold start, elastic
-relaunch, repeated bench legs). This store persists each compiled
+relaunch, repeated bench_serve legs). This store persists each compiled
 bucket executable once (``jax.experimental.serialize_executable``) and
 loads it on every later cold start, turning startup from compile-bound
 into load-bound.

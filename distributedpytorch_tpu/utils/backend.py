@@ -7,7 +7,7 @@ its own: **the CPU is used only where the operator named it**
 Three things follow from that one rule and live here:
 
 * :func:`require_accelerator` — every entry point (``train.py``,
-  ``serve``, ``bench.py``) calls it before it builds anything: a TPU is
+  ``serve``, the tools) calls it before it builds anything: a TPU is
   fine, an operator-named CPU is fine, anything else exits non-zero.
 * :func:`pallas_interpret` — the Pallas tier's one interpret/Mosaic
   decision: Mosaic on a TPU, the interpreter only on an operator-named

@@ -3,7 +3,7 @@
 jax backends initialize once per process — so a process that wants an
 n-device virtual CPU mesh must have the right env BEFORE its interpreter
 starts. Every self-provisioning entry point
-(`__graft_entry__.dryrun_multichip`, `tools/bench_pipeline.py`, the
+(`__graft_entry__.dryrun_multichip`, `tools/convergence_run.py`, the
 elastic supervisor's CPU drills) needs the same two moves: name the CPU
 (JAX_PLATFORMS=cpu — the one way this program runs there,
 utils/backend.py) and rewrite --xla_force_host_platform_device_count in
@@ -42,8 +42,8 @@ def maybe_reexec_provisioned(n_devices: int, sentinel: str) -> Optional[int]:
     ``sentinel`` is already set this process IS the provisioned child —
     return None and let the caller proceed. Otherwise re-run
     ``sys.argv`` under ``provisioned_env(n_devices)`` and return the child's exit code for the caller to
-    propagate. Used by tools/bench_pipeline.py and
-    tools/convergence_run.py; __graft_entry__ keeps its own variant (it
+    propagate. Used by tools/convergence_run.py; __graft_entry__ keeps
+    its own variant (it
     re-execs a ``-c`` command, not a script file)."""
     if os.environ.get(sentinel) == "1":
         return None

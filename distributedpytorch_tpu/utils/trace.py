@@ -27,8 +27,8 @@ numbers. This tracer records ``(phase, t0, t1)`` wall spans (a shared
 ``time.perf_counter`` clock across every thread: loader pool, placement
 worker, main loop), appends them as JSONL, and summarizes per-phase
 totals so a throughput regression is attributable to the phase that
-grew. `bench.py` emits the summary alongside imgs/sec; the overlap test
-(tests/test_async_pipeline.py) asserts on the raw spans.
+grew. The benchmark's feed readers (benchmark/feed_spans.py) and the
+overlap test (tests/test_async_pipeline.py) read the raw spans.
 
 Every span of one batch carries the same ``(epoch, seq)`` tags: ``seq``
 counts the feed's work items from 0 in each epoch (a K-stack is one
@@ -79,7 +79,7 @@ class StepTimeline:
     """Collects per-phase spans; thread-safe; JSONL-append on flush().
 
     ``path=None`` disables collection entirely unless ``enabled=True`` is
-    forced (in-memory mode — what bench.py uses for its inline summary).
+    forced (in-memory mode — what the benchmark's step loop reads).
     Even disabled, completed spans feed the flight recorder's ring
     (bounded, allocation = the ring slot) unless ``DPT_OBS=0``.
     """
@@ -227,8 +227,8 @@ class ReadyWatcher:
 
 def _format_totals(totals: Dict[str, List[float]]) -> Dict[str, Optional[dict]]:
     """phase → [count, total_s] accumulators → the summary shape shared by
-    StepTimeline.summary and summarize_events (one formatter: bench.py
-    emits both side by side, and they must never drift apart)."""
+    StepTimeline.summary and summarize_events (one formatter, so the
+    two never drift apart)."""
     out: Dict[str, Optional[dict]] = {}
     for phase in (*PHASES, *(p for p in totals if p not in PHASES)):
         if phase not in totals:
@@ -266,7 +266,7 @@ def load_events(path: str) -> List[dict]:
 
 def summarize_events(events: Iterable[dict]) -> Dict[str, Optional[dict]]:
     """Same per-phase shape as :meth:`StepTimeline.summary`, from raw
-    events (e.g. a trainer-written JSONL read back by bench.py)."""
+    events (e.g. a trainer-written JSONL read back)."""
     totals: Dict[str, List[float]] = {}
     for e in events:
         try:

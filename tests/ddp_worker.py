@@ -159,7 +159,7 @@ def main():
     trainer = Trainer(config)
     result = trainer.train()
 
-    # Eval equivalence (VERDICT r03 next-4): the sharded evaluator — each
+    # Eval equivalence: the sharded evaluator — each
     # process computing only its round-robin share through one grouped
     # sharded dispatch — must reproduce the replicated path's value, and
     # both must be identical on every rank (the plateau scheduler's

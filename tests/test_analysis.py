@@ -966,7 +966,7 @@ class TestDtypePolicyRule:
             "        return x.astype(jnp.float32).sum()\n"
             "    return stats\n"
         )
-        for mod in ("ops/pallas_kernels.py", "ops/wgrad_pallas.py",
+        for mod in ("ops/pallas_kernels.py", "ops/attention_pallas.py",
                     "ops/fused_loss.py", "ops/kernels.py"):
             findings = lint.lint_source(src, mod)
             assert [f.rule for f in findings] == ["dtype-policy"], mod
@@ -1030,7 +1030,7 @@ class TestDtypePolicyRule:
         import pathlib
 
         root = pathlib.Path(lint.__file__).resolve().parents[1]
-        for mod in ("ops/pallas_kernels.py", "ops/wgrad_pallas.py",
+        for mod in ("ops/pallas_kernels.py", "ops/attention_pallas.py",
                     "ops/fused_loss.py", "ops/kernels.py"):
             path = root / mod
             findings = lint.lint_source(path.read_text(), mod)

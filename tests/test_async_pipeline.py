@@ -232,7 +232,7 @@ def test_depth_zero_is_inline(tmp_path):
 
 def test_trainer_writes_timeline_jsonl(tmp_path):
     """--trace-timeline end to end: the JSONL lands, carries every pipeline
-    phase, and summarize_timeline (what bench.py emits) reads it back."""
+    phase, and summarize_timeline reads it back."""
     path = tmp_path / "timeline.jsonl"
     cfg = _config(tmp_path, timeline_path=str(path), prefetch_batches=2)
     Trainer(cfg).train()

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from distributedpytorch_tpu.ops import kernels, pallas_kernels, wgrad_pallas
+from distributedpytorch_tpu.ops import kernels, pallas_kernels
 
 B, H, W = 4, 640, 960  # the reference config: batch 4 at 640×960
 HBM_BYTES = 16 * 2**30  # one v5e chip
@@ -64,7 +64,7 @@ def mosaic(one_chip, no_persistent_cache, monkeypatch):
     from the test, not through an option of the program — and hand back
     ``sds(shape, dtype)`` placing an abstract array on the described
     chip."""
-    for mod in (kernels, pallas_kernels, wgrad_pallas):
+    for mod in (kernels, pallas_kernels):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
 
     def sds(shape, dtype):
@@ -116,22 +116,6 @@ def test_fused_bn_act_grad_compiles(mosaic, c):
 
     compiled = _compile(
         jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *_bn_args(mosaic, c)
-    )
-    assert _has_kernel(compiled)
-
-
-@pytest.mark.parametrize("hw_ci_co", [
-    (320, 480, 128, 128),  # enc1 conv2 / dec4 block — the hot s2d shape
-    (160, 240, 128, 256),  # enc2 conv1
-    (160, 240, 256, 256),  # enc2 conv2 / dec3 block
-])
-def test_wgrad_9tap_compiles(mosaic, hw_ci_co):
-    h, w, ci, co = hw_ci_co
-    x = mosaic((B, h, w, ci), jnp.bfloat16)
-    dy = mosaic((B, h, w, co), jnp.bfloat16)
-    compiled = _compile(
-        lambda a, b: wgrad_pallas.wgrad_9tap_pallas(a, b, interpret=False),
-        x, dy,
     )
     assert _has_kernel(compiled)
 

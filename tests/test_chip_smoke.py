@@ -14,7 +14,7 @@ import chip_smoke
 TOY = chip_smoke.Sizes(
     widths=(16, 32), image_wh=(96, 64), source_wh=(190, 128), n_images=40,
     batch=4, buckets=(1, 2), n_requests=3, param_count=None,
-    bn_widths=(8, 128), wgrad_hw_ci_co=(8, 12, 8, 16),
+    bn_widths=(8, 128),
     attention_bshgd=(1, 256, 4, 2, 128),
     multichip_batch=8, multichip_steps=2,
 )
@@ -60,7 +60,7 @@ def test_one_chip_phases_at_toy_widths(tmp_path, accept_named_cpu, capfd):
     assert rows["serve_2"]["aot_cache"]["hit"] == len(TOY.buckets)
     assert rows["serve_check"]["worst_mismatch_fraction"] == 0.0
     kernels = rows["kernels"]["kernels"]
-    assert {"eval_stats", "fused_loss", "serve_mask", "wgrad_9tap",
+    assert {"eval_stats", "fused_loss", "serve_mask",
             "fused_bn_act_c8", "fused_bn_act_grad_c128",
             "causal_attention"} <= set(kernels)
     # on the named CPU the kernels are interpreted, and the row says so

@@ -431,8 +431,7 @@ class TestStaticPreflight:
         self, tmp_path, monkeypatch
     ):
         # singleGPU runs no collectives — the analyzer has nothing to
-        # verify, so the launch must not pay a provisioned subprocess
-        # (mirrors bench_multi._preflight_combos returning no combos).
+        # verify, so the launch must not pay a provisioned subprocess.
         import distributedpytorch_tpu.analysis.preflight as preflight_mod
 
         def no_subprocess(*a, **k):

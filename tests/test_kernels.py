@@ -62,7 +62,6 @@ class TestKernelPolicy:
         assert policy.name == "pallas"
         assert policy.train_loss_fused and policy.eval_stats_fused
         assert policy.conv_epilogue and policy.serve_mask
-        assert policy.wgrad_pallas
 
     def test_unknown_policy_rejected(self):
         with pytest.raises(ValueError, match="unknown kernel policy"):
@@ -79,7 +78,6 @@ class TestKernelPolicy:
             policy = get_kernel_policy(TrainConfig(use_pallas=True))
         assert policy.train_loss_fused and policy.eval_stats_fused
         assert not policy.conv_epilogue and not policy.serve_mask
-        assert not policy.wgrad_pallas
         assert any("legacy alias" in r.message for r in caplog.records)
 
     def test_explicit_kernels_supersedes_the_alias(self):
@@ -144,8 +142,6 @@ class TestKernelPolicy:
         assert km.train_step_kernels(
             TrainConfig(model_arch="milesial")
         ) == ("fused_loss", "conv_epilogue")
-        assert "wgrad_9tap" in km.train_step_kernels(
-            TrainConfig(wgrad_taps=True))
 
 
 def _bn_case(shape=(2, 6, 9, 16), seed=0):
@@ -554,86 +550,6 @@ class TestPlannerKernelsAxis:
         assert k_on["feasible"]
         assert k_on["predicted"]["kernel_priors"] == "unprobed"
 
-    def test_rank_legs_maps_kernel_sweep_and_pallas_loss(self):
-        from distributedpytorch_tpu.analysis import planner
-
-        plan = {
-            "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-            # the probe verdicts the plan was generated against — what
-            # licenses ranking the Pallas-compiling legs at all
-            "kernel_priors": {"platform": "tpu", "rejected": []},
-            "points": [
-                {"strategy": "singleGPU", "batch": 4, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "kernels": "xla",
-                 "feasible": True, "rank": 1,
-                 "key": "singleGPU/s2d2/remat-off/b4/bf16",
-                 "predicted": {"cost_s": 0.02}},
-                {"strategy": "singleGPU", "batch": 4, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "kernels": "pallas",
-                 "feasible": True, "rank": 0,
-                 "key": "singleGPU/s2d2/remat-off/b4/bf16/k-pallas",
-                 "predicted": {"cost_s": 0.01}},
-            ],
-        }
-        configs = [
-            ("pallas_loss", {"BENCH_PALLAS_LOSS": "1"}, 60.0),
-            ("kernel_sweep", {"BENCH_KERNEL_SWEEP": "1"}, 60.0),
-            ("kernel_probe", {"BENCH_KERNEL_PROBE": "1"}, 60.0),
-        ]
-        ranks = planner.rank_legs(plan, configs)
-        # pallas_loss runs the fused kernels → the kernels=pallas point
-        assert ranks["pallas_loss"]["plan_rank"] == 0
-        # the sweep is ranked by its pallas point (present only when
-        # the plan searched the kernels axis against a priors file)
-        assert ranks["kernel_sweep"]["plan_rank"] == 0
-        # the compile-only probe is not a measurement leg: unmodeled
-        assert "kernel_probe" not in ranks
-
-    def test_kernel_sweep_unranked_without_pallas_points(self):
-        """A plan with no ranked pallas points (no priors file at plan
-        time) must leave kernel_sweep at its hand-ordered slot BEHIND
-        kernel_probe — prediction never moves a Mosaic-unvetted compile
-        ahead of the probe that vets it."""
-        from distributedpytorch_tpu.analysis import planner
-
-        plan = {
-            "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-            "points": [
-                {"strategy": "singleGPU", "batch": 4, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "kernels": "xla",
-                 "feasible": True, "rank": 0,
-                 "key": "singleGPU/s2d2/remat-off/b4/bf16",
-                 "predicted": {"cost_s": 0.02}},
-            ],
-        }
-        configs = [("kernel_sweep", {"BENCH_KERNEL_SWEEP": "1"}, 60.0)]
-        assert planner.rank_legs(plan, configs) == {}
-
-    def test_pallas_legs_unranked_when_plan_lacks_priors_provenance(self):
-        """Even a plan CARRYING ranked pallas points must not promote a
-        Pallas-compiling leg unless it records the priors file it was
-        generated against (kernel_priors non-null) — a hand-edited or
-        priors-less `--kernels xla pallas` plan cannot move a
-        Mosaic-unvetted compile ahead of the probe."""
-        from distributedpytorch_tpu.analysis import planner
-
-        plan = {
-            "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-            "kernel_priors": None,
-            "points": [
-                {"strategy": "singleGPU", "batch": 4, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "kernels": "pallas",
-                 "feasible": True, "rank": 0,
-                 "key": "singleGPU/s2d2/remat-off/b4/bf16/k-pallas",
-                 "predicted": {"cost_s": 0.01}},
-            ],
-        }
-        configs = [
-            ("pallas_loss", {"BENCH_PALLAS_LOSS": "1"}, 60.0),
-            ("kernel_sweep", {"BENCH_KERNEL_SWEEP": "1"}, 60.0),
-        ]
-        assert planner.rank_legs(plan, configs) == {}
-
     def test_missing_priors_file_never_widens_the_kernels_axis(
         self, tmp_path
     ):
@@ -656,91 +572,3 @@ class TestPlannerKernelsAxis:
         assert payload["grid"]["kernels"] == ["xla"]
         assert payload["kernel_priors"] is None
         assert all(p["kernels"] == "xla" for p in payload["points"])
-
-    def test_pre_kernels_plan_rows_still_rank_xla_legs(self):
-        """Plan files written before the kernels axis carry no kernels
-        field: they must keep ranking the xla train legs (missing field
-        reads as the historical value), and must never rank pallas
-        legs."""
-        from distributedpytorch_tpu.analysis import planner
-
-        plan = {
-            "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-            "points": [
-                {"strategy": "singleGPU", "batch": 8, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "feasible": True,
-                 "rank": 0, "key": "singleGPU/s2d2/remat-off/b8/bf16",
-                 "predicted": {"cost_s": 0.01}},
-            ],
-        }
-        configs = [
-            ("b8", {"BENCH_BATCH": "8"}, 60.0),
-            ("pallas_loss", {"BENCH_PALLAS_LOSS": "1"}, 60.0),
-        ]
-        ranks = planner.rank_legs(plan, configs)
-        assert ranks["b8"]["plan_rank"] == 0
-        assert "pallas_loss" not in ranks
-
-
-class TestKernelSweepBench:
-    """The kernel_sweep bench config (tools/bench_kernels.py)."""
-
-    def test_registered_with_probe_ahead(self):
-        import sys
-
-        sys.path.insert(0, ".")
-        from tools import bench_multi
-
-        names = [n for n, _, _ in bench_multi.CONFIGS]
-        assert "kernel_probe" in names and "kernel_sweep" in names
-        assert names.index("kernel_probe") < names.index("kernel_sweep")
-        by_name = {n: (env, b) for n, env, b in bench_multi.CONFIGS}
-        assert by_name["kernel_probe"][0] == {"BENCH_KERNEL_PROBE": "1"}
-        assert by_name["kernel_sweep"][0] == {"BENCH_KERNEL_SWEEP": "1"}
-        # single-device, collective-free: nothing for the static
-        # preflight to check (the serve_bench/dtype_sweep fast path)
-        assert bench_multi._preflight_combos(
-            {"BENCH_KERNEL_SWEEP": "1"}) == ()
-        assert bench_multi._preflight_combos(
-            {"BENCH_KERNEL_PROBE": "1"}) == ()
-
-    def test_sweep_emits_phase_cells_and_speedups(self):
-        import sys
-
-        sys.path.insert(0, ".")
-        from tools.bench_kernels import kernel_sweep
-
-        rows = []
-        summary = kernel_sweep(batch=1, hw=(16, 32), widths=(4, 8),
-                               steps=1, emit=rows.append)
-        phases = {(r["phase"], r["kernels"]) for r in rows
-                  if r.get("kind") == "kernel_cell"}
-        for phase in ("train_loss", "epilogue", "eval_stats", "serve_mask"):
-            assert (phase, "xla") in phases and (phase, "pallas") in phases
-        assert any(k.endswith("_speedup") for k in summary)
-
-    def test_sweep_skips_mosaic_rejected_cells(self):
-        import sys
-
-        sys.path.insert(0, ".")
-        from tools.bench_kernels import kernel_sweep
-
-        priors = _priors(
-            conv_epilogue=(False, "refused"),
-            serve_mask=(False, "refused"),
-        )
-        summary = kernel_sweep(batch=1, hw=(16, 32), widths=(4, 8),
-                               steps=1, priors=priors)
-        skipped = {r["phase"] for r in summary["rows"]
-                   if r.get("skipped") == "mosaic_rejected"}
-        assert skipped == {"epilogue", "serve_mask"}
-
-    def test_budget_exhausted_marks_cells_skipped(self):
-        import sys
-
-        sys.path.insert(0, ".")
-        from tools.bench_kernels import kernel_sweep
-
-        summary = kernel_sweep(batch=1, hw=(16, 32), widths=(4, 8),
-                               steps=1, budget_s=1e-9)
-        assert all(r.get("skipped") == "budget" for r in summary["rows"])

@@ -437,38 +437,6 @@ print("GRADS-MATCH")
             out.stdout + out.stderr
         )
 
-    @pytest.mark.parametrize("s2d", [0, 2])
-    def test_wgrad_taps_grads_match(self, s2d):
-        """--wgrad-taps must cover milesial's pixel AND s2d levels: same
-        gradients as the default backward in both execution domains."""
-        from distributedpytorch_tpu.ops.losses import bce_dice_loss
-
-        rng = np.random.default_rng(5)
-        x = jnp.asarray(rng.random((2, *self.HW, 3), dtype=np.float32))
-        t = jnp.asarray((rng.random((2, *self.HW, 1)) > 0.5).astype(np.float32))
-        params = stats = None
-        grads = {}
-        for taps in (False, True):
-            m = MilesialUNet(widths=self.WIDTHS, dtype=jnp.float32,
-                             s2d_levels=s2d, wgrad_taps=taps)
-            if params is None:
-                params, stats = init_milesial(m, jax.random.key(0),
-                                              input_hw=self.HW)
-
-            def f(p):
-                preds, _ = m.apply(
-                    {"params": p, "batch_stats": stats}, x, train=True,
-                    mutable=["batch_stats"],
-                )
-                return bce_dice_loss(preds, t)
-
-            grads[taps] = jax.jit(jax.grad(f))(params)
-        for a, b in zip(jax.tree.leaves(grads[False]),
-                        jax.tree.leaves(grads[True])):
-            np.testing.assert_allclose(
-                np.asarray(b), np.asarray(a), rtol=1e-5, atol=1e-6
-            )
-
     def test_auto_mode_degrades_gracefully(self):
         """-1 (auto) must never reject a config the pixel path handled:
         bilinear and ragged sizes silently fall back to pixel."""

@@ -3,7 +3,7 @@ devices each, rendezvous over localhost with torchrun-style env — the real
 `jax.distributed` path the single-process mesh tests cannot cover
 (SURVEY.md §4: 'multi-process tests via jax.distributed over localhost').
 
-Two topology families (VERDICT r04 next-6):
+Two topology families:
   * 2 procs × 2 devices — the round-3/4 configuration;
   * 4 procs × 1 device — process-count (4) differs from BOTH mesh axis
     sizes in the DDP_MP hybrid ({data:2, stage:2}), and the sharded
@@ -150,7 +150,7 @@ def _assert_world(tmp_path, reports, method, mesh_data):
 def test_two_process(tmp_path, method, mesh_data):
     """2 procs × 2 devices. DDP: 4-device global data mesh. DDP_MP:
     {data:2, stage:2} — crosses jax.distributed with the explicit pipeline
-    schedule (VERDICT r03 next-8). DDP_SP: {data:2, spatial:2} — the
+    schedule. DDP_SP: {data:2, spatial:2} — the
     H-sliced batch placement over jax.distributed."""
     reports, _ = _launch_world(tmp_path, world=2, local_devices=2, method=method)
     _assert_world(tmp_path, reports, method, mesh_data)
@@ -261,7 +261,7 @@ def test_ckpt_write_fault_fails_writer_without_hanging_survivor(tmp_path):
     "method,mesh_data", [("DDP", 4), ("DDP_MP", 2), ("DDP_SP", 2)]
 )
 def test_four_process(tmp_path, method, mesh_data):
-    """4 procs × 1 device (VERDICT r04 next-6). For the hybrids the
+    """4 procs × 1 device. For the hybrids the
     process count (4) equals NEITHER mesh axis ({data:2, stage:2} /
     {data:2, spatial:2}), so co-row processes must feed identical data
     into replicated/H-sliced shards (the row-based data_shard contract)

@@ -1,6 +1,6 @@
 """The auto-planner (analysis/planner.py + analysis/cost_model.py):
 tiny-geometry end-to-end plans on the CPU mesh, the plan-file schema
-round-trip, the cost_analysis-absent guard, the bench-leg mapping, and
+round-trip, the cost_analysis-absent guard, and
 the ISSUE-10 acceptance pins — 1F1B ranked above GPipe at M=8 at the
 activation wall, s2d-3 / remat-off feasibility, and the three seeded
 statically-broken mutants rejected with ZERO device execution (the
@@ -483,92 +483,3 @@ class TestStalePlan:
 
         rc = cli.run(["--layer", "lint", "--plan", "whatever.json"])
         assert rc == cli.EXIT_INFRA
-
-
-# ---------------------------------------------------------------------------
-class TestRankLegs:
-    """The bench_multi leg mapping (jax-free): env levers → plan point,
-    unmodeled legs absent."""
-
-    PLAN = {
-        "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-        "points": [
-            {"strategy": "singleGPU", "batch": 8, "s2d_levels": 2,
-             "remat": False, "dtype": "bf16", "feasible": True, "rank": 0,
-             "key": "singleGPU/s2d2/remat-off/b8/bf16",
-             "predicted": {"cost_s": 0.01}},
-            {"strategy": "singleGPU", "batch": 4, "s2d_levels": 0,
-             "remat": False, "dtype": "bf16", "feasible": True, "rank": 3,
-             "key": "singleGPU/s2d0/remat-off/b4/bf16",
-             "predicted": {"cost_s": 0.04}},
-            {"strategy": "MP", "schedule": "1f1b", "microbatches": 8,
-             "batch": 8, "s2d_levels": 0, "remat": False,
-             "feasible": True, "rank": 1,
-             "key": "MP/1f1b/m8/s2d0/remat-off/b8/bf16",
-             "predicted": {"cost_s": 0.02}},
-            {"strategy": "MP", "schedule": "gpipe", "microbatches": 8,
-             "batch": 8, "s2d_levels": 0, "remat": False,
-             "feasible": False, "rank": None, "reject": "memory: ...",
-             "key": "MP/gpipe/m8/s2d0/remat-off/b8/bf16",
-             "predicted": {"cost_s": 0.05}},
-        ],
-    }
-
-    CONFIGS = [
-        ("pixel", {"BENCH_S2D_LEVELS": "0"}, 60.0),
-        ("b8", {"BENCH_BATCH": "8"}, 60.0),
-        ("pipeline_sched_sweep", {"BENCH_PIPELINE_SWEEP": "1"}, 300.0),
-        ("serve_bench", {"BENCH_SERVE": "1"}, 600.0),
-        ("wgrad_taps", {"BENCH_WGRAD_TAPS": "1"}, 2700.0),
-        ("milesial_s2d", {"BENCH_ARCH": "milesial"}, 1500.0),
-    ]
-
-    def test_mapping(self):
-        ranks = planner.rank_legs(self.PLAN, self.CONFIGS)
-        # pixel: singleGPU, s2d 0, default batch 4 → rank 3
-        assert ranks["pixel"]["plan_rank"] == 3
-        # b8: singleGPU, batch 8, default s2d 2 → rank 0
-        assert ranks["b8"]["plan_rank"] == 0
-        assert ranks["b8"]["plan_cost_s"] == 0.01
-        # the pipeline sweep is ranked by its best FEASIBLE MP point —
-        # the infeasible gpipe row never represents the leg
-        assert ranks["pipeline_sched_sweep"]["plan_rank"] == 1
-        assert (ranks["pipeline_sched_sweep"]["plan_point"]
-                == "MP/1f1b/m8/s2d0/remat-off/b8/bf16")
-        # unmodeled legs: absent, keep their hand-ordered safety slot
-        for name in ("serve_bench", "wgrad_taps", "milesial_s2d"):
-            assert name not in ranks
-
-    def test_legs_without_matching_point_are_absent(self):
-        plan = {"kind": "dpt_plan", "version": planner.PLAN_VERSION,
-                "points": []}
-        assert planner.rank_legs(plan, self.CONFIGS) == {}
-
-    def test_dtype_the_bench_cannot_run_never_ranks_a_leg(self):
-        # bench.py executes bf16 (no dtype lever): a bf16_params-only
-        # plan must leave the train legs unranked rather than stamp them
-        # with a prediction for a config that never runs
-        plan = {
-            "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-            "points": [
-                {"strategy": "singleGPU", "batch": 8, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16_params",
-                 "feasible": True, "rank": 0,
-                 "predicted": {"cost_s": 0.01}},
-            ],
-        }
-        assert planner.rank_legs(plan, self.CONFIGS) == {}
-
-    def test_garbage_rank_points_are_excluded(self):
-        plan = {
-            "kind": "dpt_plan", "version": planner.PLAN_VERSION,
-            "points": [
-                {"strategy": "singleGPU", "batch": 8, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "feasible": True,
-                 "rank": {"oops": 1}, "predicted": {"cost_s": 0.01}},
-                {"strategy": "singleGPU", "batch": 8, "s2d_levels": 2,
-                 "remat": False, "dtype": "bf16", "feasible": True,
-                 "rank": True, "predicted": {"cost_s": 0.01}},
-            ],
-        }
-        assert planner.rank_legs(plan, self.CONFIGS) == {}

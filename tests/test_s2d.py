@@ -557,174 +557,3 @@ class TestPropertyEquivalence:
             )
 
         check()
-
-
-class TestWgradTaps:
-    """The 9-tap-matmul conv backward (ops/conv_backward.py) must be a
-    drop-in for XLA's conv autodiff: same forward, same dx, same dW."""
-
-    @pytest.fixture(autouse=True)
-    def _taps_everywhere(self, monkeypatch):
-        # Pin the spatial gate open: these tiny test planes would fall
-        # below an ambient DPT_WGRAD_TAPS_MIN_HW (e.g. exported while
-        # iterating on the scoped bench config), silently degenerating
-        # every assertion into plain-conv-vs-itself.
-        monkeypatch.setenv("DPT_WGRAD_TAPS_MIN_HW", "0")
-
-    def test_grads_match_xla(self):
-        from distributedpytorch_tpu.ops.conv_backward import conv3x3_same_taps
-        from distributedpytorch_tpu.ops.s2d import conv_same
-
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(rng.standard_normal((2, 12, 16, 8), dtype=np.float32))
-        k = jnp.asarray(rng.standard_normal((3, 3, 8, 16), dtype=np.float32))
-        dy = jnp.asarray(rng.standard_normal((2, 12, 16, 16), dtype=np.float32))
-
-        def loss_ref(x, k):
-            return jnp.sum(conv_same(x, k) * dy)
-
-        def loss_taps(x, k):
-            return jnp.sum(conv3x3_same_taps(x, k) * dy)
-
-        np.testing.assert_allclose(
-            np.asarray(conv3x3_same_taps(x, k)), np.asarray(conv_same(x, k)),
-            rtol=1e-6,
-        )
-        ref_dx, ref_dk = jax.jit(jax.grad(loss_ref, argnums=(0, 1)))(x, k)
-        got_dx, got_dk = jax.jit(jax.grad(loss_taps, argnums=(0, 1)))(x, k)
-        np.testing.assert_allclose(
-            np.asarray(got_dx), np.asarray(ref_dx), rtol=1e-5, atol=1e-5
-        )
-        np.testing.assert_allclose(
-            np.asarray(got_dk), np.asarray(ref_dk), rtol=1e-5, atol=1e-4
-        )
-
-    @pytest.mark.parametrize("s2d", [0, 2])
-    def test_model_grads_match(self, s2d):
-        """Full UNet, both execution domains: wgrad_taps=True must land on
-        the same gradients as the default path (s2d levels through the
-        kernel assembly, pixel levels through _TapsPixelConv)."""
-        from distributedpytorch_tpu.ops.losses import bce_dice_loss
-
-        rng = np.random.default_rng(1)
-        img = jnp.asarray(rng.random((2, 32, 48, 3), dtype=np.float32))
-        tgt = jnp.asarray((rng.random((2, 32, 48, 1)) > 0.5).astype(np.float32))
-        params = None
-        grads = {}
-        for taps in (False, True):
-            m = UNet(dtype=jnp.float32, widths=(8, 16), s2d_levels=s2d,
-                     wgrad_taps=taps)
-            if params is None:
-                params = m.init(jax.random.key(0), img[:1])["params"]
-
-            def loss(p):
-                return bce_dice_loss(m.apply({"params": p}, img), tgt)
-
-            grads[taps] = jax.jit(jax.grad(loss))(params)
-        flat_a = jax.tree.leaves(grads[False])
-        flat_b = jax.tree.leaves(grads[True])
-        for a, b in zip(flat_a, flat_b):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
-            )
-
-    def test_wgrad_taps_any_shape(self):
-        """Property sweep for the 9-tap-matmul backward: for ANY shape, dx
-        and dW equal jax.grad of the plain conv."""
-        pytest.importorskip("hypothesis")  # optional test extra
-        from hypothesis import given, strategies as st
-
-        from hypothesis import HealthCheck, settings
-
-        from distributedpytorch_tpu.ops.conv_backward import conv3x3_same_taps
-        from distributedpytorch_tpu.ops.s2d import conv_same
-
-        @settings(max_examples=6, deadline=None,
-                  suppress_health_check=[HealthCheck.too_slow])
-        @given(
-            b=st.integers(1, 2),
-            h=st.integers(3, 10),
-            w=st.integers(3, 10),
-            cin=st.integers(1, 7),
-            cout=st.integers(1, 7),
-            seed=st.integers(0, 2**31 - 1),
-        )
-        def check(b, h, w, cin, cout, seed):
-            rng = np.random.default_rng(seed)
-            x = jnp.asarray(rng.standard_normal((b, h, w, cin)), jnp.float32)
-            k = jnp.asarray(rng.standard_normal((3, 3, cin, cout)), jnp.float32)
-            dy = jnp.asarray(rng.standard_normal((b, h, w, cout)), jnp.float32)
-
-            ref = jax.grad(
-                lambda x, k: jnp.sum(conv_same(x, k) * dy), argnums=(0, 1)
-            )(x, k)
-            got = jax.grad(
-                lambda x, k: jnp.sum(conv3x3_same_taps(x, k) * dy),
-                argnums=(0, 1),
-            )(x, k)
-            for g, r in zip(got, ref):
-                np.testing.assert_allclose(
-                    np.asarray(g), np.asarray(r), atol=1e-3, rtol=1e-4
-                )
-
-        check()
-
-
-class TestWgradTapsSpatialGate:
-    """DPT_WGRAD_TAPS_MIN_HW scopes the taps rewrite to convs whose
-    H·W plane is at least the threshold — the sub-gate convs must run
-    the PLAIN conv path (identical numerics either way; what changes is
-    which backward XLA compiles, and the graph size)."""
-
-    def test_gate_routes_by_plane_size(self, monkeypatch):
-        from distributedpytorch_tpu.ops import conv_backward as cb
-
-        calls = []
-        real = cb._conv3x3_same_taps_vjp
-        monkeypatch.setattr(
-            cb, "_conv3x3_same_taps_vjp",
-            lambda x, k: calls.append(x.shape) or real(x, k))
-        rng = np.random.default_rng(0)
-        big = jnp.asarray(rng.random((1, 24, 24, 4), dtype=np.float32))
-        small = jnp.asarray(rng.random((1, 8, 8, 4), dtype=np.float32))
-        k = jnp.asarray(rng.random((3, 3, 4, 4), dtype=np.float32))
-
-        monkeypatch.setenv("DPT_WGRAD_TAPS_MIN_HW", "200")
-        cb.conv3x3_same_taps(big, k)    # 576 px >= 200 -> taps
-        cb.conv3x3_same_taps(small, k)  # 64 px < 200 -> plain conv
-        assert calls == [(1, 24, 24, 4)]
-
-        # unset = everywhere; garbage must fail LOUD (a silent fallback
-        # to 0 would select the full-taps graph under a scoped label)
-        monkeypatch.delenv("DPT_WGRAD_TAPS_MIN_HW")
-        cb.conv3x3_same_taps(small, k)
-        assert len(calls) == 2
-        monkeypatch.setenv("DPT_WGRAD_TAPS_MIN_HW", "not-a-number")
-        with pytest.raises(ValueError, match="DPT_WGRAD_TAPS_MIN_HW"):
-            cb.conv3x3_same_taps(small, k)
-
-    def test_gated_numerics_identical(self, monkeypatch):
-        """Grads through the gated function equal the plain conv's grads
-        regardless of which side of the gate a conv falls on."""
-        from distributedpytorch_tpu.ops.conv_backward import (
-            conv3x3_same_taps,
-        )
-        from distributedpytorch_tpu.ops.s2d import conv_same
-
-        rng = np.random.default_rng(3)
-        x = jnp.asarray(rng.standard_normal((2, 10, 14, 8), dtype=np.float32))
-        k = jnp.asarray(rng.standard_normal((3, 3, 8, 8), dtype=np.float32))
-        dy = jnp.asarray(rng.standard_normal((2, 10, 14, 8), dtype=np.float32))
-        ref = jax.grad(lambda x, k: jnp.sum(conv_same(x, k) * dy),
-                       argnums=(0, 1))(x, k)
-        for thresh in ("0", "1000000"):  # taps side / plain side
-            monkeypatch.setenv("DPT_WGRAD_TAPS_MIN_HW", thresh)
-            got = jax.grad(
-                lambda x, k: jnp.sum(conv3x3_same_taps(x, k) * dy),
-                argnums=(0, 1))(x, k)
-            np.testing.assert_allclose(np.asarray(got[0]),
-                                       np.asarray(ref[0]),
-                                       rtol=1e-5, atol=1e-5)
-            np.testing.assert_allclose(np.asarray(got[1]),
-                                       np.asarray(ref[1]),
-                                       rtol=1e-5, atol=1e-4)

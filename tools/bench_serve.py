@@ -20,9 +20,7 @@ Two complementary load shapes against the SAME in-process
 
 No checkpoint needed: ``--fresh-init`` (the default when no checkpoint
 is given) serves a seeded randomly-initialized model — garbage masks,
-identical machinery — so the bench runs on any CPU, chip-free. Wired as
-the ``serve_bench`` bench_multi config (non-collective: the static
-preflight has nothing to check and skips it).
+identical machinery — so the bench runs on any CPU, chip-free.
 
 Every leg row additionally records its per-phase attribution medians
 (queue_wait/placement/device/drain — obs/reqtrace.py) and the path of
@@ -37,9 +35,8 @@ plan-serve loop on themselves: each records its own arrival trace
 format), then replays that trace against its own profile in the
 discrete-event simulator (serve/sim.py) and stamps a ``validation``
 block comparing predicted p99 / shed-rate against the measured row,
-plus the ``plan_point`` grid key the leg validates (bench_multi's
-plan-provenance pattern). Tier-1 asserts the tolerance on the
-CPU-pinned legs — the simulator must reproduce the bench from traces
+plus the ``plan_point`` grid key the leg validates. Tier-1 asserts the
+tolerance on the CPU-pinned legs — the simulator must reproduce the bench from traces
 alone, or capacity plans built on it are fiction.
 
 Usage:
@@ -390,8 +387,7 @@ def _artifact_path(args, name: str) -> str:
 
 
 def _flight_path(args, leg: str) -> str:
-    """Per-leg flight-recorder artifact path (bench_multi's session rows
-    reference these for post-mortems)."""
+    """Per-leg flight-recorder artifact path (for post-mortems)."""
     return _artifact_path(args, f"flight_{leg}")
 
 
@@ -865,8 +861,7 @@ def run_bench(budget_s: float = 600.0, args: Optional[argparse.Namespace] = None
     canaried weight swap), a router leg (two HTTP workers behind the
     front-door router, mid-traffic failures, zero client-visible
     errors), and a hedge leg (wedged worker, hedged vs unhedged p99,
-    exactly-once ledger). Returns the report dict
-    (bench_multi appends it to the session artifact verbatim)."""
+    exactly-once ledger). Returns the report dict."""
     args = args or get_args([])
     levels = [int(c) for c in (levels or args.levels)]
     t_start = time.monotonic()

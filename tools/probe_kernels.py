@@ -2,9 +2,8 @@
 """Per-kernel compile-only Mosaic accept/reject probes → the per-chip
 priors file.
 
-The ``wgrad_pallas_probe`` pattern (30 s to learn compiled-or-rejected
-BEFORE a window spends its budget) generalized into a registry: every
-Pallas kernel in ``ops/kernels.PROBES`` is AOT-lowered and compiled at a
+Thirty seconds to learn compiled-or-rejected before a run spends its
+budget, as a registry: every Pallas kernel in ``ops/kernels.PROBES`` is AOT-lowered and compiled at a
 representative shape — ZERO execution — and the verdicts land in one
 versioned priors file that
 
@@ -24,8 +23,7 @@ interpreter path compiles, which proves the machinery but records the
 PLANNING backend's verdict — the file stamps ``platform`` so consumers
 can tell. The exit code is non-zero when any probed kernel was refused.
 
-Registered as the 60 s ``kernel_probe`` bench_multi config (in-process
-dispatch, writes next to the session artifact); callable standalone:
+Usage:
 
     python tools/probe_kernels.py [--out kernel_priors.json]
         [--kernels fused_loss conv_epilogue ...]
