@@ -43,7 +43,9 @@ class ModelEntry:
     ``dataset(config)`` builds the data set where the caller gave none
     (None: the image data sets of data/dataset.py); ``counters(model)``
     names what ``loss`` counts; ``attention_kernel_blocks(model, config)``
-    is how many attention blocks take the fused kernel on this backend."""
+    is how many attention blocks take the fused kernel on this backend;
+    ``kept_activation_bytes(model, config)`` is how many bytes of named
+    activations a step keeps across its blocks' backward passes."""
 
     build: Callable
     loss: Callable
@@ -52,6 +54,7 @@ class ModelEntry:
     dataset: Optional[Callable] = None
     counters: Callable = lambda model: ()
     attention_kernel_blocks: Callable = lambda model, config: 0
+    kept_activation_bytes: Callable = lambda model, config: 0
     adam_b2: float = 0.999
     # the reference's ``(batch_size * loss).backward()`` quirk
     # (TrainConfig.faithful_loss_scaling) belongs to its image models
@@ -100,9 +103,10 @@ def _build_milesial(config, compute_dtype):
 
 def _build_twotower(config, compute_dtype):
     from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
+    from distributedpytorch_tpu.utils.backend import device_memory_bytes
 
     model = TwoTower(twotower_config(getattr(config, "model_overrides", None)),
-                     dtype=compute_dtype)
+                     dtype=compute_dtype, memory_bytes=device_memory_bytes())
 
     def init_fn(rng, input_hw):
         return model.init(rng), None
@@ -146,6 +150,13 @@ def _twotower_kernel_blocks(model, config):
     return model.attention_kernel_blocks(jax.default_backend(), config.seq_len)
 
 
+def _twotower_kept_bytes(model, config):
+    import jax
+
+    return sum(model.kept_activation_bytes(
+        config.batch_size, config.seq_len, jax.default_backend()))
+
+
 MODELS = {
     "unet": ModelEntry(build=_build_unet, loss=_image_loss),
     "milesial": ModelEntry(build=_build_milesial, loss=_image_loss),
@@ -156,7 +167,8 @@ MODELS = {
         build=_build_twotower, loss=_token_loss, batch=TOKEN_BATCH,
         evaluate=_token_eval, dataset=_token_dataset,
         counters=_twotower_counters,
-        attention_kernel_blocks=_twotower_kernel_blocks, adam_b2=0.95,
+        attention_kernel_blocks=_twotower_kernel_blocks,
+        kept_activation_bytes=_twotower_kept_bytes, adam_b2=0.95,
         batch_scaled_backward=False, servable=False,
         single_device_only=True),
 }

@@ -15,8 +15,22 @@ The chip's share of a deployment is part of the shape: ``experts_held``
 of ``experts_total`` routed experts from ``first_held`` (the router keeps
 all its outputs, ``ops/moe.held_experts`` computes the held experts'
 part), and ``vocab_size`` rows of the vocabulary (ids, logits and loss
-are over that slice). Every block is recomputed in the backward pass
-(``jax.checkpoint``), so one block's activations live at a time.
+are over that slice).
+
+Every block is a ``jax.checkpoint``: its input is kept and its forward
+pass is computed again in the backward pass, so one block's activations
+live at a time. Where the device's memory allows (``kept_budget``, from
+the shapes and the figure the device reports; never a flag) a block's
+checkpoint keeps ``KEPT_ACTIVATIONS`` besides, the results that cost a
+large matrix product or a kernel to make again: Mamba-2's ``in_proj``
+result, the shared expert's ``up`` result, the attention kernel's q and
+k after rotary, its v, its output and log-sum-exp. Everything cheap
+(norms, rotary, the router, conv, gates, relu^2) and the scan with its
+float32 decay matrices are still computed twice, and a mixer's last
+product never was: it feeds the residual sum alone. The blocks are
+walked from the last and keep their names while the budget lasts; where
+the memory is unknown (the CPU) or too small, a block's input alone is
+kept, as before.
 
 Not a flax module: ``init`` returns the nested parameter dict and the
 methods take it. Names in the compiled step (``jax.named_scope``):
@@ -27,6 +41,7 @@ methods take it. Names in the compiled step (``jax.named_scope``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -34,8 +49,43 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributedpytorch_tpu.ops import moe, sequence as seq
+from distributedpytorch_tpu.ops import attention_pallas, moe, sequence as seq
 from distributedpytorch_tpu.ops.precision import LOSS_DTYPE, SCAN_DTYPE
+
+#: What a block's ``jax.checkpoint`` keeps across its backward pass
+#: besides the block's input, by ``checkpoint_name``, where
+#: ``kept_budget`` leaves the room: a fixed set, applied to a block whole
+#: or not at all.
+KEPT_ACTIVATIONS = ("mamba_in_proj", "shared_up", *attention_pallas.RESIDUALS)
+#: Bytes a parameter that are the step's arguments (the parameter and
+#: Adam's two moments, float32 each) and that are its gradient, a
+#: temporary of the step.
+ARGUMENT_BYTES_PER_PARAMETER = 12
+GRADIENT_BYTES_PER_PARAMETER = 4
+#: What the step that keeps each block's input alone holds besides, in
+#: bytes a token and unit of ``hidden_size``: every block's input, one
+#: block's backward pass with the scan's float32 decays, the logits of a
+#: token block (a compile for a described v5e: 2.11 GB of its 4.77 GB of
+#: temporaries at 16,384 tokens of width 2688; the rest is the gradient).
+WORKING_BYTES_PER_TOKEN_AND_WIDTH = 48
+#: The part of the memory beside the arguments that the step's
+#: temporaries may fill: the runtime wants a tenth beyond them for its
+#: region, and the allocator holds batches in flight and read-outs beside
+#: the state (0.45 GB in the benchmark's cell).
+TEMPORARIES_SHARE = 1 / 1.2
+
+
+def kept_budget(parameters: int, working_bytes: int, memory_bytes) -> int:
+    """Bytes of activations a step may keep on a device of
+    ``memory_bytes`` that trains ``parameters`` parameters with Adam and
+    needs ``working_bytes`` of temporaries besides their gradient. A
+    device that reports no memory (``None``: the CPU) keeps nothing."""
+    if not memory_bytes:
+        return 0
+    temporaries = TEMPORARIES_SHARE * (
+        memory_bytes - ARGUMENT_BYTES_PER_PARAMETER * parameters)
+    return max(0, int(temporaries - GRADIENT_BYTES_PER_PARAMETER * parameters
+                      - working_bytes))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,14 +164,23 @@ class TwoTower:
     is_stateful = False
 
     def __init__(self, cfg: TwoTowerConfig = NEMOTRON_TWOTOWER_SHARE,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, memory_bytes=None):
+        """``memory_bytes``: what the device that runs the step reports
+        as its memory (``utils/backend.device_memory_bytes``), ``None``
+        where it reports none."""
         bad = set(cfg.hybrid_override_pattern) - set("ME*")
         if bad:
             raise ValueError(f"unknown block kinds {sorted(bad)} in the pattern")
         self.cfg = cfg
         self.dtype = jnp.dtype(dtype)
+        self.memory_bytes = memory_bytes
 
     # -- parameters ---------------------------------------------------------
+    @functools.cached_property
+    def parameter_count(self) -> int:
+        shapes = jax.eval_shape(self.init, jax.random.key(0))
+        return sum(x.size for x in jax.tree.leaves(shapes))
+
     def init(self, rng) -> Dict[str, Any]:
         """Float32 parameters: matrices normal with variance 1 / fan-in
         (a mixer's output projection, the last product before the residual
@@ -191,7 +250,8 @@ class TwoTower:
     def _mamba(self, p, x):
         c = self.cfg
         with jax.named_scope("mamba2"):
-            zxbcdt = seq.matmul(x, p["in_proj"]["kernel"], "bsd,de->bse")
+            zxbcdt = seq.matmul(x, p["in_proj"]["kernel"], "bsd,de->bse",
+                                name="mamba_in_proj")
             z, xbc, dt = jnp.split(
                 zxbcdt, [c.d_inner, c.d_inner + c.conv_dim], axis=-1)
             # causal depthwise convolution: tap j sees the input k-1-j back
@@ -240,6 +300,45 @@ class TwoTower:
                                   c.num_attention_heads, c.num_key_value_heads)
         return c.hybrid_override_pattern.count("*") if tile else 0
 
+    def named_activation_bytes(self, batch: int, seq_len: int,
+                               platform: str) -> Tuple[int, ...]:
+        """Bytes of ``KEPT_ACTIVATIONS`` in each block, for one step of
+        ``batch`` sequences of ``seq_len`` tokens on ``platform``, from
+        the shapes (tests/test_twotower.py holds them to the traced
+        residuals)."""
+        c, item, t = self.cfg, self.dtype.itemsize, batch * seq_len
+        # blocked XLA recomputes its own blocks and has no names
+        attention = attention_pallas.residual_bytes(
+            batch, seq_len, c.num_attention_heads, c.num_key_value_heads,
+            c.head_dim, item) if self.attention_kernel_blocks(
+                platform, seq_len) else 0
+        block = {
+            "M": t * (c.d_inner + c.conv_dim + c.mamba_num_heads) * item,
+            "E": t * c.moe_shared_expert_intermediate_size * item,
+            "*": attention,
+        }
+        return tuple(block[kind] for kind in c.hybrid_override_pattern)
+
+    def kept_activation_bytes(self, batch: int, seq_len: int,
+                              platform: str) -> Tuple[int, ...]:
+        """What each block's ``jax.checkpoint`` keeps of its
+        ``named_activation_bytes`` (0: the block's input alone). The
+        blocks are walked from the last to the first and a block keeps
+        its names while they fit in what is left of ``kept_budget``: the
+        backward pass frees the last block's first, so what the first
+        blocks keep is what lies beside every other block's backward,
+        and they are the first to go without."""
+        left = kept_budget(
+            self.parameter_count,
+            WORKING_BYTES_PER_TOKEN_AND_WIDTH * batch * seq_len
+            * self.cfg.hidden_size, self.memory_bytes)
+        kept = []
+        for named in reversed(
+                self.named_activation_bytes(batch, seq_len, platform)):
+            kept.append(named if named <= left else 0)
+            left -= kept[-1]
+        return tuple(reversed(kept))
+
     def _experts(self, p, x):
         """``(shared(x) + the held experts' part, counters (3,), the
         chosen experts (T, k), the router's bias after this step)``."""
@@ -260,7 +359,8 @@ class TwoTower:
                 p["experts"]["down"]["kernel"].astype(x.dtype),
                 c.experts_total, c.first_held)
         with jax.named_scope("moe_shared"):
-            up = seq.matmul(flat, p["shared"]["up"]["kernel"], "td,df->tf")
+            up = seq.matmul(flat, p["shared"]["up"]["kernel"], "td,df->tf",
+                            name="shared_up")
             r = jnp.maximum(up, 0)
             shared = seq.matmul(r * r, p["shared"]["down"]["kernel"], "tf,fd->td")
         return ((shared + routed).reshape(lead + (c.hidden_size,)), counters, idx,
@@ -286,12 +386,15 @@ class TwoTower:
         expert block's chosen experts, [(B*S, k) int32, ...]."""
         c = self.cfg
         h = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(self.dtype)
+        kept = self.kept_activation_bytes(*tokens.shape, jax.default_backend())
+        policy = jax.checkpoint_policies.save_only_these_names(*KEPT_ACTIVATIONS)
         counters, chosen, biases = [], [], {}
         for i, kind in enumerate(c.hybrid_override_pattern):
             name = f"block_{i:02d}"
             with jax.named_scope(name):
                 block = jax.checkpoint(
-                    lambda p, h, kind=kind: self._block(kind, p, h))
+                    lambda p, h, kind=kind: self._block(kind, p, h),
+                    policy=policy if kept[i] else None)
                 h, routed = block(params[name], h)
             if routed is not None:
                 counters.append(routed[0])

@@ -78,6 +78,14 @@ ATTENTION_KERNEL_BLOCKS = REGISTRY.gauge(
     "dpt_attention_kernel_blocks",
     "Attention blocks of the model whose shapes take the fused kernel "
     "(0: all run as blocked XLA)")
+# -- the token model's recomputation (models/twotower.py): what each
+#    block's ``jax.checkpoint`` keeps besides the block's input, decided
+#    from shapes and the device's memory (``twotower.kept_budget``) and set once
+#    when the Trainer is built -------------------------------------------
+KEPT_ACTIVATION_BYTES = REGISTRY.gauge(
+    "dpt_kept_activation_bytes",
+    "Bytes of named activations a step keeps across its blocks' backward "
+    "passes (0: each block's input alone is kept and all else recomputed)")
 _STEP_COUNTERS = {
     "moe_rows_routed": MOE_ROWS_ROUTED.labels,
     "moe_rows_computed": MOE_ROWS_COMPUTED.labels,

@@ -30,6 +30,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,8 +53,27 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
     vmem_limit_bytes=_VMEM_LIMIT)
 
+#: ``checkpoint_name``s of what the backward kernel reads besides the
+#: output's gradient: q, k and v as the kernels take them (in their
+#: layout: a name on the caller's layout would keep a second copy) and
+#: the forward kernel's two results. A ``jax.checkpoint`` around the
+#: caller whose policy keeps all five finds the forward ``pallas_call``
+#: dead in its recomputation, and whatever made q, k and v with it.
+RESIDUALS = ("attention_q", "attention_k", "attention_v",
+             "attention_out", "attention_lse")
+
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def residual_bytes(b: int, s: int, hq: int, hkv: int, d: int,
+                   itemsize: int) -> int:
+    """Bytes of ``RESIDUALS`` for ``b`` sequences of ``s`` positions,
+    ``hq`` query and ``hkv`` key-value heads of size ``d``: q, k, v and the
+    output in the compute dtype and one float32 log-sum-exp a query row,
+    stored ``_SUBLANES`` rows deep."""
+    return b * s * ((2 * hq + 2 * hkv) * d * itemsize
+                    + hq * _SUBLANES * jnp.dtype(LOSS_DTYPE).itemsize)
 
 
 def fits_vmem(s: int, d: int) -> bool:
@@ -197,7 +217,9 @@ def _attention(q, k, v, tile, interpret):
 
 
 def _attention_fwd(q, k, v, tile, interpret):
-    out, lse = _forward(q, k, v, tile, interpret)
+    q, k, v = map(checkpoint_name, (q, k, v), RESIDUALS[:3])
+    out, lse = map(checkpoint_name, _forward(q, k, v, tile, interpret),
+                   RESIDUALS[3:])
     return out, (q, k, v, out, lse)
 
 

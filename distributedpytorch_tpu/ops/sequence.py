@@ -25,6 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distributedpytorch_tpu.ops import attention_pallas
 from distributedpytorch_tpu.ops.precision import (
@@ -44,11 +45,15 @@ LOSS_BLOCK = 2048
 ATTENTION_TILES = (1024, 512, 256)
 
 
-def matmul(x, w, spec: str):
+def matmul(x, w, spec: str, name: str = ""):
     """``einsum(spec, x, w)`` with float32 accumulation, back in ``x``'s
-    dtype: the one spelling of a projection."""
-    return jnp.einsum(spec, x, w.astype(x.dtype),
-                      preferred_element_type=NORM_DTYPE).astype(x.dtype)
+    dtype: the one spelling of a projection. ``name`` is the result's
+    ``checkpoint_name``, given after the cast (a policy on the product
+    itself would keep its float32 sum, twice the bytes); an identity
+    outside a ``jax.checkpoint`` whose policy knows the name."""
+    out = jnp.einsum(spec, x, w.astype(x.dtype),
+                     preferred_element_type=NORM_DTYPE).astype(x.dtype)
+    return checkpoint_name(out, name) if name else out
 
 
 def rms_norm(x, scale, eps: float):

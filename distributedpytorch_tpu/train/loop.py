@@ -167,6 +167,11 @@ class Trainer:
         self.attention_kernel_blocks = self.entry.attention_kernel_blocks(
             self.model, config)
         obsm.ATTENTION_KERNEL_BLOCKS.set(self.attention_kernel_blocks)
+        # likewise what the blocks' recomputation keeps, from shapes and
+        # the device's memory (models/twotower.kept_budget)
+        self.kept_activation_bytes = self.entry.kept_activation_bytes(
+            self.model, config)
+        obsm.KEPT_ACTIVATION_BYTES.set(self.kept_activation_bytes)
         params, model_state = init_fn(
             self.rng, (config.image_size[1], config.image_size[0])
         )
@@ -934,13 +939,15 @@ class Trainer:
         n_train = self.train_loader.num_samples()
         logger.info(
             "Training %s: %d epochs, global batch %d, lr %.2e, %d train "
-            "batches/shard, %d attention blocks on the fused kernel",
+            "batches/shard, %d attention blocks on the fused kernel, %d bytes "
+            "of named activations kept a step",
             cfg.train_method,
             cfg.epochs,
             self.strategy.global_batch_size,
             get_learning_rate(self.state.opt_state),
             len(self.train_loader),
             self.attention_kernel_blocks,
+            self.kept_activation_bytes,
         )
         # whole-run capture only when no step range was asked for — the
         # two would race one another's start/stop on the same profiler

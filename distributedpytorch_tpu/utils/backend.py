@@ -77,6 +77,14 @@ def pallas_interpret() -> bool:
     )
 
 
+def device_memory_bytes():
+    """What the first local device reports as its memory
+    (``memory_stats()["bytes_limit"]``: 16.9 GB on a v5e chip), or
+    ``None`` where it reports none, as the CPU."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
 def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its
     directory. ``JAX_COMPILATION_CACHE_DIR`` set: jax already reads it,

@@ -105,6 +105,50 @@ def test_causal_attention_takes_the_path_it_is_told(monkeypatch, platform, calls
         assert (name in text) == bool(calls)
 
 
+#: An attention block at the kernel's smallest shapes, and a device's
+#: memory beside which it fits.
+KERNEL_BLOCK = dict(hybrid_override_pattern="*", hidden_size=64, vocab_size=96,
+                    num_attention_heads=4, num_key_value_heads=2, head_dim=128)
+
+
+@pytest.mark.parametrize("memory,forwards,kept", [
+    (16 << 30, 1, True),   # the five residuals kept: the forward runs once
+    (None, 2, False),      # the block's input alone: it runs again
+])
+def test_kept_residuals_leave_one_forward_kernel(monkeypatch, memory, forwards,
+                                                 kept):
+    """The gradient of an attention block whose ``jax.checkpoint`` keeps
+    the kernel's residuals holds one forward ``pallas_call`` and one
+    backward, and q's product once less; two forwards where it keeps the
+    block's input alone. The gauge's value is the residuals' bytes."""
+    from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
+
+    monkeypatch.setattr(seq.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attention_pallas, "pallas_interpret", lambda: True)
+    model = TwoTower(twotower_config(KERNEL_BLOCK), jnp.float32,
+                     memory_bytes=memory)
+    params = model.init(jax.random.key(0))
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: model.loss(p, tokens)[0]))(params))
+    assert text.count("name=causal_attention_fwd") == forwards
+    assert text.count("name=causal_attention_bwd") == 1
+    assert text.count("pallas_call") == forwards + 1
+    # q's, k's and v's products: forward, and again unless their results
+    # are kept (dx of the o product has q's shape too: the same in both)
+    assert text.count(":f32[1,256,512] = dot_general[") == forwards + 1
+    assert text.count(":f32[1,256,256] = dot_general[") == 2 * forwards
+    for name in attention_pallas.RESIDUALS:
+        assert (f"name={name}]" in text) is True, name
+    # q and the output (1, 256, 4, 128), k and v (1, 256, 2, 128), float32
+    # here; the log-sum-exp 8 sublanes deep
+    named = 4 * 256 * 128 * (2 * 4 + 2 * 2) + 4 * 4 * 8 * 256
+    assert model.named_activation_bytes(1, 256, "tpu") == (named,)
+    assert model.named_activation_bytes(1, 256, "cpu") == (0,)
+    assert model.kept_activation_bytes(1, 256, "tpu") == (named if kept else 0,)
+    assert defs.KEPT_ACTIVATION_BYTES.name == "dpt_kept_activation_bytes"
+
+
 def test_gauge_counts_the_blocks_that_take_the_kernel():
     from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
 

@@ -3,8 +3,11 @@ data/tokens.py) against its plain reference
 (benchmark/references/nemotron_twotower_30b_a3b.py) at a small size on the
 CPU: seeded random weights, widths shrunk here and nowhere else."""
 
+import collections
 import dataclasses
+import math
 import os
+import re
 import sys
 
 import jax
@@ -21,13 +24,15 @@ import weights_tokens  # noqa: E402
 
 from distributedpytorch_tpu.config import TrainConfig  # noqa: E402
 from distributedpytorch_tpu.models import MODELS, create_model, model_entry  # noqa: E402
+from distributedpytorch_tpu.models import twotower  # noqa: E402
 from distributedpytorch_tpu.models.twotower import (  # noqa: E402
+    KEPT_ACTIVATIONS,
     NEMOTRON_TWOTOWER_SHARE,
     TwoTower,
     counter_names,
     twotower_config,
 )
-from distributedpytorch_tpu.ops import moe, sequence as seq  # noqa: E402
+from distributedpytorch_tpu.ops import attention_pallas, moe, sequence as seq  # noqa: E402
 
 TINY = dict(hidden_size=64, vocab_size=96, mamba_num_heads=8, mamba_head_dim=8,
             ssm_state_size=16, n_groups=2, chunk_size=8, num_attention_heads=4,
@@ -320,3 +325,157 @@ def test_scopes_name_the_compiled_step():
     for scope in ("mamba2", "ssd_scan", "attention", "moe_router", "moe_experts",
                   "moe_shared", "lm_head"):
         assert scope in text, scope
+
+
+# -- what the blocks' recomputation keeps (twotower.KEPT_ACTIVATIONS) --------
+
+#: A device's memory beside which every toy size fits.
+AMPLE = 16 << 30
+
+
+def loss_grads_and_routing(model, params, tokens):
+    """((loss, (counters, biases)), gradients) of one jitted step."""
+    return jax.jit(jax.value_and_grad(
+        lambda p, t: (lambda loss, *rest: (loss, rest))(*model.loss(p, t)),
+        has_aux=True))(params, tokens)
+
+
+def residuals(model, params, tokens, capsys):
+    """How often each shape and dtype is among the residuals that the
+    loss's backward pass keeps, as ``print_saved_residuals`` lists them."""
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p: model.loss(p, tokens)[0], params)
+    return collections.Counter(
+        line.split(" ", 1)[0]
+        for line in capsys.readouterr().out.strip().splitlines())
+
+
+def result_dots(jaxpr_text, shape):
+    """How many matrix products in the jaxpr give a float32 ``shape``."""
+    dims = ",".join(str(n) for n in shape)
+    return jaxpr_text.count(f":f32[{dims}] = dot_general[")
+
+
+@pytest.mark.parametrize("pattern", ["M", "E", "*", "ME*E"])
+def test_kept_activations_change_no_number(pattern, monkeypatch):
+    """Loss, every gradient leaf, counters and biases with the named
+    activations kept are those with each block's input alone kept, and
+    those with nothing recomputed at all."""
+    _, overrides = tiny(pattern)
+    cfg = twotower_config(overrides)
+    params = TwoTower(cfg, jnp.float32).init(jax.random.key(1))
+    tokens = jax.random.randint(jax.random.key(2), (2, 43), 0, 96)
+    kept = loss_grads_and_routing(
+        TwoTower(cfg, jnp.float32, memory_bytes=AMPLE), params, tokens)
+    bare = loss_grads_and_routing(TwoTower(cfg, jnp.float32), params, tokens)
+    monkeypatch.setattr(jax, "checkpoint", lambda fn, **kwargs: fn)
+    plain = loss_grads_and_routing(TwoTower(cfg, jnp.float32), params, tokens)
+    for other in (bare, plain):
+        ((loss, (counters, biases)), grads) = other
+        # the same sums in another order of XLA's fusions: rounding alone
+        assert abs(float(kept[0][0]) - float(loss)) <= 1e-6 * float(loss)
+        assert worst_leaf(bench_weights.flat_names(kept[1]),
+                          bench_weights.flat_names(grads)) < 1e-5
+        assert np.array_equal(kept[0][1][0], counters)
+        assert jax.tree.all(jax.tree.map(np.array_equal, kept[0][1][1], biases))
+
+
+@pytest.mark.parametrize("pattern", ["M", "E", "*", "ME*E"])
+def test_policy_keeps_the_named_results_and_never_the_scans_decays(pattern, capsys):
+    _, overrides = tiny(pattern)
+    cfg = twotower_config(overrides)
+    kept_model = TwoTower(cfg, jnp.float32, memory_bytes=AMPLE)
+    bare_model = TwoTower(cfg, jnp.float32)
+    params = bare_model.init(jax.random.key(1))
+    tokens = jax.random.randint(jax.random.key(2), (2, 43), 0, 96)
+    t = tokens.size
+    kept = residuals(kept_model, params, tokens, capsys)
+    bare = residuals(bare_model, params, tokens, capsys)
+    assert not bare - kept
+    added = sorted((kept - bare).elements())
+    # what the policy adds is the named results and nothing else ...
+    shapes = {
+        "M": [f"f32[2,43,{cfg.d_inner + cfg.conv_dim + cfg.mamba_num_heads}]"],
+        "E": [f"f32[{t},48]"],
+        # blocked XLA attention has no names (the kernel's are held by
+        # tests/test_attention_kernel.py)
+        "*": [],
+    }
+    assert added == sorted(s for kind in pattern for s in shapes[kind])
+    # ... its bytes are the gauge's ...
+    sizes = {"f32": 4, "i32": 4}
+    assert sum(sizes[a[:3]] * math.prod(int(n) for n in a[4:-1].split(","))
+               for a in added) \
+        == sum(kept_model.kept_activation_bytes(2, 43, "cpu")) \
+        == sum(kept_model.named_activation_bytes(2, 43, "cpu"))
+    assert not any(bare_model.kept_activation_bytes(2, 43, "cpu"))
+    # ... and no (chunk x chunk) decay matrix of the scan is among them
+    assert not [a for a in kept if a.count(",") == 5]
+    # every name is in the gradient's jaxpr; a kept product is made once less
+    grad = lambda model: str(jax.make_jaxpr(jax.grad(  # noqa: E731
+        lambda p: model.loss(p, tokens)[0]))(params))
+    kept_text, bare_text = grad(kept_model), grad(bare_model)
+    names = {"M": ["mamba_in_proj"], "E": ["shared_up"], "*": []}
+    for kind in set(pattern):
+        for name in names[kind]:
+            assert f"name={name}]" in kept_text, name
+    assert set(n for ns in names.values() for n in ns) | set(
+        attention_pallas.RESIDUALS) == set(KEPT_ACTIVATIONS)
+    results = {"M": (2, 43, cfg.d_inner + cfg.conv_dim + cfg.mamba_num_heads),
+               "E": (t, 48)}
+    for kind in set(pattern) - {"*"}:
+        assert (result_dots(bare_text, results[kind])
+                - result_dots(kept_text, results[kind])) == pattern.count(kind), kind
+
+
+#: One step of the benchmark's cell: 2 x 8192 tokens of the published
+#: share, bf16, the attention block on the kernel; bytes a block kind.
+CELL_NAMED = {"M": 337_641_472, "E": 121_634_816, "*": 301_989_888}
+V5E = 16_909_336_064  # memory_stats()["bytes_limit"] of one v5e chip
+
+
+@pytest.mark.parametrize("batch,memory,kept", [
+    (2, V5E, "MEMEM*EME"),       # the cell: every block's, 0.50 GB to spare
+    (3, V5E, "---E-*EME"),       # half as many tokens again: from the last,
+    (4, V5E, "------E-E"),       # twice the tokens: what fits beside the rest
+    (2, None, "---------"),      # no figure (the CPU): each block's input alone
+    (2, 12 << 30, "---------"),  # state and working set alone fill it
+])
+def test_blocks_keep_their_names_from_the_last_while_the_budget_lasts(
+        batch, memory, kept):
+    model = TwoTower(dtype=jnp.bfloat16, memory_bytes=memory)
+    named = model.named_activation_bytes(batch, 8192, "tpu")
+    assert named == tuple(CELL_NAMED[k] * batch // 2 for k in "MEMEM*EME")
+    assert model.kept_activation_bytes(batch, 8192, "tpu") == tuple(
+        n if k != "-" else 0 for n, k in zip(named, kept))
+    budget = twotower.kept_budget(
+        666_963_456, 48 * batch * 8192 * 2688, memory)
+    assert sum(model.kept_activation_bytes(batch, 8192, "tpu")) <= budget
+    assert (budget > 0) == ("E" in kept)
+
+
+@pytest.mark.parametrize("memory", [None, 1 << 20])
+def test_without_the_room_the_step_lowers_as_without_any_name(memory, monkeypatch):
+    """A device that reports no memory, or too little, takes block-level
+    recomputation: the lowered step is the one of a program in which no
+    value has a name (and not with the room), character for character
+    once the numbers in private functions' symbols are taken off (jax
+    numbers ``@_where_159`` by the equations traced before it, and a name
+    is an equation, which lowers to nothing)."""
+    _, overrides = tiny("ME*E")
+    cfg = twotower_config(overrides)
+    params = TwoTower(cfg, jnp.float32).init(jax.random.key(1))
+    tokens = jnp.zeros((2, 43), jnp.int32)
+
+    def lowered(memory_bytes):
+        model = TwoTower(cfg, jnp.float32, memory_bytes=memory_bytes)
+        text = jax.jit(jax.value_and_grad(
+            lambda p, t: model.loss(p, t)[0])).lower(params, tokens).as_text()
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    fallback, engaged = lowered(memory), lowered(AMPLE)
+    for module in (seq, attention_pallas):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert fallback == lowered(memory)
+    assert engaged != fallback
