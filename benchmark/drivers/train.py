@@ -65,12 +65,19 @@ def compile_meter(jax):
 class StepLoop:
     """The step loop of ``Trainer._run``: epoch after epoch of the loader's
     batches through ``stacked_work`` and ``pipelined_placement`` into
-    ``trainer.train_step``. One object serves set-up and the window."""
+    ``trainer.train_step``. One object serves set-up and the window.
+    Samples are counted on the batch's ``field``; with ``keep_readouts``
+    every step's second output (a device array of a few floats: the loss,
+    or ``[loss, *counters]``) is kept with the time of its dispatch, to be
+    read once the window has closed."""
 
-    def __init__(self, trainer, tracer, annotate):
+    def __init__(self, trainer, tracer, annotate, field: str = "image",
+                 keep_readouts: bool = False):
         self.trainer = trainer
         self.tracer = tracer
         self.annotate = annotate
+        self.field = field
+        self.readouts = [] if keep_readouts else None
         self.epoch = 0
         self.steps = 0
         self.images = 0
@@ -111,16 +118,18 @@ class StepLoop:
                     (_, payload), placed = item
                     with self.annotate("dispatch"), self.tracer.span(
                             "dispatch", step=self.steps + 1):
-                        tr.state, loss = tr.train_step(tr.state, placed)
+                        tr.state, out = tr.train_step(tr.state, placed)
                     del placed
                     self.steps += 1
-                    self.images += int(payload["image"].shape[0])
-                    self.inflight.append(loss)
+                    self.images += int(payload[self.field].shape[0])
+                    self.inflight.append(out)
+                    if self.readouts is not None:
+                        self.readouts.append((time.perf_counter(), out))
                     if len(self.inflight) > LAG:
                         with self.annotate("readback"):
                             self.inflight.popleft().block_until_ready()
                     if self.on_step is not None:
-                        self.on_step(self, payload, loss)
+                        self.on_step(self, payload, out)
                     if deadline is not None and time.perf_counter() >= deadline:
                         return
                     if last is not None and self.steps >= last:
@@ -315,30 +324,32 @@ def prepare(ctx, seed: int, tracer, annotate, whole_epoch: bool = True):
                                  rows=None)
 
 
-def run(ctx) -> dict:
+def instruments(ctx):
+    """What a run of either driver starts with: jax's compile meter, the
+    program's timeline and the profiler's annotation (both of the last
+    off in an untraced run)."""
     import jax
-
-    args, cell = ctx.args, ctx.cell
-    devices = ctx.devices
-    meter = compile_meter(jax)
 
     from distributedpytorch_tpu.utils.trace import StepTimeline
 
-    if args.trace:
+    if ctx.args.trace:
         annotate = lambda name, **kw: jax.profiler.TraceAnnotation(  # noqa: E731
             "bench_" + name, **kw)
     else:
         annotate = lambda name, **kw: contextlib.nullcontext()  # noqa: E731
-    tracer = StepTimeline(enabled=bool(args.trace))
+    return compile_meter(jax), StepTimeline(enabled=bool(ctx.args.trace)), annotate
 
-    mem = {"start": memory_readings(jax, devices)}
-    session = prepare(ctx, args.seed, tracer, annotate)
-    trainer, loop, cfg, prog = (session.trainer, session.loop, session.cfg,
-                                session.prog)
-    mem["after_setup"] = memory_readings(jax, devices)
 
-    # --- the window --------------------------------------------------------
-    trace_dir = os.path.join(ctx.root, ".bench_run", cell["name"], "trace")
+def measure(ctx, loop, meter, tracer, samples: str = "images"):
+    """The window, for every kind of training cell: ``loop`` driven until
+    the clock ends it. A ``--trace 1`` run traces its first seconds, and
+    the seconds that writing the trace takes are left out of the window.
+    Returns ``(window, setup_s, trace_dir)``; refuses a window in which
+    something compiled or no step completed."""
+    import jax
+
+    args = ctx.args
+    trace_dir = os.path.join(ctx.root, ".bench_run", ctx.cell["name"], "trace")
     traced = None
     if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -372,6 +383,7 @@ def run(ctx) -> dict:
     window = {
         "t0": t0, "seconds": t1 - t0 - paused, **since(start, loop),
         "compiles": meter["compiles"] - compiles0,
+        "compiles_before": compiles0,
         "epochs": loop.epoch,
         "traced": traced,
         # the rest of the window, after the profiler has stopped: what the
@@ -380,49 +392,66 @@ def run(ctx) -> dict:
         "untraced": rest and {"t0": rest[0], "seconds": t1 - rest[0],
                               **since(rest[1], loop)},
     }
-    mem["after_window"] = memory_readings(jax, devices)
-    ctx.say("window: {steps} steps, {images} images in {seconds:.3f} s, "
-            "{compiles} compiles, waited {wait_s:.3f} s for input".format(**window))
+    ctx.say(f"window: {window['steps']} steps, {window['images']} {samples} in "
+            f"{window['seconds']:.3f} s, {window['compiles']} compiles, waited "
+            f"{window['wait_s']:.3f} s for input")
     if window["compiles"]:
         raise NotMeasurable(
             f"{window['compiles']} compilations inside the measured window: "
             "a shape was not warmed in set-up")
     if window["steps"] < 1:
         raise NotMeasurable("no step completed inside the window")
+    return window, setup_s, trace_dir if args.trace else None
 
-    spans = tracer.events()
-    memory = peak_memory(trainer, prog["batches"][0], mem["after_window"])
 
-    # --- free the program's state, then the reference ---------------------
-    batch_size = cfg.batch_size
-    release(session)
-    del trainer, loop
-    gc.collect()
-    t_ref = time.perf_counter()
-    ref = follow(ctx, session)
-    verdict = judge(ctx, prog, ref)
-    verdict["reference_s"] = time.perf_counter() - t_ref
-
-    result = {
+def report(ctx, window, setup_s, trace_dir, meter, mem, memory, spans, verdict,
+           batch, flops_per_sample, samples: str = "images") -> dict:
+    """The ``run`` that ``run.py`` and the readers take, from what a
+    window and its comparison gave (``info`` counts the window's samples
+    under the cell's own word for them)."""
+    return {
         "window": window, "peak_bytes": memory["peak_bytes"],
         "end_to_end": {"train_imgs_per_s": window["images"] / window["seconds"],
                        "setup_s": setup_s},
         "attempted": window["steps"], "failed": 0,
-        "info": {"steps": window["steps"], "images": window["images"],
+        "info": {"steps": window["steps"], samples: window["images"],
                  "window_s": window["seconds"], "epochs": window["epochs"],
-                 "compiles_before_window": compiles0,
+                 "compiles_before_window": window["compiles_before"],
                  "compile_s": meter["compile_s"],
                  "cache_hits": meter["cache_hits"],
                  "reference_s": verdict["reference_s"],
                  "memory": memory,
                  "worst_leaf": verdict["where"]},
         "spans": spans, "verdict": verdict, "memory": mem,
-        "meter": dict(meter), "batch": batch_size,
-        "chips": len(devices), "trace_dir": trace_dir if args.trace else None,
-        "train_flops_per_image": flops.train_flops_per_image(
-            ctx.effective_config()),
+        "meter": dict(meter), "batch": batch,
+        "chips": len(ctx.devices), "trace_dir": trace_dir,
+        "train_flops_per_image": flops_per_sample,
     }
-    return result
+
+
+def run(ctx) -> dict:
+    import jax
+
+    devices = ctx.devices
+    meter, tracer, annotate = instruments(ctx)
+    mem = {"start": memory_readings(jax, devices)}
+    session = prepare(ctx, ctx.args.seed, tracer, annotate)
+    mem["after_setup"] = memory_readings(jax, devices)
+    window, setup_s, trace_dir = measure(ctx, session.loop, meter, tracer)
+    mem["after_window"] = memory_readings(jax, devices)
+    spans = tracer.events()
+    memory = peak_memory(session.trainer, session.prog["batches"][0],
+                         mem["after_window"])
+
+    # --- free the program's state, then the reference ---------------------
+    release(session)
+    t_ref = time.perf_counter()
+    ref = follow(ctx, session)
+    verdict = judge(ctx, session.prog, ref)
+    verdict["reference_s"] = time.perf_counter() - t_ref
+    return report(ctx, window, setup_s, trace_dir, meter, mem, memory, spans,
+                  verdict, session.cfg.batch_size,
+                  flops.train_flops_per_image(ctx.effective_config()))
 
 
 def peak_memory(trainer, batch, readings) -> dict:

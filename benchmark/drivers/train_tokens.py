@@ -1,35 +1,50 @@
 """Driver of ``kind: train_tokens`` cells: a token model through the same
-Trainer, loader, feed (``stacked_work``, ``pipelined_placement``,
-``strategy.place_work``) and compiled step as ``drivers/train.py`` drives
-for an image model, filling the same ``run`` keys, so that every reader
-under ``layer_metrics/`` reads such a cell unedited. ``images`` counts
-samples: one packed sequence.
+Trainer, loader, feed, compiled step, step loop and window as
+``drivers/train.py`` drives for an image model (its ``StepLoop``,
+``measure`` and ``report``), filling the same ``run`` keys, so that every
+reader under ``layer_metrics/`` reads such a cell unedited. ``images``
+counts samples: one packed sequence.
 
 What differs from the image driver, and why it is a file of its own:
-a batch is ``{'tokens': (B, S)}``, not image and mask; the weights'
-rules are a token model's (``weights_tokens.py``); the traffic is packed
-documents (``traffic_tokens.py``); the step's second output is
+a batch is ``{'tokens': (B, S)}``, not image and mask; the traffic is
+packed documents (``traffic_tokens.py``); the step's second output is
 ``[loss, *counters]`` (the program's ``pack_readout``), from which the
-window's expert-row counters are read with no readback of their own; the
-plain reference follows one sequence at a time; logical FLOPs come from
-the reference's walk of the matrix products. ``correct`` is decided as
-for an image cell, by ``check.py``.
+window's counters are read with no readback of their own; the plain
+reference follows one sequence at a time. ``correct`` is decided as for
+an image cell, by ``check.py``.
+
+The driver names no model. What belongs to one comes from the three
+places that a configuration brings (PERF.md §4):
+
+- its reference module ``references/<name>.py``: ``param_shapes``,
+  ``make_loss_and_grad``, ``program_overrides(config)`` (the program's
+  size keys), ``expert_blocks(config)`` (how many of its blocks hold
+  routed experts), ``train_flops_per_sample``, and, where it has routers,
+  ``balanced_biases`` and ``routed_left_out``;
+- its file ``configs/<name>.json``: ``train_config.model_arch``, an
+  optional ``weights`` (the module under ``benchmark/`` whose
+  ``make(shapes, seed, config)`` draws the seed's weights; default
+  ``weights_tokens``) and an optional ``deployment``;
+- the cell's file: ``settle_steps`` (optional), limits, ``rehearsal``.
+
+A model without expert blocks runs with no settling, no routing program
+and no router rows among its checks.
 
 ``follow(mode=...)``: ``f32`` the reference; ``fp8`` the control (its
 matrix products in 8-bit floats); ``no_routed`` a planted fault (the
 routed experts left out). ``keep`` and ``skip_update`` as in the image
-driver. ``--fault`` (rehearsal only): ``unchanged``, ``half_batch``,
-``no_routed`` break the step underneath; ``wrong_mask`` (the name the
-harness's tests give a loader that alters a row) alters a token of each
-batch's first sequence.
+driver, but that a batch of ONE sequence is cut by positions (half the
+row), a larger one by rows. ``--fault`` (rehearsal only): ``unchanged``,
+``half_batch``, ``no_routed`` (``faults``: only with expert blocks) break
+the step underneath; ``wrong_mask`` (the name the harness's tests give a
+loader that alters a row) alters a token of each batch's first sequence.
 """
 
 from __future__ import annotations
 
-import contextlib
 import gc
+import importlib
 import os
-import shutil
 import time
 import types
 
@@ -40,15 +55,19 @@ import reference
 import traffic as traffic_params
 import traffic_tokens
 import weights as weights_mod
-import weights_tokens
 from drivers import train as image_driver
 
-LAG = image_driver.LAG
 COMPARED_STEPS = image_driver.COMPARED_STEPS
 NotMeasurable = image_driver.NotMeasurable
 trace_summary = image_driver.trace_summary
 judge = image_driver.judge
-FAULTS = ("unchanged", "half_batch", "no_routed", "wrong_mask")
+
+
+def faults(config) -> tuple:
+    """The faults ``--fault`` can plant in a cell of this configuration:
+    routed experts can be left out only where there are some."""
+    routed = flops.load_reference(config).expert_blocks(config) > 0
+    return ("unchanged", "half_batch") + ("no_routed",) * routed + ("wrong_mask",)
 
 
 def effective_config(ctx) -> dict:
@@ -57,82 +76,18 @@ def effective_config(ctx) -> dict:
     config = ctx.config
     if ctx.args.rehearse:
         toy = ctx.cell["rehearsal"]
-        config = {**config, **toy["config"],
-                  "deployment": {**config["deployment"], **toy["deployment"]}}
+        config = {**config, **toy.get("config", {})}
+        if "deployment" in toy:
+            config.update(deployment={**config.get("deployment", {}),
+                                      **toy["deployment"]})
     return config
 
 
-def model_overrides(config) -> dict:
-    """The program's size keys (``TwoTowerConfig``) from the
-    configuration: the same names, but for the deployment's two."""
-    import dataclasses
-
-    from distributedpytorch_tpu.models.twotower import TwoTowerConfig
-
-    out = {f.name: config[f.name] for f in dataclasses.fields(TwoTowerConfig)
-           if f.name in config}
-    out.update(experts_total=config["deployment"]["experts_total"],
-               first_held=config["deployment"]["first_held"],
-               norm_eps=config["layer_norm_epsilon"])
-    return out
-
-
-class StepLoop(image_driver.StepLoop):
-    """``drivers/train.StepLoop`` for token batches: samples are counted
-    on ``tokens``, and each step's readout is kept (a device array of a
-    few floats) for the counters."""
-
-    def __init__(self, trainer, tracer, annotate):
-        super().__init__(trainer, tracer, annotate)
-        self.readouts = []
-
-    def run(self, deadline=None, epochs=None, max_steps=None):
-        from distributedpytorch_tpu.utils.prefetch import (
-            pipelined_placement,
-            stacked_work,
-        )
-
-        tr, cfg = self.trainer, self.trainer.config
-        done, last = 0, None if max_steps is None else self.steps + max_steps
-        while epochs is None or done < epochs:
-            source = pipelined_placement(
-                stacked_work(tr.train_loader.epoch_batches(self.epoch), 1,
-                             cfg.batch_size),
-                tr.strategy.place_work,
-                depth=cfg.prefetch_batches,
-                tracer=self.tracer,
-                epoch=self.epoch,
-                max_retries=cfg.data_retries,
-                retry_backoff_s=cfg.retry_backoff_s,
-            )
-            with contextlib.closing(source):
-                while True:
-                    t0 = time.perf_counter()
-                    with self.annotate("input_wait"):
-                        item = next(source, None)
-                    self.wait_s += time.perf_counter() - t0
-                    if item is None:
-                        break
-                    (_, payload), placed = item
-                    with self.annotate("dispatch"), self.tracer.span(
-                            "dispatch", step=self.steps + 1):
-                        tr.state, out = tr.train_step(tr.state, placed)
-                    del placed
-                    self.steps += 1
-                    self.images += int(payload["tokens"].shape[0])
-                    self.inflight.append(out)
-                    self.readouts.append((time.perf_counter(), out))
-                    if len(self.inflight) > LAG:
-                        with self.annotate("readback"):
-                            self.inflight.popleft().block_until_ready()
-                    if self.on_step is not None:
-                        self.on_step(self, payload, out)
-                    if deadline is not None and time.perf_counter() >= deadline:
-                        return
-                    if last is not None and self.steps >= last:
-                        return
-            self.epoch += 1
-            done += 1
+def step_loop(trainer, tracer, annotate):
+    """``drivers/train.StepLoop`` counting samples on ``tokens`` and
+    keeping each step's ``[loss, *counters]`` for the counters."""
+    return image_driver.StepLoop(trainer, tracer, annotate, field="tokens",
+                                 keep_readouts=True)
 
 
 def build_train_config(ctx, seed: int):
@@ -145,7 +100,8 @@ def build_train_config(ctx, seed: int):
     if ctx.args.rehearse:
         fields.update(cell["rehearsal"]["train_config"])
     # the configuration's file sizes the program, whatever its defaults are
-    fields["model_overrides"] = model_overrides(config)
+    fields["model_overrides"] = flops.load_reference(config).program_overrides(
+        config)
     fields.update(
         seed=seed, synthetic_samples=0, val_percent=0.0,
         epochs=10 ** 9, checkpoint_dir=os.path.join(out_dir, "checkpoints"),
@@ -155,10 +111,13 @@ def build_train_config(ctx, seed: int):
     return TrainConfig(**fields)
 
 
-def plant_fault(trainer, fault: str):
+def plant_fault(trainer, fault: str, offered: tuple):
     import jax
     import jax.numpy as jnp
 
+    if fault not in offered:
+        raise NotMeasurable(f"unknown fault {fault!r} for this configuration "
+                            f"(known: {offered})")
     if fault == "wrong_mask":
         batches = trainer.train_loader.epoch_batches
 
@@ -195,11 +154,11 @@ def plant_fault(trainer, fault: str):
         def step(state, batch):
             _, out = real(jax.tree.map(jnp.copy, state), batch)
             return state, out
-    elif fault == "half_batch":
+    else:  # half_batch: of one sequence, half the row
         def step(state, batch):
-            return real(state, {k: v[: v.shape[0] // 2] for k, v in batch.items()})
-    else:
-        raise NotMeasurable(f"unknown fault {fault!r} (known: {FAULTS})")
+            return real(state, {
+                k: v[:, : v.shape[1] // 2] if v.shape[0] == 1
+                else v[: v.shape[0] // 2] for k, v in batch.items()})
     step.lower = real.lower
     trainer.train_step = step
 
@@ -230,7 +189,7 @@ def settle_routers(trainer, tracer, annotate, steps: int):
     trainer.state = state.replace(
         opt_state=set_learning_rate(state.opt_state, 0.0))
     del state
-    loop = StepLoop(trainer, tracer, annotate)
+    loop = step_loop(trainer, tracer, annotate)
     loop.run(max_steps=steps)
     loop.drain()
     state, trainer.state = trainer.state, None
@@ -244,6 +203,9 @@ def settle_routers(trainer, tracer, annotate, steps: int):
 
 
 def router_biases(flat: dict) -> dict:
+    """The leaves the program names ``*/router/bias``: selection biases
+    that a model's own balancing moves, not the optimiser (none in a model
+    without routers)."""
     return {k: v for k, v in flat.items() if k.endswith("/router/bias")}
 
 
@@ -275,7 +237,7 @@ def prepare(ctx, seed: int, tracer, annotate, whole_epoch: bool = False):
     trainer = Trainer(cfg, dataset=traffic_tokens.build(*mix),
                       strategy=build_strategy(cfg, list(devices)))
     if getattr(ctx.args, "fault", None):
-        plant_fault(trainer, ctx.args.fault)
+        plant_fault(trainer, ctx.args.fault, faults(config))
 
     # the program's own initial state goes first (8 GB at the published
     # sizes): the seed's weights, their copy in the program's tree and the
@@ -293,7 +255,8 @@ def prepare(ctx, seed: int, tracer, annotate, whole_epoch: bool = False):
     for leaf in jax.tree.leaves((state.params, state.opt_state)):
         leaf.delete()
     del state
-    flat = weights_tokens.make(shapes, seed, config["num_hidden_layers"])
+    flat = importlib.import_module(config.get("weights", "weights_tokens")).make(
+        shapes, seed, config)
     start = {k: np.asarray(v) for k, v in flat.items()}  # kept on the host
     params = weights_mod.to_program(flat, template)
     del flat
@@ -307,7 +270,7 @@ def prepare(ctx, seed: int, tracer, annotate, whole_epoch: bool = False):
     start.update({k: np.asarray(v) for k, v in router_biases(
         weights_mod.flat_names(trainer.state.params)).items()})
 
-    loop = StepLoop(trainer, tracer, annotate)
+    loop = step_loop(trainer, tracer, annotate)
     prog = {"losses": [], "batches": [], "settled": settled}
     norms = jax.jit(reference.leaf_norms)
     b1 = config["optimizer"]["b1"]
@@ -408,32 +371,37 @@ def follow(ctx, session, mode: str = "f32", keep: float = 1.0,
     import jax
     import jax.numpy as jnp
 
-    config = session.config
-    if mode == "no_routed":
-        config = {**config, "n_routed_experts": 0}
+    config, start = session.config, session.start
     ref_module = flops.load_reference(config)
+    if mode == "no_routed":
+        config, start = ref_module.routed_left_out(config, start)
+    # a batch of ONE sequence is cut by positions (half of it is half the
+    # row), a larger one by rows
+    rows = compared_rows(session)
+    one, length = rows[0].shape[0] == 1, session.cfg.seq_len
+    if one and keep < 1.0:
+        length = max(2, round(length * keep))
     # every program of the reference is loaded before its first array is
     # placed (see make_loss_and_grad)
     loss_and_grad = ref_module.make_loss_and_grad(
-        config, "f32" if mode == "no_routed" else mode, tokens=session.cfg.seq_len)
+        config, "f32" if mode == "no_routed" else mode, tokens=length)
     ensure_region(session, int(1.1 * loss_and_grad.temp_bytes))
     update = reference.make_update(config)
-    start = session.start
-    if mode == "no_routed":
-        start = {k: (v[:0] if "/experts/" in k else v) for k, v in start.items()}
+    balance = getattr(ref_module, "balanced_biases", None)
     cur = {k: jnp.asarray(v) for k, v in start.items()}
     # Adam's moments wait on the host between updates (5.3 GB that the
     # gradient's pass does not need beside it); a leaf at a time, under one
     # name so that the update compiles once a shape
     moments = {k: [np.zeros(x.shape, np.float32)] * 2 for k, x in start.items()}
     losses, g1, routed = [], {}, None
-    for i, tokens in enumerate(compared_rows(session)):
-        tokens = tokens[: max(1, round(len(tokens) * keep))]
+    for i, tokens in enumerate(rows):
+        tokens = (tokens[:, :length] if one
+                  else tokens[: max(1, round(len(tokens) * keep))])
         loss, g, chosen, loads = loss_and_grad(cur, tokens)
         routed = chosen if i == 0 else routed
         # the routers' own balancing, from the biases the step began with
-        biases = {} if skip_update else ref_module.balanced_biases(
-            config, cur, loads)
+        biases = ({} if skip_update or balance is None
+                  else balance(config, cur, loads))
         for k in list(cur):
             m, v = ({"x": jnp.asarray(x)} for x in moments[k])
             new, m, v, g_k = update({"x": cur[k]}, m, v, jnp.float32(i + 1),
@@ -452,11 +420,10 @@ def follow(ctx, session, mode: str = "f32", keep: float = 1.0,
            "grad_norms": {k: float(np.sqrt(np.sum(np.square(x, dtype=np.float64))))
                           for k, x in g1.items()},
            "change_norms": change}
-    if mode == "no_routed":  # the leaves that fault has not: nothing moved
-        for k, x in session.start.items():
-            if "/experts/" in k:
-                out["grad"][k] = np.zeros(x.shape, x.dtype)
-                out["grad_norms"][k] = out["change_norms"][k] = 0.0
+    for k, x in session.start.items():
+        if out["grad"][k].shape != x.shape:  # a leaf left out: nothing moved
+            out["grad"][k] = np.zeros(x.shape, x.dtype)
+            out["grad_norms"][k] = out["change_norms"][k] = 0.0
     return out
 
 
@@ -535,84 +502,33 @@ def routed_by_step(readouts, names) -> list:
 def run(ctx) -> dict:
     import jax
 
-    args, cell, devices = ctx.args, ctx.cell, ctx.devices
-    meter = image_driver.compile_meter(jax)
-
-    from distributedpytorch_tpu.utils.trace import StepTimeline
-
-    if args.trace:
-        annotate = lambda name, **kw: jax.profiler.TraceAnnotation(  # noqa: E731
-            "bench_" + name, **kw)
-    else:
-        annotate = lambda name, **kw: contextlib.nullcontext()  # noqa: E731
-    tracer = StepTimeline(enabled=bool(args.trace))
-
+    devices = ctx.devices
+    meter, tracer, annotate = image_driver.instruments(ctx)
     mem = {"start": image_driver.memory_readings(jax, devices)}
-    session = prepare(ctx, args.seed, tracer, annotate)
+    session = prepare(ctx, ctx.args.seed, tracer, annotate)
     trainer, loop, cfg, prog = (session.trainer, session.loop, session.cfg,
                                 session.prog)
     config = session.config
     mem["after_setup"] = image_driver.memory_readings(jax, devices)
-
-    # --- the window: as drivers/train.run --------------------------------
-    trace_dir = os.path.join(ctx.root, ".bench_run", cell["name"], "trace")
-    traced = None
-    if args.trace:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 1
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        with jax.profiler.TraceAnnotation(
-                "bench_sync", pc_ns=time.perf_counter_ns()):
-            pass
-    tracer.flush()
-    start = image_driver.tally(loop)
-    compiles0 = meter["compiles"]
-    t0 = time.perf_counter()
-    setup_s = time.monotonic() - ctx.t_start
-    paused, rest = 0.0, None
-    if args.trace:
-        loop.run(deadline=t0 + min(args.seconds, ctx.trace_seconds))
-        loop.drain()
-        traced = (t0, time.perf_counter())
-        jax.profiler.stop_trace()
-        rest = (time.perf_counter(), image_driver.tally(loop))
-        paused = rest[0] - traced[1]
-    loop.run(deadline=t0 + paused + args.seconds)
-    loop.drain()
-    t1 = time.perf_counter()
-    window = {
-        "t0": t0, "seconds": t1 - t0 - paused, **image_driver.since(start, loop),
-        "compiles": meter["compiles"] - compiles0,
-        "epochs": loop.epoch,
-        "traced": traced,
-        "untraced": rest and {"t0": rest[0], "seconds": t1 - rest[0],
-                              **image_driver.since(rest[1], loop)},
-    }
+    window, setup_s, trace_dir = image_driver.measure(ctx, loop, meter, tracer,
+                                                      "sequences")
     mem["after_window"] = image_driver.memory_readings(jax, devices)
-    ctx.say("window: {steps} steps, {images} sequences in {seconds:.3f} s, "
-            "{compiles} compiles, waited {wait_s:.3f} s for input".format(**window))
-    if window["compiles"]:
-        raise NotMeasurable(
-            f"{window['compiles']} compilations inside the measured window: "
-            "a shape was not warmed in set-up")
-    if window["steps"] < 1:
-        raise NotMeasurable("no step completed inside the window")
-
     spans = tracer.events()
     memory = image_driver.peak_memory(trainer, prog["batches"][0],
                                       mem["after_window"])
     names = trainer.counter_names
-    counted = counters(loop.readouts, names, rest[0] if rest else t0)
-    expert_blocks = config["hybrid_override_pattern"].count("E")
+    counted = counters(loop.readouts, names,
+                       (window["untraced"] or window)["t0"])
+    ref_module = flops.load_reference(config)
+    expert_blocks = ref_module.expert_blocks(config)
     # rows one expert block's routers sent to the held experts, a step
     routed_rows = (counted["moe_rows_routed"] / counted["steps"] / expert_blocks
                    if counted.get("steps") and expert_blocks else None)
-    by_step = {"settle": routed_by_step(prog["settled"], names),
-               "run": routed_by_step(loop.readouts, names)}
-    ctx.say(f"rows routed to held experts (all expert blocks, fullest expert) "
-            f"by step: {by_step}")
+    if expert_blocks:
+        by_step = {"settle": routed_by_step(prog["settled"], names),
+                   "run": routed_by_step(loop.readouts, names)}
+        ctx.say(f"rows routed to held experts (all expert blocks, fullest "
+                f"expert) by step: {by_step}")
 
     # --- free the program's state, then the reference ---------------------
     batch_size, seq_len = cfg.batch_size, cfg.seq_len
@@ -622,38 +538,27 @@ def run(ctx) -> dict:
     ctx.say(f"after release: {devices[0].memory_stats()}")
     t_ref = time.perf_counter()
     ref = follow(ctx, session)
-    mine = program_routing(session)
+    mine = program_routing(session) if expert_blocks else None
     verdict = judge(ctx, prog, ref)
-    flips = routing_flips_pct(mine, ref)
-    verdict["rows"].append(("routing_flips_pct", flips, None))
-    verdict["rows"].append(
-        ("biases_differ_pct", biases_differ_pct(prog, ref), None))
+    routers = {}
+    if expert_blocks:  # shown, not compared
+        routers["routing_flips_pct"] = routing_flips_pct(mine, ref)
+        verdict["rows"] += [
+            ("routing_flips_pct", routers["routing_flips_pct"], None),
+            ("biases_differ_pct", biases_differ_pct(prog, ref), None)]
     verdict["reference_s"] = time.perf_counter() - t_ref
 
-    ref_module = flops.load_reference(config)
-    return {
-        "window": window, "peak_bytes": memory["peak_bytes"],
-        "end_to_end": {"train_imgs_per_s": window["images"] / window["seconds"],
-                       "setup_s": setup_s},
-        "attempted": window["steps"], "failed": 0,
-        "info": {"steps": window["steps"], "sequences": window["images"],
-                 "tokens_per_s": window["images"] * seq_len / window["seconds"],
-                 "window_s": window["seconds"], "epochs": window["epochs"],
-                 "compiles_before_window": compiles0,
-                 "compile_s": meter["compile_s"],
-                 "cache_hits": meter["cache_hits"],
-                 "reference_s": verdict["reference_s"],
-                 "memory": memory, "counters": counted,
-                 "rows_routed_per_block_step": routed_rows,
-                 "routing_flips_pct": flips,
-                 "worst_leaf": verdict["where"]},
-        "spans": spans, "verdict": verdict, "memory": mem,
-        "meter": dict(meter), "batch": batch_size, "seq_len": seq_len,
-        "chips": len(devices), "trace_dir": trace_dir if args.trace else None,
-        "counters": counted, "routed_rows": routed_rows,
+    out = image_driver.report(
+        ctx, window, setup_s, trace_dir, meter, mem, memory, spans, verdict,
+        batch_size,
         # logical FLOPs of a sequence, the routed experts' over the rows
         # that were routed to them in this window
-        "train_flops_per_image": ref_module.train_flops_per_sample(
+        ref_module.train_flops_per_sample(
             config, seq_len,
             routed_rows=None if routed_rows is None else routed_rows / batch_size),
-    }
+        "sequences")
+    out["info"].update(
+        tokens_per_s=window["images"] * seq_len / window["seconds"],
+        counters=counted, rows_routed_per_block_step=routed_rows, **routers)
+    out.update(seq_len=seq_len, counters=counted, routed_rows=routed_rows)
+    return out

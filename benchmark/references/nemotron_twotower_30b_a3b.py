@@ -107,6 +107,41 @@ def param_count(config) -> int:
     return sum(math.prod(s) for s in param_shapes(config).values())
 
 
+#: The keys of the configuration that size the program (its ``--model-arch
+#: twotower``, ``TrainConfig.model_overrides``) under the same names.
+PROGRAM_KEYS = (
+    "hybrid_override_pattern", "hidden_size", "vocab_size", "mamba_num_heads",
+    "mamba_head_dim", "ssm_state_size", "n_groups", "conv_kernel", "chunk_size",
+    "time_step_min", "time_step_max", "time_step_floor", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "rope_theta", "n_routed_experts",
+    "num_experts_per_tok", "moe_intermediate_size",
+    "moe_shared_expert_intermediate_size", "routed_scaling_factor",
+    "norm_topk_prob", "router_bias_update_rate", "num_hidden_layers")
+
+
+def program_overrides(config) -> dict:
+    """What the driver hands the program to size its model from this
+    configuration: ``PROGRAM_KEYS`` as they are, the deployment's two, and
+    the norm's epsilon under the program's name for it."""
+    out = {k: config[k] for k in PROGRAM_KEYS if k in config}
+    out.update(experts_total=config["deployment"]["experts_total"],
+               first_held=config["deployment"]["first_held"],
+               norm_eps=config["layer_norm_epsilon"])
+    return out
+
+
+def expert_blocks(config) -> int:
+    """How many of the blocks run here hold routed experts."""
+    return config["hybrid_override_pattern"].count("E")
+
+
+def routed_left_out(config, params):
+    """The configuration and the seed's weights of the planted fault
+    ``no_routed``: no routed expert is held, the routers still score."""
+    return ({**config, "n_routed_experts": 0},
+            {k: (v[:0] if "/experts/" in k else v) for k, v in params.items()})
+
+
 def published(config) -> dict:
     """The configuration uncut: the published pattern, every expert, the
     whole vocabulary."""

@@ -11,7 +11,9 @@ or one per-layer metric is a file of its own, found by the name that
     benchmark/references/<reference>.py    its plain reference and the walk of its convolutions
     benchmark/traffic/<traffic>.json       the traffic mix's parameters
     benchmark/drivers/<kind>.py            what drives a cell of that kind
-    benchmark/layer_metrics/<metric>.py    one reader per per-layer metric
+    benchmark/layer_metrics/<metric>.py    one reader per per-layer metric; an entry
+                                           named <reader>.<suffix> that has no file
+                                           of its own is read by <reader>.py
 
 The last line of standard output is the result's JSON object. Without a
 TPU, with fewer chips than the cell asks for, with a device kind that
@@ -73,6 +75,19 @@ def load_module(kind_dir: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(metric: str):
+    """The reader of a per-layer metric: ``layer_metrics/<metric>.py``, or,
+    for an entry named ``<reader>.<suffix>`` with no file of its whole
+    name, ``layer_metrics/<reader>.py``: one reader serves several
+    entries, each with a ``workloads`` list of its own."""
+    parts = metric.split(".")
+    for n in range(len(parts), 0, -1):
+        reader = load_module("layer_metrics", ".".join(parts[:n]))
+        if reader is not None:
+            return reader
+    return None
 
 
 class Context:
@@ -205,7 +220,7 @@ def main(argv=None) -> int:
         for m in bench["per_layer"]:
             if not applies(m, cell["name"]):
                 continue
-            reader = load_module("layer_metrics", m["name"])
+            reader = load_reader(m["name"])
             if reader is None:
                 fail(f"no reader benchmark/layer_metrics/{m['name']}.py")
             value = reader.read(run)

@@ -14,6 +14,7 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, HERE)
 
 import flops  # noqa: E402
+import run as bench_run  # noqa: E402
 import trace_reduce as tr  # noqa: E402
 
 CONFIG = flops.load_config("nemotron_twotower_30b_a3b")
@@ -86,8 +87,8 @@ def test_nothing_to_read_where_no_kernel_ran(monkeypatch):
 
 
 def test_benchmark_json_lists_the_metric_for_the_token_cell_alone():
-    bench = json.load(open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")))
-    entry = bench["per_layer"][-1]
+    bench = bench_run.load_json(bench_run.ROOT, "BENCHMARK.json")
+    entry, = (m for m in bench["per_layer"] if m["name"] == "attention_roofline")
     assert entry == {"name": "attention_roofline", "unit": "%", "better": "higher",
                      "source": "device_trace", "layer": "kernels",
                      "moves": "train_imgs_per_s",

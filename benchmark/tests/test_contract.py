@@ -1,6 +1,10 @@
 """``BENCHMARK.json`` against the limits of its contract that can be
 checked without a run, and against the files it names: every cell,
-configuration, traffic mix and per-layer metric is a file of its own."""
+configuration, traffic mix and per-layer metric is a file of its own.
+Every entry is found by its name and through the harness's own loaders
+(``run.load_json``, ``flops.load_reference``, ``run.load_reader``), never
+by its place in a list: a later PR appends entries
+(``test_second_token_config.py`` runs these checks over such a file)."""
 
 import json
 import os
@@ -11,6 +15,9 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import flops  # noqa: E402
+import run as bench_run  # noqa: E402
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -19,8 +26,7 @@ WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "proj", "width",
 
 
 def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return bench_run.load_json(ROOT, "BENCHMARK.json")
 
 
 def line(text, limit=200):
@@ -57,8 +63,7 @@ def test_configs():
             assert NAME.match(key)
             assert not key.endswith(("_dim", "_rank"))
             assert not any(w in key for w in WIDTH_WORDS)
-        assert os.path.exists(os.path.join(
-            HERE, "references", data["reference"] + ".py"))
+        assert callable(flops.load_reference(data).param_shapes)
     assert len({c["file"] for c in b["configs"]}) == len(b["configs"])
 
 
@@ -73,8 +78,7 @@ def test_cells():
         assert w["config"] in configs and w["chips"] in (1, 4)
         assert line(w["why"])
         pairs.add((w["config"], w["traffic"]))
-        with open(os.path.join(HERE, "workloads", w["name"] + ".json")) as f:
-            cell = json.load(f)
+        cell = bench_run.load_json(HERE, "workloads", w["name"] + ".json")
         assert os.path.exists(os.path.join(HERE, "drivers", cell["kind"] + ".py"))
         assert os.path.exists(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
         assert all(isinstance(v, (int, float)) for v in cell["limits"].values())
@@ -99,7 +103,7 @@ def test_metrics():
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
         assert m["moves"] in e2e and m["source"] in SOURCES and line(m["layer"])
-        assert os.path.exists(os.path.join(HERE, "layer_metrics", m["name"] + ".py"))
+        assert callable(bench_run.load_reader(m["name"]).read)
         if m["name"].endswith("_roofline") or "mfu" in m["name"]:
             assert m["unit"] == "%"
     for m in b["end_to_end"] + b["per_layer"]:

@@ -6,6 +6,7 @@ configuration brings. (``test_reference.py`` already runs every cell of
 the fp8 control and the faults ``unchanged``, ``half_batch`` and
 ``wrong_mask``.)"""
 
+import functools
 import json
 import os
 import sys
@@ -25,6 +26,20 @@ CELL = "twotower_train_packed8k"
 CONFIG = flops.load_config("nemotron_twotower_30b_a3b")
 REF = flops.load_reference(CONFIG)
 PEAK = json.load(open(os.path.join(HERE, "peaks.json")))["TPU v5 lite"]
+#: What a run of the cell prints, by name, as PR 31's harness printed it:
+#: a harness that takes other token models may not change what this one shows.
+CHECKS = ["grad_diff_median", "loss_gap_step3", "grad_norm_gap", "change_norm_gap",
+          "rows_repeated", "rows_altered", "loss_gap_step1", "loss_gap_step2",
+          "grad_norm_gap_median", "change_norm_gap_median", "grad_diff_worst",
+          "routing_flips_pct", "biases_differ_pct"]
+INFO = ["cache_hits", "compile_s", "compiles_before_window", "counters", "epochs",
+        "memory", "reference_s", "routing_flips_pct", "rows_routed_per_block_step",
+        "sequences", "steps", "tokens_per_s", "window_s", "worst_leaf"]
+
+
+@functools.lru_cache(maxsize=None)
+def sound_line():
+    return rehearse(CELL, 2147489010)
 
 
 def test_walk_counts_the_cut_and_the_published_tower():
@@ -111,8 +126,37 @@ def test_routed_experts_left_out_come_out_not_correct():
     assert "grad_norm_gap" in over
 
 
+def test_rehearsal_prints_the_checks_and_info_it_printed_before():
+    line = sound_line()
+    assert line["correct"] is True
+    assert list(line["checks"]) == CHECKS and list(line)[-1] == "checks"
+    assert sorted(line["info"]) == INFO
+    assert sorted(line["info"]["counters"]) == [
+        "moe_rows_computed", "moe_rows_max_expert", "moe_rows_routed", "steps"]
+
+
+def test_program_overrides_are_the_programs_size_keys_that_the_file_has():
+    """The reference module lists the keys by name (it imports nothing of
+    the program); the program's own dataclass says whether one is missing."""
+    import dataclasses
+
+    from distributedpytorch_tpu.models.twotower import TwoTowerConfig
+
+    fields = {f.name for f in dataclasses.fields(TwoTowerConfig)}
+    out = REF.program_overrides(CONFIG)
+    assert set(out) == {k for k in fields if k in CONFIG} | {
+        "experts_total", "first_held"}
+    assert (out["experts_total"], out["first_held"], out["norm_eps"]) == (
+        128, 0, CONFIG["layer_norm_epsilon"])
+    assert all(out[k] == CONFIG[k] for k in REF.PROGRAM_KEYS)
+    assert REF.expert_blocks(CONFIG) == 4
+    # the published share, but for the balancing rate the cell states
+    assert TwoTowerConfig(**out) == dataclasses.replace(
+        TwoTowerConfig(), router_bias_update_rate=0.01)
+
+
 def test_rehearsal_reports_counters_and_both_new_readers(monkeypatch):
-    line = rehearse(CELL, 2147489010)
+    line = sound_line()
     counted = line["info"]["counters"]
     assert counted["moe_rows_computed"] >= counted["moe_rows_routed"] > 0
     assert 0 <= line["checks"]["routing_flips_pct"]["value"] < 20

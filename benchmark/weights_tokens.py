@@ -5,9 +5,9 @@ under the same flat names. By the leaf's name: ``embedding`` unit normal;
 ``kernel`` normal with variance 1 / fan-in (the axis before the last; a
 depthwise convolution's (taps, channels) kernel: its taps), a mixer's
 output projection (``out_proj``, ``o``, ``down``: the last product before
-the residual sum) divided by sqrt(2 x ``layers``) besides, the published
-depth (the config's ``rescale_prenorm_residual``); ``scale`` and
-``D`` one; ``bias`` zero; ``A_log`` the log of uniform [1, 16);
+the residual sum) divided by sqrt(2 x layers) besides, the published
+depth (``num_hidden_layers``: the config's ``rescale_prenorm_residual``);
+``scale`` and ``D`` one; ``bias`` zero; ``A_log`` the log of uniform [1, 16);
 ``dt_bias`` the inverse softplus of a step log-uniform in [0.001, 0.1]:
 what a freshly initialised network of this family holds.
 """
@@ -20,8 +20,11 @@ import jax
 import jax.numpy as jnp
 
 
-def make(shapes: dict, seed: int, layers: int = 52) -> dict:
+def make(shapes: dict, seed: int, config: dict = None) -> dict:
+    """``config``: the configuration's file, for the published depth
+    (``num_hidden_layers``; 52 without one)."""
     names = sorted(shapes)
+    layers = (config or {}).get("num_hidden_layers", 52)
 
     def build(key):
         out = {}
