@@ -147,15 +147,19 @@ def get_args():
                              "(2 on TPU, 0 elsewhere)")
     parser.add_argument("--model", "--model-arch", dest="model_arch",
                         type=str, default="unet",
-                        choices=["unet", "milesial", "twotower"],
+                        choices=["unet", "milesial", "twotower", "lfm2"],
                         help="Model (models/__init__.py holds the table): "
                              "the reference course UNet (7.76M params), the "
                              "original milesial/Pytorch-UNet (31M params, "
                              "BatchNorm), or 'twotower': one chip's share "
                              "(667M params) of the Mamba-2 + expert + "
                              "attention tower of Nemotron-Labs-TwoTower-"
-                             "30B-A3B's config.json, trained on packed "
-                             "token sequences (-t singleGPU only)")
+                             "30B-A3B's config.json, or 'lfm2': one chip's "
+                             "share (788M params) of LFM2-24B-A2B's "
+                             "config.json (gated short convolutions, "
+                             "QK-normed attention, dense and sparse "
+                             "SwiGLU feed-forwards); both trained on "
+                             "packed token sequences (-t singleGPU only)")
     parser.add_argument("--seq-len", type=int, default=8192,
                         help="Tokens to a packed sequence of a token "
                              "model's batch (-b counts sequences)")

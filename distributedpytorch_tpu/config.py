@@ -119,9 +119,10 @@ class TrainConfig:
     # None = the architecture's documented channel plan. Narrower tuples
     # build faster-compiling variants for tests.
     model_widths: Optional[Tuple[int, ...]] = None
-    # "twotower" (models/twotower.py; models/__init__.py holds the table)
-    # is built at the published share; a mapping of its size keys here
-    # shrinks it for tests and rehearsals (as model_widths does a UNet).
+    # A token model ("twotower", models/twotower.py; "lfm2", models/lfm2.py;
+    # models/__init__.py holds the table) is built at its published share;
+    # a mapping of its size keys here shrinks it for tests and rehearsals
+    # (as model_widths does a UNet).
     model_overrides: Optional[dict] = None
     # Tokens to a packed sequence of a token model's batch (data/tokens.py);
     # -b counts sequences.

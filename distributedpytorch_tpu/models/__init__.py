@@ -101,17 +101,22 @@ def _build_milesial(config, compute_dtype):
     return model, init_fn
 
 
-def _build_twotower(config, compute_dtype):
-    from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
-    from distributedpytorch_tpu.utils.backend import device_memory_bytes
+def _token_builder(module: str, model: str, sizes: str):
+    """``build`` of a token model: class ``model`` of ``models/<module>.py``
+    at its published share with ``config.model_overrides`` laid over it
+    (``sizes``), told what the device reports as its memory."""
+    def build(config, compute_dtype):
+        import importlib
 
-    model = TwoTower(twotower_config(getattr(config, "model_overrides", None)),
-                     dtype=compute_dtype, memory_bytes=device_memory_bytes())
+        from distributedpytorch_tpu.utils.backend import device_memory_bytes
 
-    def init_fn(rng, input_hw):
-        return model.init(rng), None
+        mod = importlib.import_module(f"{__name__}.{module}")
+        net = getattr(mod, model)(
+            getattr(mod, sizes)(getattr(config, "model_overrides", None)),
+            dtype=compute_dtype, memory_bytes=device_memory_bytes())
+        return net, lambda rng, input_hw: (net.init(rng), None)
 
-    return model, init_fn
+    return build
 
 
 def _token_loss(model, params, model_state, batch, loss_impl=None):
@@ -132,30 +137,37 @@ def _token_eval(model, variables, batch):
 
 def _token_dataset(config):
     from distributedpytorch_tpu.data.tokens import build_token_dataset
-    from distributedpytorch_tpu.models.twotower import twotower_config
 
-    return build_token_dataset(
-        config, twotower_config(config.model_overrides).vocab_size)
-
-
-def _twotower_counters(model):
-    from distributedpytorch_tpu.models.twotower import counter_names
-
-    return counter_names(model.cfg)
+    # the vocabulary is the model's: built for its sizes alone (no array)
+    return build_token_dataset(config, create_model(config)[0].cfg.vocab_size)
 
 
-def _twotower_kernel_blocks(model, config):
+def _token_counters(model):
+    return model.counter_names
+
+
+def _token_kernel_blocks(model, config):
     import jax
 
     return model.attention_kernel_blocks(jax.default_backend(), config.seq_len)
 
 
-def _twotower_kept_bytes(model, config):
+def _token_kept_bytes(model, config):
     import jax
 
     return sum(model.kept_activation_bytes(
         config.batch_size, config.seq_len, jax.default_backend()))
 
+
+#: What the token models share: a batch of packed tokens, mean next-token
+#: cross-entropy with counters and the routers' own biases beside it,
+#: trained on one device, not served.
+_TOKEN_MODEL = ModelEntry(
+    build=None, loss=_token_loss, batch=TOKEN_BATCH, evaluate=_token_eval,
+    dataset=_token_dataset, counters=_token_counters,
+    attention_kernel_blocks=_token_kernel_blocks,
+    kept_activation_bytes=_token_kept_bytes, adam_b2=0.95,
+    batch_scaled_backward=False, servable=False, single_device_only=True)
 
 MODELS = {
     "unet": ModelEntry(build=_build_unet, loss=_image_loss),
@@ -163,14 +175,14 @@ MODELS = {
     # the 52-block tower of Nemotron-Labs-TwoTower-30B-A3B-Base-BF16's
     # config.json, one chip's share (models/twotower.py); its denoising
     # tower and block-diffusion decoding are not supported
-    "twotower": ModelEntry(
-        build=_build_twotower, loss=_token_loss, batch=TOKEN_BATCH,
-        evaluate=_token_eval, dataset=_token_dataset,
-        counters=_twotower_counters,
-        attention_kernel_blocks=_twotower_kernel_blocks,
-        kept_activation_bytes=_twotower_kept_bytes, adam_b2=0.95,
-        batch_scaled_backward=False, servable=False,
-        single_device_only=True),
+    "twotower": dataclasses.replace(
+        _TOKEN_MODEL, build=_token_builder("twotower", "TwoTower",
+                                           "twotower_config")),
+    # LFM2-24B-A2B's config.json (lfm2_moe), one chip's share
+    # (models/lfm2.py): gated short convolutions and QK-normed attention,
+    # a dense or a sparse SwiGLU feed-forward in every layer, tied head
+    "lfm2": dataclasses.replace(
+        _TOKEN_MODEL, build=_token_builder("lfm2", "Lfm2", "lfm2_config")),
 }
 
 

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributedpytorch_tpu.models.recompute import keep_from_last, kept_budget
 from distributedpytorch_tpu.ops import attention_pallas, moe, sequence as seq
 from distributedpytorch_tpu.ops.precision import LOSS_DTYPE, SCAN_DTYPE
 
@@ -57,35 +58,12 @@ from distributedpytorch_tpu.ops.precision import LOSS_DTYPE, SCAN_DTYPE
 #: ``kept_budget`` leaves the room: a fixed set, applied to a block whole
 #: or not at all.
 KEPT_ACTIVATIONS = ("mamba_in_proj", "shared_up", *attention_pallas.RESIDUALS)
-#: Bytes a parameter that are the step's arguments (the parameter and
-#: Adam's two moments, float32 each) and that are its gradient, a
-#: temporary of the step.
-ARGUMENT_BYTES_PER_PARAMETER = 12
-GRADIENT_BYTES_PER_PARAMETER = 4
 #: What the step that keeps each block's input alone holds besides, in
 #: bytes a token and unit of ``hidden_size``: every block's input, one
 #: block's backward pass with the scan's float32 decays, the logits of a
 #: token block (a compile for a described v5e: 2.11 GB of its 4.77 GB of
 #: temporaries at 16,384 tokens of width 2688; the rest is the gradient).
 WORKING_BYTES_PER_TOKEN_AND_WIDTH = 48
-#: The part of the memory beside the arguments that the step's
-#: temporaries may fill: the runtime wants a tenth beyond them for its
-#: region, and the allocator holds batches in flight and read-outs beside
-#: the state (0.45 GB in the benchmark's cell).
-TEMPORARIES_SHARE = 1 / 1.2
-
-
-def kept_budget(parameters: int, working_bytes: int, memory_bytes) -> int:
-    """Bytes of activations a step may keep on a device of
-    ``memory_bytes`` that trains ``parameters`` parameters with Adam and
-    needs ``working_bytes`` of temporaries besides their gradient. A
-    device that reports no memory (``None``: the CPU) keeps nothing."""
-    if not memory_bytes:
-        return 0
-    temporaries = TEMPORARIES_SHARE * (
-        memory_bytes - ARGUMENT_BYTES_PER_PARAMETER * parameters)
-    return max(0, int(temporaries - GRADIENT_BYTES_PER_PARAMETER * parameters
-                      - working_bytes))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,6 +158,10 @@ class TwoTower:
     def parameter_count(self) -> int:
         shapes = jax.eval_shape(self.init, jax.random.key(0))
         return sum(x.size for x in jax.tree.leaves(shapes))
+
+    @property
+    def counter_names(self) -> Tuple[str, ...]:
+        return counter_names(self.cfg)
 
     def init(self, rng) -> Dict[str, Any]:
         """Float32 parameters: matrices normal with variance 1 / fan-in
@@ -328,16 +310,11 @@ class TwoTower:
         backward pass frees the last block's first, so what the first
         blocks keep is what lies beside every other block's backward,
         and they are the first to go without."""
-        left = kept_budget(
-            self.parameter_count,
-            WORKING_BYTES_PER_TOKEN_AND_WIDTH * batch * seq_len
-            * self.cfg.hidden_size, self.memory_bytes)
-        kept = []
-        for named in reversed(
-                self.named_activation_bytes(batch, seq_len, platform)):
-            kept.append(named if named <= left else 0)
-            left -= kept[-1]
-        return tuple(reversed(kept))
+        return keep_from_last(
+            self.named_activation_bytes(batch, seq_len, platform),
+            kept_budget(self.parameter_count,
+                        WORKING_BYTES_PER_TOKEN_AND_WIDTH * batch * seq_len
+                        * self.cfg.hidden_size, self.memory_bytes))
 
     def _experts(self, p, x):
         """``(shared(x) + the held experts' part, counters (3,), the

@@ -2,9 +2,12 @@
 forward and backward: the float32 scores of a (query tile, key tile) pair
 live in VMEM and never reach HBM.
 
-The whole key and value sequence of one key-value head (S x D in the
-compute dtype, 2 MB each at 8192 x 128) stays resident in VMEM while the
-query heads that share it go by, one query tile to a grid step. A step
+The head size is a multiple of the 128 lanes, or 64: half a row, a block
+whose last dimension is the whole head (Mosaic takes it; the products then
+fill half of the MXU's depth, which is the head's own size and no fault of
+the tiling). The whole key and value sequence of one key-value head (S x D
+in the compute dtype, 2 MB each at 8192 x 128) stays resident in VMEM while
+the query heads that share it go by, one query tile to a grid step. A step
 walks the key tiles up to its own diagonal with a loop whose trip count is
 the query tile's index, so tiles above the diagonal are skipped, not
 masked; only the diagonal tile pays for a mask. Forward: running maximum
@@ -40,6 +43,8 @@ from distributedpytorch_tpu.utils.backend import pallas_interpret
 #: Rows of the log-sum-exp's and delta's blocks: a row vector is stored as
 #: the first of one sublane tile.
 _SUBLANES = 8
+#: Lanes of a row of VMEM.
+_LANES = 128
 #: What the kernels may use of the chip's 128 MiB of VMEM, and the part of
 #: it that one key-value head's resident blocks may take (the rest is the
 #: tiles' scores and the query-sized blocks).
@@ -76,11 +81,19 @@ def residual_bytes(b: int, s: int, hq: int, hkv: int, d: int,
                     + hq * _SUBLANES * jnp.dtype(LOSS_DTYPE).itemsize)
 
 
+def head_size_ok(d: int) -> bool:
+    """Head sizes the kernels take: a multiple of the lanes, or half a
+    row (64: a block as wide as the head)."""
+    return d % _LANES == 0 or d == _LANES // 2
+
+
 def fits_vmem(s: int, d: int) -> bool:
     """Whether one key-value head's k, v, dk and dv at sequence length
     ``s`` and head size ``d`` can stay resident while the kernels run
-    (up to 16,384 positions at head 128)."""
-    return s * d * _RESIDENT_BYTES_PER_ELEMENT <= _RESIDENT_LIMIT
+    (up to 16,384 positions at head 128, and at head 64: a row of VMEM is
+    128 lanes wide, so half a row costs a whole one)."""
+    lanes = -(-d // _LANES) * _LANES
+    return s * lanes * _RESIDENT_BYTES_PER_ELEMENT <= _RESIDENT_LIMIT
 
 
 def _rows(j, tile):
@@ -123,7 +136,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     l = l_ref[...]
     o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
     lse = m_ref[...] + jnp.log(l)  # (tile, 1) -> a lane-dense row
-    lse_ref[...] = jnp.broadcast_to(lse, (tile, 128)).T[:_SUBLANES]
+    lse_ref[...] = jnp.broadcast_to(lse, (tile, _LANES)).T[:_SUBLANES]
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,

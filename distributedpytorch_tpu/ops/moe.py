@@ -7,8 +7,10 @@ taken from the unbiased scores, normalised over the chosen and scaled).
 ``held_experts`` is told which experts this chip holds
 (``first_held``, and as many as its weights have) and computes, for the
 (token, slot) choices that fall on them, ``gate * W_down relu(W_up x)^2``
-added up per token. What the other experts would add is left out: on one
-chip there is no exchange, and no code stands in for the absent chips.
+(or, for experts with a gate matrix, ``gate * W_down (silu(W_gate x) *
+W_up x)``: the same loop, one product more a tile) added up per token.
+What the other experts would add is left out: on one chip there is no
+exchange, and no code stands in for the absent chips.
 
 No token is dropped and there is no capacity limit. The chosen rows are
 sorted by expert and cut into tiles of ``tile`` rows, each tile of one
@@ -141,12 +143,33 @@ def _act(h):
     return r * r
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held(x, gates, idx, w_up, w_down, first_held, tile):
-    return _held_fwd(x, gates, idx, w_up, w_down, first_held, tile)[0]
+def _expert_forward(xt, e, w_up, w_gate):
+    """What expert ``e`` holds of tile ``xt`` before its down product, in
+    float32: ``(activation, (up's result, gate's result or None))``:
+    ``relu(W_up x)^2``, or with a gate matrix ``silu(W_gate x) * W_up x``."""
+    h = jnp.dot(xt, w_up[e], preferred_element_type=WGRAD_DTYPE)
+    if w_gate is None:
+        return _act(h), (h, None)
+    a = jnp.dot(xt, w_gate[e], preferred_element_type=WGRAD_DTYPE)
+    return jax.nn.silu(a) * h, (h, a)
 
 
-def _held_fwd(x, gates, idx, w_up, w_down, first_held, tile):
+def _expert_backward(da, pre):
+    """The activation's gradient ``da`` taken back through it: ``(d up's
+    result, d gate's result or None)`` in float32."""
+    h, a = pre
+    if a is None:
+        return da * 2.0 * jnp.maximum(h, 0.0), None
+    s = jax.nn.sigmoid(a)
+    return da * (a * s), da * h * (s * (1.0 + a * (1.0 - s)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _held(x, gates, idx, w_up, w_down, w_gate, first_held, tile):
+    return _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile)[0]
+
+
+def _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile):
     tokens, top_k = idx.shape
     n_held = w_up.shape[0]
     plan = _plan(idx, first_held, n_held, tile)
@@ -155,8 +178,8 @@ def _held_fwd(x, gates, idx, w_up, w_down, first_held, tile):
     def body(j, rows):
         e, tok, gate = _tile(j, plan, gates_flat, top_k, tile, tokens)
         xt = x.at[tok].get(mode="fill", fill_value=0)
-        h = jnp.dot(xt, w_up[e], preferred_element_type=WGRAD_DTYPE)
-        y = jnp.dot(_act(h).astype(x.dtype), w_down[e],
+        act, _ = _expert_forward(xt, e, w_up, w_gate)
+        y = jnp.dot(act.astype(x.dtype), w_down[e],
                     preferred_element_type=WGRAD_DTYPE)
         return lax.dynamic_update_slice(
             rows, (y * gate[:, None]).astype(x.dtype), (j * tile, 0))
@@ -168,11 +191,11 @@ def _held_fwd(x, gates, idx, w_up, w_down, first_held, tile):
     counters = jnp.stack([jnp.sum(plan[1]), n_tiles * tile,
                           jnp.max(plan[1])]).astype(WGRAD_DTYPE)
     return ((_combine(rows, plan[5]).astype(x.dtype), counters),
-            (x, gates, idx, w_up, w_down))
+            (x, gates, idx, w_up, w_down, w_gate))
 
 
 def _held_bwd(first_held, tile, saved, cts):
-    x, gates, idx, w_up, w_down = saved
+    x, gates, idx, w_up, w_down, w_gate = saved
     dy = cts[0]
     tokens, top_k = idx.shape
     n_held = w_up.shape[0]
@@ -181,52 +204,62 @@ def _held_bwd(first_held, tile, saved, cts):
     rows = capacity(tokens, top_k, n_held, tile)
 
     def body(j, carry):
-        dx_rows, dgate_rows, dw_up, dw_down = carry
+        dx_rows, dgate_rows, dw_up, dw_down, dw_gate = carry
         e, tok, gate = _tile(j, plan, gates_flat, top_k, tile, tokens)
         xt = x.at[tok].get(mode="fill", fill_value=0)
         dyt = dy.at[tok].get(mode="fill", fill_value=0)
-        h = jnp.dot(xt, w_up[e], preferred_element_type=WGRAD_DTYPE)
-        a = _act(h)
+        a, pre = _expert_forward(xt, e, w_up, w_gate)
         # d(gate * a W_down) : through a, through W_down, through the gate
         da = jnp.dot(dyt, w_down[e].T, preferred_element_type=WGRAD_DTYPE)
         dg = jnp.sum(a * da, axis=-1)
-        dh = (da * gate[:, None] * 2.0 * jnp.maximum(h, 0.0)).astype(x.dtype)
+        dh, dgated = _expert_backward(da * gate[:, None], pre)
+        dh = dh.astype(x.dtype)
         ag = (a * gate[:, None]).astype(x.dtype)
         dw_down = dw_down.at[e].add(
             jnp.dot(ag.T, dyt, preferred_element_type=WGRAD_DTYPE))
         dw_up = dw_up.at[e].add(
             jnp.dot(xt.T, dh, preferred_element_type=WGRAD_DTYPE))
         dxt = jnp.dot(dh, w_up[e].T, preferred_element_type=WGRAD_DTYPE)
+        if w_gate is not None:
+            dgated = dgated.astype(x.dtype)
+            dw_gate = dw_gate.at[e].add(
+                jnp.dot(xt.T, dgated, preferred_element_type=WGRAD_DTYPE))
+            dxt = dxt + jnp.dot(dgated, w_gate[e].T,
+                                preferred_element_type=WGRAD_DTYPE)
         dx_rows = lax.dynamic_update_slice(
             dx_rows, dxt.astype(x.dtype), (j * tile, 0))
         dgate_rows = lax.dynamic_update_slice(dgate_rows, dg, (j * tile,))
-        return dx_rows, dgate_rows, dw_up, dw_down
+        return dx_rows, dgate_rows, dw_up, dw_down, dw_gate
 
     carry = (jnp.zeros((rows, x.shape[1]), x.dtype),
              jnp.zeros((rows,), WGRAD_DTYPE),
              jnp.zeros(w_up.shape, WGRAD_DTYPE),
-             jnp.zeros(w_down.shape, WGRAD_DTYPE))
-    dx_rows, dgate_rows, dw_up, dw_down = lax.fori_loop(
+             jnp.zeros(w_down.shape, WGRAD_DTYPE),
+             None if w_gate is None else jnp.zeros(w_gate.shape, WGRAD_DTYPE))
+    dx_rows, dgate_rows, dw_up, dw_down, dw_gate = lax.fori_loop(
         0, plan[4][-1], body, carry)
     dgate = dgate_rows.at[plan[5]].get(mode="fill", fill_value=0)
     return (_combine(dx_rows, plan[5]).astype(x.dtype), dgate.astype(gates.dtype),
-            None, dw_up.astype(w_up.dtype), dw_down.astype(w_down.dtype))
+            None, dw_up.astype(w_up.dtype), dw_down.astype(w_down.dtype),
+            None if w_gate is None else dw_gate.astype(w_gate.dtype))
 
 
 _held.defvjp(_held_fwd, _held_bwd)
 
 
 def held_experts(x, idx, gates, w_up, w_down, experts_total: int,
-                 first_held: int):
+                 first_held: int, w_gate=None):
     """``(y (T, D), counters (3,))``: what the experts
     ``first_held .. first_held + n_held - 1`` of ``experts_total`` add for
     tokens ``x`` (T, D) routed by ``idx`` and ``gates`` (T, k). ``w_up``
     (n_held, D, F) and ``w_down`` (n_held, F, D) are the held experts'
-    weights in the compute dtype. The counters are ``COUNTERS``."""
+    weights in the compute dtype; with ``w_gate`` (n_held, D, F) an expert
+    is gated, ``W_down (silu(W_gate x) * W_up x)``, else
+    ``W_down relu(W_up x)^2``. The counters are ``COUNTERS``."""
     if not 0 <= first_held <= experts_total - w_up.shape[0]:
         raise ValueError(
             f"experts {first_held}..{first_held + w_up.shape[0] - 1} are not "
             f"among {experts_total}")
     tile = tile_rows(x.shape[0], idx.shape[1], experts_total)
-    y, counters = _held(x, gates, idx, w_up, w_down, first_held, tile)
+    y, counters = _held(x, gates, idx, w_up, w_down, w_gate, first_held, tile)
     return y, lax.stop_gradient(counters)

@@ -1,14 +1,16 @@
-"""Sequence mixers and the token loss: RMSNorm, rotary positions, causal
+"""Sequence mixers and the token loss: RMSNorm (over the hidden size, or
+over each head of q and k: the last axis), rotary positions, causal
 grouped-query attention, Mamba-2's state-space dual (SSD) as a chunked
-scan, and next-token cross-entropy in token blocks.
+scan, LFM2's gated short convolution, and next-token cross-entropy in
+token blocks (the head a matrix of its own, or the embedding's).
 
 Attention runs on one of two paths, chosen by ``attention_path`` from what
 the code observes (platform and shapes), never by a user: on a TPU, with a
-head size that fills the lanes and a length that a tile divides, the
-fused kernel of ``ops/attention_pallas.py`` (forward and its own backward;
-scores stay in VMEM); everywhere else (the CPU, toy sizes, a ragged
-length) ``blocked_attention``, query blocks in plain XLA, which is also
-the kernel's oracle. Everything else here is plain XLA differentiated by
+head size that fills the lanes (or half of them: 64) and a length that a
+tile divides, the fused kernel of ``ops/attention_pallas.py`` (forward and
+its own backward; scores stay in VMEM); everywhere else (the CPU, toy
+sizes, a ragged length) ``blocked_attention``, query blocks in plain XLA,
+which is also the kernel's oracle. Everything else here is plain XLA differentiated by
 jax: the chunked scan's backward is the chunked scan's transpose, the
 blocked attention's scores are recomputed block by block
 (``jax.checkpoint``), and so are the logits. Matrix products take
@@ -84,13 +86,76 @@ def rotary(x, theta: float):
     return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(x.dtype)
 
 
+def _shifted(x, back: int):
+    """``x`` (B, S, D) moved ``back`` positions later along S (earlier for
+    a negative ``back``), zeros moved in."""
+    if back == 0:
+        return x
+    s = x.shape[1]
+    if back > 0:
+        return jnp.pad(x, [(0, 0), (back, 0), (0, 0)])[:, :s]
+    return jnp.pad(x, [(0, 0), (0, -back), (0, 0)])[:, -back:]
+
+
+@jax.custom_vjp
+def short_conv(bcx, kernel):
+    """LFM2's gated short convolution, the part between its two
+    projections: ``bcx`` (B, S, 3 x D) is ``B``, ``C`` and ``x`` side by
+    side, ``kernel`` (taps, D) a causal depthwise filter (tap j sees the
+    input taps - 1 - j positions back; no bias, no activation). Returns
+    ``C * conv(B * x)`` (B, S, D): shifted products, no recurrence. The
+    arithmetic is float32 between a read and a write in ``bcx``'s dtype,
+    and the backward pass (its own: jax's would keep five float32 passes
+    as wide as ``bcx`` for it) reads ``bcx`` and the gradient alone."""
+    return _short_conv_fwd(bcx, kernel)[0]
+
+
+def _short_conv_parts(bcx, kernel):
+    # split, then widen: a float32 copy of all of ``bcx`` would be a
+    # buffer of its own, twice its size
+    b, c, x = (t.astype(SCAN_DTYPE) for t in jnp.split(bcx, 3, axis=-1))
+    w = kernel.astype(SCAN_DTYPE)
+    taps = w.shape[0]
+    bx = b * x
+    conv = sum(_shifted(bx, taps - 1 - j) * w[j] for j in range(taps))
+    return b, c, x, bx, conv, w
+
+
+def _short_conv_fwd(bcx, kernel):
+    _, c, _, _, conv, _ = _short_conv_parts(bcx, kernel)
+    return (c * conv).astype(bcx.dtype), (bcx, kernel)
+
+
+def _short_conv_bwd(saved, dy):
+    bcx, kernel = saved
+    # behind a barrier: where a checkpoint keeps ``bcx``, the compiler
+    # would else keep the forward pass's float32 parts beside it (the
+    # same expressions) from there to here
+    b, c, x, bx, conv, w = _short_conv_parts(
+        lax.optimization_barrier(bcx), kernel)
+    taps = w.shape[0]
+    dconv = dy.astype(SCAN_DTYPE) * c
+    # conv[t] = sum_j w[j] bx[t - (taps-1-j)], so bx[t] is read by the
+    # outputs taps-1-j positions later
+    dbx = sum(_shifted(dconv, -(taps - 1 - j)) * w[j] for j in range(taps))
+    dkernel = jnp.stack([jnp.sum(_shifted(bx, taps - 1 - j) * dconv, axis=(0, 1))
+                         for j in range(taps)])
+    dbcx = jnp.concatenate([dbx * x, dy.astype(SCAN_DTYPE) * conv, dbx * b],
+                           axis=-1)
+    return dbcx.astype(bcx.dtype), dkernel.astype(kernel.dtype)
+
+
+short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
 def attention_path(platform: str, s: int, d: int, hq: int, hkv: int) -> int:
     """The fused kernel's tile where attention of these shapes runs on it,
     0 where it runs as ``blocked_attention``: the kernel on a TPU when the
-    head size is a multiple of the 128 lanes, a tile divides the length,
-    the query heads divide evenly over the key-value heads and one
-    key-value head's sequence fits the kernel's share of VMEM."""
-    if (platform != "tpu" or d % 128 or hq % hkv
+    head size is a multiple of the 128 lanes or half of them (64: a block
+    as wide as the head), a tile divides the length, the query heads
+    divide evenly over the key-value heads and one key-value head's
+    sequence fits the kernel's share of VMEM."""
+    if (platform != "tpu" or not attention_pallas.head_size_ok(d) or hq % hkv
             or not attention_pallas.fits_vmem(s, d)):
         return 0
     return next((t for t in ATTENTION_TILES if s % t == 0), 0)
@@ -230,12 +295,14 @@ def _carry_weights(total, nc: int):
     return jnp.exp(_segsum(total)[..., :nc, 1:])
 
 
-def next_token_loss(h, head, tokens, block: int = LOSS_BLOCK):
+def next_token_loss(h, head, tokens, block: int = LOSS_BLOCK,
+                    tied: bool = False):
     """Mean next-token cross-entropy: position t of every sequence
     predicts token t + 1, the last position predicts nothing. ``h``
     (B, S, D) after the final norm, ``head`` (D, V) as the optimiser holds
-    it, ``tokens`` (B, S). The logits of one block of tokens exist at a
-    time, in float32, and are recomputed in the backward pass."""
+    it, or with ``tied`` the embedding's own (V, D) matrix, ``tokens``
+    (B, S). The logits of one block of tokens exist at a time, in float32,
+    and are recomputed in the backward pass."""
     bsz, s, d = h.shape
     targets = jnp.roll(tokens, -1, axis=1).reshape(-1)
     weight = jnp.broadcast_to(jnp.arange(s) < s - 1, (bsz, s)).reshape(-1)
@@ -245,11 +312,12 @@ def next_token_loss(h, head, tokens, block: int = LOSS_BLOCK):
     pad = -t % block
     h = jnp.pad(h.reshape(t, d), [(0, pad), (0, 0)])
     targets, weight = jnp.pad(targets, (0, pad)), jnp.pad(weight, (0, pad))
+    spec = "td,vd->tv" if tied else "td,dv->tv"
 
     @jax.checkpoint
     def one_block(total, xs):
         hb, tb, wb = xs
-        logits = jnp.einsum("td,dv->tv", hb, head.astype(hb.dtype),
+        logits = jnp.einsum(spec, hb, head.astype(hb.dtype),
                             preferred_element_type=LOSS_DTYPE)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]

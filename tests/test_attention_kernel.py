@@ -43,7 +43,10 @@ def gap(a, b):
 # (S, query heads a key-value head, D, tile): every group holds more than
 # one query head, so dK and dV are sums over heads as well as query tiles
 SHAPES = [(256, 2, 128, 128), (512, 4, 128, 256), (384, 3, 128, 128),
-          (256, 16, 128, 256), (256, 2, 256, 128)]
+          (256, 16, 128, 256), (256, 2, 256, 128),
+          # head size 64, half the lanes (the lfm2 model's: 4 query heads a
+          # key-value head): a block as wide as the head
+          (256, 4, 64, 128), (512, 2, 64, 256), (384, 4, 64, 128)]
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -82,7 +85,10 @@ def test_kernel_is_full_attention_and_the_blocked_path(s, rep, d, tile, dtype):
     ("tpu", 8192 + 512, 128, 32, 2, 512),           # a smaller tile divides it
     ("tpu", 8192 + 72, 128, 32, 2, 0),              # a ragged length
     ("tpu", 72, 16, 4, 2, 0),                       # the rehearsal's toy head
-    ("tpu", 8192, 64, 32, 2, 0),                    # half the lanes
+    ("tpu", 8192, 64, 32, 8, 1024),                 # half the lanes: the kernel
+    ("tpu", 16384 + 256, 64, 32, 8, 0),             # ... whose rows cost whole ones
+    ("tpu", 8192, 32, 32, 8, 0),                    # a quarter of the lanes
+    ("tpu", 8192, 96, 32, 8, 0),                    # no whole or half row
     ("tpu", 65536, 128, 32, 2, 0),                  # a head VMEM cannot hold
     ("cpu", 8192, 128, 32, 2, 0),
 ])
@@ -149,8 +155,24 @@ def test_kept_residuals_leave_one_forward_kernel(monkeypatch, memory, forwards,
     assert defs.KEPT_ACTIVATION_BYTES.name == "dpt_kept_activation_bytes"
 
 
+@pytest.mark.parametrize("d,hq,hkv", [(128, 32, 2), (64, 32, 8)])
+def test_residual_bytes_and_vmem_follow_the_head_size(d, hq, hkv):
+    """The kept residuals count the head's own width; VMEM counts a row
+    of 128 lanes for a head of 64."""
+    item = 2
+    assert attention_pallas.residual_bytes(2, 8192, hq, hkv, d, item) == (
+        2 * 8192 * ((2 * hq + 2 * hkv) * d * item + hq * 8 * 4))
+    assert attention_pallas.fits_vmem(16384, d)
+    assert not attention_pallas.fits_vmem(16384 + 256, d)
+
+
 def test_gauge_counts_the_blocks_that_take_the_kernel():
+    from distributedpytorch_tpu.models.lfm2 import Lfm2
     from distributedpytorch_tpu.models.twotower import TwoTower, twotower_config
+
+    lfm2 = Lfm2(dtype=jnp.bfloat16)
+    assert lfm2.attention_kernel_blocks("tpu", 8192) == 1
+    assert lfm2.attention_kernel_blocks("cpu", 8192) == 0
 
     model = TwoTower(twotower_config(None), dtype=jnp.bfloat16)
     assert model.attention_kernel_blocks("cpu", 8192) == 0

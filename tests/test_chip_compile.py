@@ -137,29 +137,32 @@ def test_fused_loss_value_and_grad_compiles(mosaic):
     assert _has_kernel(compiled)
 
 
-@pytest.mark.parametrize("s,dtype", [
-    (8192, jnp.bfloat16),   # the token cell's block: 2 x 8192, 32 / 2 heads
-    (8192 + 512, jnp.bfloat16),  # a length only the 512 tile divides
-    (16384, jnp.float32),   # the longest and widest attention_path admits
+@pytest.mark.parametrize("s,dtype,d,hkv", [
+    (8192, jnp.bfloat16, 128, 2),   # the token cell's block: 2 x 8192, 32 / 2 heads
+    (8192 + 512, jnp.bfloat16, 128, 2),  # a length only the 512 tile divides
+    (16384, jnp.float32, 128, 2),   # the longest and widest attention_path admits
+    (8192, jnp.bfloat16, 64, 8),    # the lfm2 cell's layer: 32 / 8 heads of 64
+    (16384, jnp.float32, 64, 8),    # the longest at half the lanes
 ])
-def test_causal_attention_value_and_grads_compile(mosaic, s, dtype):
+def test_causal_attention_value_and_grads_compile(mosaic, s, dtype, d, hkv):
     """Forward and backward kernels at the tile ``attention_path`` picks,
     with the keys, values and their gradients of one head resident in
     VMEM: Mosaic takes them, and no (S x S) tensor is left in HBM."""
     from distributedpytorch_tpu.ops import attention_pallas
     from distributedpytorch_tpu.ops import sequence as seq
 
-    tile = seq.attention_path("tpu", s, 128, 32, 2)
+    tile = seq.attention_path("tpu", s, d, 32, hkv)
     assert tile
-    q, kv = mosaic((2, s, 32, 128), dtype), mosaic((2, s, 2, 128), dtype)
+    q, kv = mosaic((2, s, 32, d), dtype), mosaic((2, s, hkv, d), dtype)
     compiled = _compile(jax.grad(
         lambda q, k, v: jnp.sum(attention_pallas.causal_attention(
             q, k, v, tile, interpret=False).astype(jnp.float32)),
         argnums=(0, 1, 2)), q, kv, kv)
     assert compiled.as_text().count("tpu_custom_call") >= 2
-    # q-sized tensors only: the scores of one head pair alone would be
-    # s * s * 4 bytes
-    assert compiled.memory_analysis().temp_size_in_bytes < 6 * q.size * 4
+    # q-sized tensors only (a head of 64 is stored 128 lanes wide): the
+    # scores of every head pair would be 32 x s * s * 4 bytes a sequence
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        6 * q.size * 4 * max(d, 128) // d)
 
 
 # -- the b4 640×960 bf16 s2d-2 train step and one serve bucket -------------

@@ -351,7 +351,8 @@ class TestMilesialS2D:
         gradient's equality with the stored maximum missed). The pool now
         picks one winner from the operand it is given: every leaf's norm is
         within 5e-3 of the pixel path's, and the difference within 2e-2 of
-        the norm (the readings of benchmark/tools/s2d_pool_gradient.py)."""
+        the norm (the readings of PERF.md §6, PRs 24 and 26; the tool that
+        took them went with the fault, PR 33)."""
         widths, hw = (8, 16, 32, 64), (32, 48)
         x = jax.random.uniform(jax.random.key(0), (2, *hw, 3))
         t = (jax.random.uniform(jax.random.key(1), (2, *hw, 1)) > 0.5).astype(
