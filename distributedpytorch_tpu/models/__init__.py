@@ -45,7 +45,9 @@ class ModelEntry:
     names what ``loss`` counts; ``attention_kernel_blocks(model, config)``
     is how many attention blocks take the fused kernel on this backend;
     ``kept_activation_bytes(model, config)`` is how many bytes of named
-    activations a step keeps across its blocks' backward passes."""
+    activations a step keeps across its blocks' backward passes;
+    ``moe_wgrad_kernel_layers(model, config)`` is how many sparse expert
+    layers take the grouped weight-gradient kernel on this backend."""
 
     build: Callable
     loss: Callable
@@ -55,6 +57,7 @@ class ModelEntry:
     counters: Callable = lambda model: ()
     attention_kernel_blocks: Callable = lambda model, config: 0
     kept_activation_bytes: Callable = lambda model, config: 0
+    moe_wgrad_kernel_layers: Callable = lambda model, config: 0
     adam_b2: float = 0.999
     # the reference's ``(batch_size * loss).backward()`` quirk
     # (TrainConfig.faithful_loss_scaling) belongs to its image models
@@ -159,6 +162,13 @@ def _token_kept_bytes(model, config):
         config.batch_size, config.seq_len, jax.default_backend()))
 
 
+def _token_wgrad_layers(model, config):
+    import jax
+
+    return model.moe_wgrad_kernel_layers(
+        jax.default_backend(), config.batch_size * config.seq_len)
+
+
 #: What the token models share: a batch of packed tokens, mean next-token
 #: cross-entropy with counters and the routers' own biases beside it,
 #: trained on one device, not served.
@@ -166,7 +176,8 @@ _TOKEN_MODEL = ModelEntry(
     build=None, loss=_token_loss, batch=TOKEN_BATCH, evaluate=_token_eval,
     dataset=_token_dataset, counters=_token_counters,
     attention_kernel_blocks=_token_kernel_blocks,
-    kept_activation_bytes=_token_kept_bytes, adam_b2=0.95,
+    kept_activation_bytes=_token_kept_bytes,
+    moe_wgrad_kernel_layers=_token_wgrad_layers, adam_b2=0.95,
     batch_scaled_backward=False, servable=False, single_device_only=True)
 
 MODELS = {

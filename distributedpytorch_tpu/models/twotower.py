@@ -282,6 +282,16 @@ class TwoTower:
                                   c.num_attention_heads, c.num_key_value_heads)
         return c.hybrid_override_pattern.count("*") if tile else 0
 
+    def moe_wgrad_kernel_layers(self, platform: str, tokens: int) -> int:
+        """How many of the model's expert blocks hand their experts'
+        weight gradients to the grouped kernel for a step of ``tokens``
+        tokens on ``platform`` (0: the plain loop over tiles)."""
+        c = self.cfg
+        tile = moe.tile_rows(tokens, c.num_experts_per_tok, c.experts_total)
+        kernel = moe.wgrad_path(platform, c.hidden_size,
+                                c.moe_intermediate_size, tile)
+        return c.hybrid_override_pattern.count("E") if kernel else 0
+
     def named_activation_bytes(self, batch: int, seq_len: int,
                                platform: str) -> Tuple[int, ...]:
         """Bytes of ``KEPT_ACTIVATIONS`` in each block, for one step of

@@ -78,6 +78,13 @@ ATTENTION_KERNEL_BLOCKS = REGISTRY.gauge(
     "dpt_attention_kernel_blocks",
     "Attention blocks of the model whose shapes take the fused kernel "
     "(0: all run as blocked XLA)")
+# -- the held experts' weight gradients (ops/moe.py): which path the grouped
+#    product over the sorted rows took, decided from platform and shapes
+#    (ops/moe.wgrad_path) and set once when the Trainer is built ----------
+MOE_WGRAD_KERNEL_LAYERS = REGISTRY.gauge(
+    "dpt_moe_wgrad_kernel_layers",
+    "Sparse expert layers of the model whose weight gradients take the "
+    "grouped_wgrad kernel (0: all run the plain loop over tiles)")
 # -- the token model's recomputation (models/twotower.py): what each
 #    block's ``jax.checkpoint`` keeps besides the block's input, decided
 #    from shapes and the device's memory (``twotower.kept_budget``) and set once

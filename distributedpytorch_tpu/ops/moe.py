@@ -20,7 +20,18 @@ routing, whatever the imbalance. A tile's rows are gathered from the
 tokens, its results written side by side into a buffer sized for the
 worst routing, and each token then gathers its choices' rows from there:
 gathers throughout, never a scatter into the tokens. Such a loop cannot be differentiated by
-jax, so the layer carries its own backward pass, the same loop again.
+jax, so the layer carries its own backward pass: the same tiles again, in
+chunks of as many tiles as the layer has tokens (``chunk_tiles``). A
+chunk's tile loop computes everything but the experts' weight gradients
+(the forward again, the gradients of the tile's rows and gates) and
+writes their operands side by side in the tiles' order; the weight
+gradients of the chunk are then one grouped product over those sorted
+rows a matrix, in which an expert's float32 slab is written once for all
+its consecutive tiles, not once a tile. ``wgrad_path`` sends that product,
+from what the code observes (platform and shapes), to the kernel of
+``ops/moe_pallas.py`` (``grouped_wgrad``: the slab stays in VMEM over the
+expert's tiles) or to the plain loop of ``_grouped_product`` (the CPU, toy
+widths), which adds tile by tile into the slab and is the kernel's oracle.
 Three counters say what the routing cost: rows routed to held experts,
 rows the loop multiplied (padding included), rows of the fullest expert.
 
@@ -39,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributedpytorch_tpu.ops import moe_pallas
 from distributedpytorch_tpu.ops.precision import ROUTER_DTYPE, WGRAD_DTYPE
 
 #: Counter names, in the order ``held_experts`` returns them.
@@ -112,6 +124,24 @@ def capacity(tokens: int, top_k: int, n_held: int, tile: int) -> int:
     expert, and a padded last tile for each. Sized for the worst routing,
     so that no row is ever dropped; the loop touches only the tiles in use."""
     return tokens * min(top_k, n_held) + n_held * tile
+
+
+def chunk_tiles(tokens: int, tile: int) -> int:
+    """Tiles to a chunk of the backward pass: as many as the layer has
+    tokens. The weight gradients' operands are buffered a chunk at a time
+    (the tiles' whole buffer, sized for the worst routing, would be
+    ``min(top_k, n_held)`` times as large); an expert that spans two
+    chunks has its slab written twice."""
+    return max(1, tokens // tile)
+
+
+def wgrad_path(platform: str, d: int, f: int, tile: int) -> bool:
+    """Whether the held experts' weight gradients of hidden size ``d``,
+    expert width ``f`` and ``tile`` rows a tile take the kernel
+    (``moe_pallas.grouped_wgrad``): on a TPU at shapes the kernel takes.
+    Everywhere else (the CPU, toy widths) ``_grouped_product``'s plain
+    loop."""
+    return platform == "tpu" and moe_pallas.shapes_ok(d, f, tile)
 
 
 def _tile(j, plan, gates_flat, top_k: int, tile: int, tokens: int):
@@ -194,17 +224,39 @@ def _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile):
             (x, gates, idx, w_up, w_down, w_gate))
 
 
+def _grouped_product(lhs, rhs, acc, tile_expert, used, tile: int):
+    """``acc[e] += lhs[rows of e].T @ rhs[rows of e]`` over the first
+    ``used`` tiles of ``tile`` rows, tile ``t`` of expert
+    ``tile_expert[t]``: the plain form of ``moe_pallas.grouped_wgrad``, a
+    read and a write of the expert's slab every tile."""
+    def body(t, acc):
+        rows = lax.dynamic_slice_in_dim(lhs, t * tile, tile)
+        other = lax.dynamic_slice_in_dim(rhs, t * tile, tile)
+        return acc.at[tile_expert[t]].add(
+            jnp.dot(rows.T, other, preferred_element_type=WGRAD_DTYPE))
+
+    return lax.fori_loop(0, used, body, acc)
+
+
 def _held_bwd(first_held, tile, saved, cts):
     x, gates, idx, w_up, w_down, w_gate = saved
     dy = cts[0]
     tokens, top_k = idx.shape
-    n_held = w_up.shape[0]
+    n_held, d, f = w_up.shape
     plan = _plan(idx, first_held, n_held, tile)
+    tile_starts, tile_ends = plan[3], plan[4]
     gates_flat = gates.reshape(-1)
     rows = capacity(tokens, top_k, n_held, tile)
+    chunk = chunk_tiles(tokens, tile)
+    n_tiles = tile_ends[-1]
+    kernel = wgrad_path(jax.default_backend(), d, f, tile)
 
-    def body(j, carry):
-        dx_rows, dgate_rows, dw_up, dw_down, dw_gate = carry
+    # an operand as wide as the experts lies (f, rows) where the kernel
+    # wants it so (moe_pallas.rows_last): the order XLA gives its tiles
+    turned = kernel and moe_pallas.rows_last(f)
+
+    def tile_body(j, carry):
+        dx_rows, dgate_rows, hidden, wide = carry
         e, tok, gate = _tile(j, plan, gates_flat, top_k, tile, tokens)
         xt = x.at[tok].get(mode="fill", fill_value=0)
         dyt = dy.at[tok].get(mode="fill", fill_value=0)
@@ -215,33 +267,80 @@ def _held_bwd(first_held, tile, saved, cts):
         dh, dgated = _expert_backward(da * gate[:, None], pre)
         dh = dh.astype(x.dtype)
         ag = (a * gate[:, None]).astype(x.dtype)
-        dw_down = dw_down.at[e].add(
-            jnp.dot(ag.T, dyt, preferred_element_type=WGRAD_DTYPE))
-        dw_up = dw_up.at[e].add(
-            jnp.dot(xt.T, dh, preferred_element_type=WGRAD_DTYPE))
         dxt = jnp.dot(dh, w_up[e].T, preferred_element_type=WGRAD_DTYPE)
         if w_gate is not None:
             dgated = dgated.astype(x.dtype)
-            dw_gate = dw_gate.at[e].add(
-                jnp.dot(xt.T, dgated, preferred_element_type=WGRAD_DTYPE))
             dxt = dxt + jnp.dot(dgated, w_gate[e].T,
                                 preferred_element_type=WGRAD_DTYPE)
+        # what the weight gradients multiply, side by side in the tiles'
+        # order, for ``wgrads``
+        at = (j % chunk) * tile
+        hidden = tuple(lax.dynamic_update_slice(buffer, rows_, (at, 0))
+                       for buffer, rows_ in zip(hidden, (xt, dyt)))
+        wide = tuple(
+            lax.dynamic_update_slice(buffer, rows_.T, (0, at)) if turned
+            else lax.dynamic_update_slice(buffer, rows_, (at, 0))
+            for buffer, rows_ in zip(wide, (ag, dh, dgated)))
         dx_rows = lax.dynamic_update_slice(
             dx_rows, dxt.astype(x.dtype), (j * tile, 0))
         dgate_rows = lax.dynamic_update_slice(dgate_rows, dg, (j * tile,))
-        return dx_rows, dgate_rows, dw_up, dw_down, dw_gate
+        return dx_rows, dgate_rows, hidden, wide
 
-    carry = (jnp.zeros((rows, x.shape[1]), x.dtype),
-             jnp.zeros((rows,), WGRAD_DTYPE),
-             jnp.zeros(w_up.shape, WGRAD_DTYPE),
-             jnp.zeros(w_down.shape, WGRAD_DTYPE),
-             None if w_gate is None else jnp.zeros(w_gate.shape, WGRAD_DTYPE))
-    dx_rows, dgate_rows, dw_up, dw_down, dw_gate = lax.fori_loop(
-        0, plan[4][-1], body, carry)
+    def wgrads(first, used, hidden, wide, dws):
+        """The chunk's part of ``dw_up = x.T dh``, ``dw_down = ag.T dy``
+        and ``dw_gate = x.T dgated`` added into ``dws``."""
+        (xs, dys), (ags, dhs, *dgateds) = hidden, wide
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(tile_ends, first + jnp.arange(chunk), side="right"),
+            n_held - 1).astype(jnp.int32)
+        # (lhs, rhs, whether the hidden dimension is the result's first)
+        if turned:  # the turned operand first: every slab (f, d)
+            products = [(dhs, xs, False), (ags, dys, False)] + [
+                (dgated, xs, False) for dgated in dgateds]
+        else:
+            products = [(xs, dhs, True), (ags, dys, False)] + [
+                (xs, dgated, True) for dgated in dgateds]
+        if not kernel:
+            return tuple(
+                _grouped_product(lhs, rhs, dw, tile_expert, used, tile)
+                for (lhs, rhs, _), dw in zip(products, dws))
+        # the first tile's expert has tiles in the chunk before this one
+        schedule = moe_pallas.tile_schedule(
+            tile_expert, used, tile_starts[tile_expert[0]] < first)
+        return tuple(
+            moe_pallas.grouped_wgrad(lhs, rhs, dw, schedule, tile, hidden_is_k,
+                                     turned)
+            for (lhs, rhs, hidden_is_k), dw in zip(products, dws))
+
+    def chunk_body(c, carry):
+        dx_rows, dgate_rows, hidden, wide, dws = carry
+        first = c * chunk
+        used = jnp.minimum(n_tiles - first, chunk)
+        dx_rows, dgate_rows, hidden, wide = lax.fori_loop(
+            first, first + used, tile_body, (dx_rows, dgate_rows, hidden, wide))
+        return (dx_rows, dgate_rows, hidden, wide,
+                wgrads(first, used, hidden, wide, dws))
+
+    gated = w_gate is not None
+    # tiles past a chunk's last in use are never read: zeros once will do
+    hidden = (jnp.zeros((chunk * tile, d), x.dtype),) * 2
+    wide = (jnp.zeros((f, chunk * tile) if turned else (chunk * tile, f),
+                      x.dtype),) * (3 if gated else 2)
+    dws = tuple(jnp.zeros(w_down.shape if turned else w.shape, WGRAD_DTYPE)
+                for w in (w_up, w_down) + (w_gate,) * gated)
+    dx_rows, dgate_rows, _, _, dws = lax.fori_loop(
+        0, (n_tiles + chunk - 1) // chunk, chunk_body,
+        (jnp.zeros((rows, d), x.dtype), jnp.zeros((rows,), WGRAD_DTYPE),
+         hidden, wide, dws))
     dgate = dgate_rows.at[plan[5]].get(mode="fill", fill_value=0)
+    dw_up, dw_down, *dw_gate = dws
+    if turned:
+        # (f, d) slabs of the (d, f) matrices: XLA holds such a matrix d
+        # last as well (the dimension the lanes divide), so this moves nothing
+        dw_up, dw_gate = dw_up.swapaxes(1, 2), [g.swapaxes(1, 2) for g in dw_gate]
     return (_combine(dx_rows, plan[5]).astype(x.dtype), dgate.astype(gates.dtype),
             None, dw_up.astype(w_up.dtype), dw_down.astype(w_down.dtype),
-            None if w_gate is None else dw_gate.astype(w_gate.dtype))
+            dw_gate[0].astype(w_gate.dtype) if gated else None)
 
 
 _held.defvjp(_held_fwd, _held_bwd)

@@ -172,6 +172,11 @@ class Trainer:
         self.kept_activation_bytes = self.entry.kept_activation_bytes(
             self.model, config)
         obsm.KEPT_ACTIVATION_BYTES.set(self.kept_activation_bytes)
+        # and which path the held experts' weight gradients take
+        # (ops/moe.wgrad_path)
+        self.moe_wgrad_kernel_layers = self.entry.moe_wgrad_kernel_layers(
+            self.model, config)
+        obsm.MOE_WGRAD_KERNEL_LAYERS.set(self.moe_wgrad_kernel_layers)
         params, model_state = init_fn(
             self.rng, (config.image_size[1], config.image_size[0])
         )
@@ -940,7 +945,8 @@ class Trainer:
         logger.info(
             "Training %s: %d epochs, global batch %d, lr %.2e, %d train "
             "batches/shard, %d attention blocks on the fused kernel, %d bytes "
-            "of named activations kept a step",
+            "of named activations kept a step, %d expert layers' weight "
+            "gradients on the grouped kernel",
             cfg.train_method,
             cfg.epochs,
             self.strategy.global_batch_size,
@@ -948,6 +954,7 @@ class Trainer:
             len(self.train_loader),
             self.attention_kernel_blocks,
             self.kept_activation_bytes,
+            self.moe_wgrad_kernel_layers,
         )
         # whole-run capture only when no step range was asked for — the
         # two would race one another's start/stop on the same profiler

@@ -165,6 +165,39 @@ def test_causal_attention_value_and_grads_compile(mosaic, s, dtype, d, hkv):
         6 * q.size * 4 * max(d, 128) // d)
 
 
+@pytest.mark.parametrize("experts,d,f", [
+    (16, 2048, 1536),   # the lfm2 cell's experts: both orders of the result
+    (8, 2688, 1856),    # the first token cell's, 14.5 x 128 wide: turned
+])
+def test_grouped_wgrad_compiles_at_both_cells_widths(mosaic, experts, d, f):
+    """The held experts' weight-gradient kernel over one chunk of 64 tiles
+    of 256 rows, an expert's whole float32 slab resident in VMEM: Mosaic
+    takes every form ``ops/moe.py`` calls, in place (no second slab)."""
+    from distributedpytorch_tpu.ops import moe, moe_pallas
+
+    tile, tiles = 256, moe.chunk_tiles(2 * 8192, 256)
+    assert moe.wgrad_path("tpu", d, f, tile)
+    assert moe_pallas.hidden_block(d, f) == d
+    turned = moe_pallas.rows_last(f)
+    hidden = mosaic((tiles * tile, d), jnp.bfloat16)
+    wide = mosaic((f, tiles * tile) if turned else (tiles * tile, f), jnp.bfloat16)
+    scalars = (mosaic((tiles,), jnp.int32), mosaic((), jnp.int32),
+               mosaic((), jnp.bool_))
+    forms = [(wide, hidden, (experts, f, d), False)]
+    if not turned:
+        forms.append((hidden, wide, (experts, d, f), True))
+    for lhs, rhs, slab, hidden_is_k in forms:
+        compiled = _compile(
+            lambda l, r, a, e, n, c: moe_pallas.grouped_wgrad(
+                l, r, a, moe_pallas.tile_schedule(e, n, c), tile, hidden_is_k,
+                turned, interpret=False),
+            lhs, rhs, mosaic(slab, jnp.float32), *scalars, donate_argnums=(2,))
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= experts * d * f * 4
+        assert memory.temp_size_in_bytes < 1 << 20
+
+
 # -- the b4 640×960 bf16 s2d-2 train step and one serve bucket -------------
 
 
