@@ -147,7 +147,8 @@ def get_args():
                              "(2 on TPU, 0 elsewhere)")
     parser.add_argument("--model", "--model-arch", dest="model_arch",
                         type=str, default="unet",
-                        choices=["unet", "milesial", "twotower", "lfm2"],
+                        choices=["unet", "milesial", "twotower", "lfm2",
+                                 "smallthinker"],
                         help="Model (models/__init__.py holds the table): "
                              "the reference course UNet (7.76M params), the "
                              "original milesial/Pytorch-UNet (31M params, "
@@ -158,8 +159,14 @@ def get_args():
                              "share (788M params) of LFM2-24B-A2B's "
                              "config.json (gated short convolutions, "
                              "QK-normed attention, dense and sparse "
-                             "SwiGLU feed-forwards); both trained on "
-                             "packed token sequences (-t singleGPU only)")
+                             "SwiGLU feed-forwards), or 'smallthinker': one "
+                             "chip's share (657M params) of SmallThinker-"
+                             "21BA3B-Instruct's config.json (sliding-window "
+                             "attention with rotary and full attention "
+                             "without positions, a router that reads the "
+                             "layer's input, softmax-gated ReGLU experts); "
+                             "all three trained on packed token sequences "
+                             "(-t singleGPU only)")
     parser.add_argument("--seq-len", type=int, default=8192,
                         help="Tokens to a packed sequence of a token "
                              "model's batch (-b counts sequences)")

@@ -120,7 +120,7 @@ class TrainConfig:
     # build faster-compiling variants for tests.
     model_widths: Optional[Tuple[int, ...]] = None
     # A token model ("twotower", models/twotower.py; "lfm2", models/lfm2.py;
-    # models/__init__.py holds the table) is built at its published share;
+    # "smallthinker", models/smallthinker.py; models/__init__.py holds the table) is built at its published share;
     # a mapping of its size keys here shrinks it for tests and rehearsals
     # (as model_widths does a UNet).
     model_overrides: Optional[dict] = None

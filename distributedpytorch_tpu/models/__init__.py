@@ -194,6 +194,13 @@ MODELS = {
     # a dense or a sparse SwiGLU feed-forward in every layer, tied head
     "lfm2": dataclasses.replace(
         _TOKEN_MODEL, build=_token_builder("lfm2", "Lfm2", "lfm2_config")),
+    # SmallThinker-21BA3B-Instruct's config.json, one chip's share
+    # (models/smallthinker.py): sliding-window attention with rotary and
+    # full attention without positions in one stack, a router that reads
+    # the layer's input before attention, softmax-gated ReGLU experts
+    "smallthinker": dataclasses.replace(
+        _TOKEN_MODEL, build=_token_builder("smallthinker", "SmallThinker",
+                                           "smallthinker_config")),
 }
 
 

@@ -93,10 +93,20 @@ KEPT_ACTIVATION_BYTES = REGISTRY.gauge(
     "dpt_kept_activation_bytes",
     "Bytes of named activations a step keeps across its blocks' backward "
     "passes (0: each block's input alone is kept and all else recomputed)")
+# -- the attention path's own work (ops/sequence.attention_pairs): counted
+#    from the shapes, the tile and the window by the rule that sets the
+#    kernel's loop bounds (the blocked path counts its query blocks against
+#    the keys sliced for them), read back with the step's loss, per layer --
+ATTENTION_PAIRS_COMPUTED = REGISTRY.counter(
+    "dpt_attention_pairs_computed_total",
+    "(query, key) pairs the attention path multiplied, over the sequences "
+    "and the query heads, what its masks then threw away included",
+    ("block",))
 _STEP_COUNTERS = {
     "moe_rows_routed": MOE_ROWS_ROUTED.labels,
     "moe_rows_computed": MOE_ROWS_COMPUTED.labels,
     "moe_rows_max_expert": MOE_ROWS_MAX_EXPERT.labels,
+    "attention_pairs_computed": ATTENTION_PAIRS_COMPUTED.labels,
 }
 
 

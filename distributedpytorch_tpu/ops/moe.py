@@ -3,12 +3,14 @@ experts, and the part of the result that the experts HELD HERE give.
 
 ``route`` scores every token against every expert (sigmoid scores in
 float32, a per-expert selection bias added for the choice alone, the gate
-taken from the unbiased scores, normalised over the chosen and scaled).
-``held_experts`` is told which experts this chip holds
+taken from the unbiased scores, normalised over the chosen and scaled; or
+``score="softmax"``: the choice by logit + bias, the gates a softmax over
+the chosen logits). ``held_experts`` is told which experts this chip holds
 (``first_held``, and as many as its weights have) and computes, for the
 (token, slot) choices that fall on them, ``gate * W_down relu(W_up x)^2``
-(or, for experts with a gate matrix, ``gate * W_down (silu(W_gate x) *
-W_up x)``: the same loop, one product more a tile) added up per token.
+(or, for experts with a gate matrix, ``gate * W_down (act(W_gate x) *
+W_up x)`` with ``act`` SiLU or ReLU: the same loop, one product more a
+tile) added up per token.
 What the other experts would add is left out: on one chip there is no
 exchange, and no code stands in for the absent chips.
 
@@ -49,6 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from distributedpytorch_tpu.ops import moe_pallas
 from distributedpytorch_tpu.ops.precision import ROUTER_DTYPE, WGRAD_DTYPE
@@ -57,13 +60,61 @@ from distributedpytorch_tpu.ops.precision import ROUTER_DTYPE, WGRAD_DTYPE
 COUNTERS = ("rows_routed", "rows_computed", "rows_max_expert")
 
 
-def route(h, router, bias, top_k: int, norm_topk: bool, scale: float):
+#: How ``route`` turns logits into gates.
+SCORES = ("sigmoid", "softmax")
+#: What a gated expert's gate product goes through.
+GATE_ACTIVATIONS = ("silu", "relu")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _softmax(x, name):
+    """Softmax over the last axis whose backward pass reads its own
+    result alone, under ``name`` (a ``checkpoint_name``) where one is
+    given: a ``jax.checkpoint`` that keeps the name has nothing upstream
+    of it to compute again."""
+    return _softmax_fwd(x, name)[0]
+
+
+def _softmax_fwd(x, name):
+    y = jax.nn.softmax(x, axis=-1)
+    y = checkpoint_name(y, name) if name else y
+    return y, y
+
+
+def _softmax_bwd(name, y, dy):
+    return (y * (dy - jnp.sum(y * dy, axis=-1, keepdims=True)),)
+
+
+_softmax.defvjp(_softmax_fwd, _softmax_bwd)
+
+
+def route(h, router, bias, top_k: int, norm_topk: bool, scale: float,
+          score: str = "sigmoid", names=None):
     """``(expert ids (T, k) int32, gates (T, k) float32)`` of tokens
     ``h`` (T, D). The ids are no function of anything differentiable; the
-    gates carry the gradient to ``router`` and ``h``."""
+    gates carry the gradient to ``router`` and ``h``. ``score``:
+    ``sigmoid`` (each expert's own score, normalised over the chosen with
+    ``norm_topk``) or ``softmax`` (over the chosen logits: what a softmax
+    over all the experts renormalised over the chosen gives, so
+    ``norm_topk`` has to be set; ``names``, a pair of ``checkpoint_name``s
+    for the ids and the gates, given where they are made, so that a
+    ``jax.checkpoint`` that keeps both runs no part of the router again)."""
+    if score not in SCORES:
+        raise ValueError(f"unknown router score {score!r} (known: {SCORES})")
     logits = jnp.einsum("td,de->te", h.astype(ROUTER_DTYPE),
                         router.astype(ROUTER_DTYPE),
                         precision=lax.Precision.HIGHEST)
+    if score == "softmax":
+        if not norm_topk:
+            raise ValueError("a softmax over the chosen logits is normalised "
+                             "over the chosen: norm_topk has to be set")
+        _, idx = lax.top_k(
+            lax.stop_gradient(logits) + bias.astype(ROUTER_DTYPE), top_k)
+        idx, gate_name = idx.astype(jnp.int32), ""
+        if names is not None:
+            idx, gate_name = checkpoint_name(idx, names[0]), names[1]
+        gates = _softmax(jnp.take_along_axis(logits, idx, axis=-1), gate_name)
+        return idx, gates * scale
     scores = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(lax.stop_gradient(scores) + bias.astype(ROUTER_DTYPE),
                        top_k)
@@ -173,33 +224,39 @@ def _act(h):
     return r * r
 
 
-def _expert_forward(xt, e, w_up, w_gate):
+def _expert_forward(xt, e, w_up, w_gate, act):
     """What expert ``e`` holds of tile ``xt`` before its down product, in
     float32: ``(activation, (up's result, gate's result or None))``:
-    ``relu(W_up x)^2``, or with a gate matrix ``silu(W_gate x) * W_up x``."""
+    ``relu(W_up x)^2``, or with a gate matrix ``act(W_gate x) * W_up x``,
+    ``act`` SiLU or ReLU."""
     h = jnp.dot(xt, w_up[e], preferred_element_type=WGRAD_DTYPE)
     if w_gate is None:
         return _act(h), (h, None)
     a = jnp.dot(xt, w_gate[e], preferred_element_type=WGRAD_DTYPE)
+    if act == "relu":
+        return jnp.maximum(a, 0.0) * h, (h, a)
     return jax.nn.silu(a) * h, (h, a)
 
 
-def _expert_backward(da, pre):
+def _expert_backward(da, pre, act):
     """The activation's gradient ``da`` taken back through it: ``(d up's
     result, d gate's result or None)`` in float32."""
     h, a = pre
     if a is None:
         return da * 2.0 * jnp.maximum(h, 0.0), None
+    if act == "relu":
+        return da * jnp.maximum(a, 0.0), jnp.where(a > 0.0, da * h, 0.0)
     s = jax.nn.sigmoid(a)
     return da * (a * s), da * h * (s * (1.0 + a * (1.0 - s)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _held(x, gates, idx, w_up, w_down, w_gate, first_held, tile):
-    return _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _held(x, gates, idx, w_up, w_down, w_gate, first_held, tile, act):
+    return _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile,
+                     act)[0]
 
 
-def _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile):
+def _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile, act):
     tokens, top_k = idx.shape
     n_held = w_up.shape[0]
     plan = _plan(idx, first_held, n_held, tile)
@@ -208,8 +265,8 @@ def _held_fwd(x, gates, idx, w_up, w_down, w_gate, first_held, tile):
     def body(j, rows):
         e, tok, gate = _tile(j, plan, gates_flat, top_k, tile, tokens)
         xt = x.at[tok].get(mode="fill", fill_value=0)
-        act, _ = _expert_forward(xt, e, w_up, w_gate)
-        y = jnp.dot(act.astype(x.dtype), w_down[e],
+        a, _ = _expert_forward(xt, e, w_up, w_gate, act)
+        y = jnp.dot(a.astype(x.dtype), w_down[e],
                     preferred_element_type=WGRAD_DTYPE)
         return lax.dynamic_update_slice(
             rows, (y * gate[:, None]).astype(x.dtype), (j * tile, 0))
@@ -238,7 +295,7 @@ def _grouped_product(lhs, rhs, acc, tile_expert, used, tile: int):
     return lax.fori_loop(0, used, body, acc)
 
 
-def _held_bwd(first_held, tile, saved, cts):
+def _held_bwd(first_held, tile, act, saved, cts):
     x, gates, idx, w_up, w_down, w_gate = saved
     dy = cts[0]
     tokens, top_k = idx.shape
@@ -260,11 +317,11 @@ def _held_bwd(first_held, tile, saved, cts):
         e, tok, gate = _tile(j, plan, gates_flat, top_k, tile, tokens)
         xt = x.at[tok].get(mode="fill", fill_value=0)
         dyt = dy.at[tok].get(mode="fill", fill_value=0)
-        a, pre = _expert_forward(xt, e, w_up, w_gate)
+        a, pre = _expert_forward(xt, e, w_up, w_gate, act)
         # d(gate * a W_down) : through a, through W_down, through the gate
         da = jnp.dot(dyt, w_down[e].T, preferred_element_type=WGRAD_DTYPE)
         dg = jnp.sum(a * da, axis=-1)
-        dh, dgated = _expert_backward(da * gate[:, None], pre)
+        dh, dgated = _expert_backward(da * gate[:, None], pre, act)
         dh = dh.astype(x.dtype)
         ag = (a * gate[:, None]).astype(x.dtype)
         dxt = jnp.dot(dh, w_up[e].T, preferred_element_type=WGRAD_DTYPE)
@@ -347,18 +404,23 @@ _held.defvjp(_held_fwd, _held_bwd)
 
 
 def held_experts(x, idx, gates, w_up, w_down, experts_total: int,
-                 first_held: int, w_gate=None):
+                 first_held: int, w_gate=None, act: str = "silu"):
     """``(y (T, D), counters (3,))``: what the experts
     ``first_held .. first_held + n_held - 1`` of ``experts_total`` add for
     tokens ``x`` (T, D) routed by ``idx`` and ``gates`` (T, k). ``w_up``
     (n_held, D, F) and ``w_down`` (n_held, F, D) are the held experts'
     weights in the compute dtype; with ``w_gate`` (n_held, D, F) an expert
-    is gated, ``W_down (silu(W_gate x) * W_up x)``, else
-    ``W_down relu(W_up x)^2``. The counters are ``COUNTERS``."""
+    is gated, ``W_down (act(W_gate x) * W_up x)`` with ``act`` ``silu`` or
+    ``relu``, else ``W_down relu(W_up x)^2``. The counters are
+    ``COUNTERS``."""
+    if act not in GATE_ACTIVATIONS:
+        raise ValueError(f"unknown gate activation {act!r} "
+                         f"(known: {GATE_ACTIVATIONS})")
     if not 0 <= first_held <= experts_total - w_up.shape[0]:
         raise ValueError(
             f"experts {first_held}..{first_held + w_up.shape[0] - 1} are not "
             f"among {experts_total}")
     tile = tile_rows(x.shape[0], idx.shape[1], experts_total)
-    y, counters = _held(x, gates, idx, w_up, w_down, w_gate, first_held, tile)
+    y, counters = _held(x, gates, idx, w_up, w_down, w_gate, first_held, tile,
+                        act)
     return y, lax.stop_gradient(counters)

@@ -161,21 +161,48 @@ def attention_path(platform: str, s: int, d: int, hq: int, hkv: int) -> int:
     return next((t for t in ATTENTION_TILES if s % t == 0), 0)
 
 
-def causal_attention(q, k, v, block: int = ATTENTION_BLOCK):
+def causal_attention(q, k, v, block: int = ATTENTION_BLOCK, window=None):
     """Causal grouped-query attention with scores scaled by 1 / sqrt(D):
     ``q`` (B, S, Hq, D), ``k`` and ``v`` (B, S, Hkv, D), Hq a multiple of
-    Hkv. The fused kernel where ``attention_path`` says so, else query
-    blocks of ``block`` rows in plain XLA."""
+    Hkv. With ``window`` (sliding-window attention) query ``i`` sees key
+    ``j`` iff ``0 <= i - j < window``. The fused kernel where
+    ``attention_path`` says so, else query blocks of ``block`` rows in
+    plain XLA."""
     tile = attention_path(jax.default_backend(), q.shape[1], q.shape[3],
                           q.shape[2], k.shape[2])
     if tile:
-        return attention_pallas.causal_attention(q, k, v, tile)
-    return blocked_attention(q, k, v, block)
+        return attention_pallas.causal_attention(q, k, v, tile, window)
+    return blocked_attention(q, k, v, block, window)
 
 
-def blocked_attention(q, k, v, block: int = ATTENTION_BLOCK):
+def _query_blocks(s: int, block: int, window):
+    """``(start, end, first)`` of ``blocked_attention``'s query blocks:
+    queries ``start .. end - 1`` against keys ``first .. end - 1``, from
+    the first position the block's first query sees."""
+    for start in range(0, s, block):
+        yield (start, min(s, start + block),
+               0 if window is None else max(0, start - window + 1))
+
+
+def attention_pairs(platform: str, s: int, d: int, hq: int, hkv: int,
+                    window=None, block: int = ATTENTION_BLOCK) -> int:
+    """(query, key) pairs of positions that ``causal_attention`` multiplies
+    for one head of one sequence of these shapes on ``platform``, what its
+    masks then throw away included: the kernel's whole tiles
+    (``attention_pallas.pairs_computed``: its own loop bounds), or the
+    blocked path's query blocks against the keys sliced for them."""
+    window = attention_pallas.effective_window(window, s)
+    tile = attention_path(platform, s, d, hq, hkv)
+    if tile:
+        return attention_pallas.pairs_computed(s, tile, window)
+    return sum((end - start) * (end - first)
+               for start, end, first in _query_blocks(s, block, window))
+
+
+def blocked_attention(q, k, v, block: int = ATTENTION_BLOCK, window=None):
     """Causal grouped-query attention, one block of queries at a time
-    against the keys up to that block's end, so that no (S x S) score
+    against the keys up to that block's end (with ``window``: from the
+    first key that the block's first query sees), so that no (S x S) score
     matrix exists: ``q`` (B, S, Hq, D), ``k`` and ``v`` (B, S, Hkv, D),
     Hq a multiple of Hkv. The query heads that share a key-value head are
     rows of one matrix product (Hq / Hkv x block rows against the keys).
@@ -183,6 +210,7 @@ def blocked_attention(q, k, v, block: int = ATTENTION_BLOCK):
     backward pass. Keys and values are held in float32 outside the blocks
     so that their gradient, a sum over the blocks, adds up in float32."""
     b, s, hq, d = q.shape
+    window = attention_pallas.effective_window(window, s)
     hkv = k.shape[2]
     rep = hq // hkv
     scale = 1.0 / math.sqrt(d)
@@ -194,13 +222,16 @@ def blocked_attention(q, k, v, block: int = ATTENTION_BLOCK):
 
     @jax.checkpoint
     def one_block(qb, kb, vb, start):
+        # ``start``: the block's first query, counted from its first key
         n, end = qb.shape[3], kb.shape[2]
         rows = qb.reshape(b, hkv, rep * n, d)
         scores = jnp.einsum("bgmd,bgkd->bgmk", rows, kb.astype(dtype),
                             preferred_element_type=LOSS_DTYPE) * scale
         qpos = start + jnp.arange(rep * n) % n
-        scores = jnp.where(jnp.arange(end)[None, :] <= qpos[:, None],
-                           scores, -jnp.inf)
+        seen = jnp.arange(end)[None, :] <= qpos[:, None]
+        if window is not None:
+            seen &= qpos[:, None] - jnp.arange(end)[None, :] < window
+        scores = jnp.where(seen, scores, -jnp.inf)
         # the row maximum behind a barrier: left to itself the chip's
         # compiler turns "reduce, broadcast, subtract" into a reduce-window
         # as wide as the row, quadratic work (PERF.md §6)
@@ -213,10 +244,9 @@ def blocked_attention(q, k, v, block: int = ATTENTION_BLOCK):
         return out.astype(dtype).reshape(b, hkv, rep, n, d)
 
     outs = []
-    for start in range(0, s, block):
-        end = min(s, start + block)
-        outs.append(one_block(q[:, :, :, start:end], k32[:, :, :end],
-                              v32[:, :, :end], start))
+    for start, end, first in _query_blocks(s, block, window):
+        outs.append(one_block(q[:, :, :, start:end], k32[:, :, first:end],
+                              v32[:, :, first:end], start - first))
     out = jnp.concatenate(outs, axis=3)  # (B, Hkv, rep, S, D)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, d)
 

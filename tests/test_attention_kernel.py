@@ -179,3 +179,136 @@ def test_gauge_counts_the_blocks_that_take_the_kernel():
     assert model.attention_kernel_blocks("tpu", 8192) == 1
     assert model.attention_kernel_blocks("tpu", 8192 + 72) == 0
     assert defs.ATTENTION_KERNEL_BLOCKS.name == "dpt_attention_kernel_blocks"
+
+
+# -- a sliding window: query i sees key j iff 0 <= i - j < window -----------
+
+def windowed_attention(window):
+    """Softmax over the whole masked (S x S) scores, float32."""
+    def fn(q, k, v):
+        s, rep = q.shape[1], q.shape[2] // k.shape[2]
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            precision="highest") / math.sqrt(q.shape[-1])
+        ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        scores = jnp.where((ahead >= 0) & (ahead < window), scores, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v,
+                          precision="highest")
+    return fn
+
+
+def _qkvw(s, rep, d, hkv=2, dtype=jnp.float32, seed=0):
+    keys = jax.random.split(jax.random.key(seed + s + rep + d), 4)
+    q = jax.random.normal(keys[0], (1, s, hkv * rep, d)).astype(dtype)
+    k = jax.random.normal(keys[1], (1, s, hkv, d)).astype(dtype)
+    v = jax.random.normal(keys[2], (1, s, hkv, d)).astype(dtype)
+    return q, k, v, jax.random.normal(keys[3], q.shape)
+
+
+#: Windows against a tile of 128 over 512 positions: below a tile, a tile,
+#: above it and no multiple, two tiles, three and a half, one key alone,
+#: all but the first key.
+WINDOWS = [40, 128, 200, 256, 448, 1, 511]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", WINDOWS)
+def test_windowed_kernel_and_blocked_path_are_the_masked_softmax(window, dtype):
+    dtype = jnp.dtype(dtype)
+    s, tile = 512, 128
+    q, k, v, weight = _qkvw(s, 2, 128, dtype=dtype, seed=window)
+
+    def kernel(q, k, v):
+        return attention_pallas.causal_attention(q, k, v, tile, window,
+                                                 interpret=True)
+
+    def blocked(q, k, v):
+        return seq.blocked_attention(q, k, v, block=96, window=window)
+
+    whole, fused, xla = jax.jit(lambda *a: [
+        output_and_gradients(fn, *a)
+        for fn in (windowed_attention(window), kernel, blocked)
+    ])(q, k, v, weight)
+    for name, exact, mine, theirs in zip(("out", "dq", "dk", "dv"),
+                                         whole, fused, xla):
+        assert bool(jnp.all(jnp.isfinite(mine))), name
+        size = max(1.0, float(jnp.max(jnp.abs(exact))))
+        if dtype == jnp.float32:
+            assert gap(mine, exact) < 1e-4 * size, name
+            assert gap(theirs, exact) < 1e-4 * size, name
+        else:
+            assert gap(mine, exact) <= max(2 * gap(theirs, exact), 0.02 * size), name
+
+
+@pytest.mark.parametrize("window", [512, 513, 10 ** 6])
+def test_a_window_that_reaches_the_whole_sequence_is_no_window(window):
+    """Bit for bit, on both paths: the same program is traced."""
+    q, k, v, weight = _qkvw(512, 2, 128, dtype=jnp.bfloat16)
+
+    def both(window):
+        return jax.jit(lambda *a: [output_and_gradients(fn, *a) for fn in (
+            lambda q, k, v: attention_pallas.causal_attention(
+                q, k, v, 128, window, interpret=True),
+            lambda q, k, v: seq.blocked_attention(q, k, v, 96, window))])
+    with_window, without = both(window), both(None)
+    assert (with_window.lower(q, k, v, weight).as_text()
+            == without.lower(q, k, v, weight).as_text())
+    for a, b in zip(jax.tree.leaves(with_window(q, k, v, weight)),
+                    jax.tree.leaves(without(q, k, v, weight))):
+        assert bool(jnp.all(a == b))
+
+
+def test_a_window_of_no_position_is_refused():
+    q, k, v, _ = _qkvw(256, 2, 128)
+    with pytest.raises(ValueError, match="sees no key"):
+        seq.blocked_attention(q, k, v, window=0)
+
+
+@pytest.mark.parametrize("s,tile,window,tiles", [
+    (16384, 1024, None, 136),   # 16 query tiles to the diagonal
+    (16384, 1024, 4096, 70),    # 1 + 2 + 3 + 4, then five a query tile
+    (16384, 1024, 4097, 70),    # the tile's first query sees its first key
+    (16384, 1024, 4098, 81),    # one key more reaches a sixth tile
+    (16384, 1024, 1024, 31),    # the diagonal tile and the one before
+    (16384, 1024, 1000, 31),
+    (512, 128, 40, 7),
+    (512, 128, 1, 4),           # the diagonal tiles alone
+])
+def test_walk_of_the_key_tiles_starts_where_the_window_does(s, tile, window, tiles):
+    assert attention_pallas.pairs_computed(s, tile, window) == tiles * tile * tile
+    for i in range(s // tile):
+        first, whole = attention_pallas.key_tiles(i, tile, window)
+        assert 0 <= first <= whole <= i
+        # jax's operators give the kernel the same bounds
+        traced = attention_pallas.key_tiles(jnp.int32(i), tile, window,
+                                            jnp.maximum, jnp.minimum)
+        assert (int(traced[0]), int(traced[1])) == (first, whole)
+        if window is None:
+            continue
+        q0, q1 = i * tile, i * tile + tile - 1
+        # the first tile walked holds the first key the tile's first query sees
+        assert first == max(0, q0 - window + 1) // tile
+        # a whole tile hides nothing: its first key is seen by the last query
+        for j in range(whole, i):
+            assert q1 - j * tile < window
+        for j in range(first, whole):
+            assert q1 - j * tile >= window
+
+
+def test_pairs_counted_are_the_paths_own():
+    """The kernel's whole tiles on a TPU at its shapes; elsewhere the
+    blocked path's query blocks against the keys sliced for them; never
+    fewer than the pairs inside the masks."""
+    s, w = 16384, 4096
+    inside = w * (w + 1) // 2 + (s - w) * w
+    assert inside == 58_722_304 and s * (s + 1) // 2 == 134_225_920
+    assert seq.attention_pairs("tpu", s, 128, 28, 4) == 136 * 1024 ** 2
+    assert seq.attention_pairs("tpu", s, 128, 28, 4, window=w) == 70 * 1024 ** 2
+    assert seq.attention_pairs("tpu", s, 128, 28, 4, window=s) == 136 * 1024 ** 2
+    blocked = seq.attention_pairs("cpu", s, 128, 28, 4, window=w)
+    # blocks of 512 against at most 4095 + 512 keys
+    assert inside < blocked == sum(
+        512 * (start + 512 - max(0, start - w + 1)) for start in range(0, s, 512))
+    assert seq.attention_pairs("cpu", 72, 16, 4, 2, window=20, block=32) == (
+        32 * 32 + 32 * (64 - 13) + 8 * (72 - 45))

@@ -165,9 +165,34 @@ def test_causal_attention_value_and_grads_compile(mosaic, s, dtype, d, hkv):
         6 * q.size * 4 * max(d, 128) // d)
 
 
+@pytest.mark.parametrize("window", [None, 4096, 1000])
+def test_windowed_attention_compiles_at_the_16k_cells_shapes(mosaic, window):
+    """One sequence of 16,384 positions, 28 / 4 heads of 128 in bf16 (the
+    smallthinker cell's layers): the full layer, the published window of
+    four tiles, and a window that no tile divides and that is shorter than
+    one (both masks in the diagonal tile). Mosaic takes the walk whose
+    first tile follows the query tile."""
+    from distributedpytorch_tpu.ops import attention_pallas
+    from distributedpytorch_tpu.ops import sequence as seq
+
+    s, hq, hkv, d = 16384, 28, 4, 128
+    tile = seq.attention_path("tpu", s, d, hq, hkv)
+    assert tile == 1024
+    q, kv = mosaic((1, s, hq, d), jnp.bfloat16), mosaic((1, s, hkv, d), jnp.bfloat16)
+    compiled = _compile(jax.grad(
+        lambda q, k, v: jnp.sum(attention_pallas.causal_attention(
+            q, k, v, tile, window, interpret=False).astype(jnp.float32)),
+        argnums=(0, 1, 2)), q, kv, kv)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "causal_attention_fwd" in text and "causal_attention_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * q.size * 4
+
+
 @pytest.mark.parametrize("experts,d,f", [
     (16, 2048, 1536),   # the lfm2 cell's experts: both orders of the result
     (8, 2688, 1856),    # the first token cell's, 14.5 x 128 wide: turned
+    (16, 2560, 768),    # the smallthinker cell's: 6 x 128 wide
 ])
 def test_grouped_wgrad_compiles_at_both_cells_widths(mosaic, experts, d, f):
     """The held experts' weight-gradient kernel over one chunk of 64 tiles

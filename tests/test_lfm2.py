@@ -329,7 +329,8 @@ def test_trainer_reproduces_the_references_losses_and_counts(tmp_path):
 
 
 def test_model_table_names_its_models_and_refuses_serving_and_meshes():
-    assert set(MODELS) == {"unet", "milesial", "twotower", "lfm2"}
+    assert set(MODELS) == {"unet", "milesial", "twotower", "lfm2",
+                           "smallthinker"}
     entry = model_entry("lfm2")
     assert entry.batch.fields == ("tokens",) and entry.adam_b2 == 0.95
     assert not entry.servable and entry.single_device_only

@@ -285,7 +285,8 @@ def test_trainer_reproduces_the_references_losses_and_resumes(tmp_path):
 
 
 def test_model_table_names_its_models_and_serve_refuses_the_token_model():
-    assert set(MODELS) == {"unet", "milesial", "twotower", "lfm2"}
+    assert set(MODELS) == {"unet", "milesial", "twotower", "lfm2",
+                           "smallthinker"}
     assert model_entry("twotower").batch.fields == ("tokens",)
     assert not model_entry("twotower").servable and model_entry("unet").servable
     with pytest.raises(ValueError, match="known: .*'twotower'"):
